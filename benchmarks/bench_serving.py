@@ -88,7 +88,7 @@ def bench_in_graph(seed: int = 7) -> dict:
     @jax.jit
     def step(xp, yp, dims):
         traces["n"] += 1            # trace-time only: the compile counter
-        return matmul(xp, yp, in_graph=disp, dims=dims, interpret=True)
+        return matmul(xp, yp, in_graph=disp, dims=dims)
 
     @jax.jit
     def decide(dims):

@@ -3,6 +3,9 @@ batching engine (more requests than decode slots -> slots are recycled).
 
     PYTHONPATH=src python examples/serve_lm.py --requests 10 --batch 4
 
+serves the full-width model with random weights; ``--smoke`` swaps in the
+architecture's reduced config (a few seconds on a CPU).
+
 ``--async`` routes the same workload through the engine's async
 front-end: a scheduler thread owns the device loop, ``submit`` is
 thread-safe (requests here are submitted *while the engine is already
@@ -21,7 +24,10 @@ trace-event file to load in ui.perfetto.dev.
 import argparse
 import time
 
+import jax
+
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.serve import build_engine
 from repro.serving import Request
 
@@ -29,6 +35,9 @@ from repro.serving import Request
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the architecture's reduced config instead "
+                         "of the full-width one")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=96)
     ap.add_argument("--requests", type=int, default=10)
@@ -41,11 +50,12 @@ def main() -> None:
                     help="write a Chrome trace-event JSON of the run")
     args = ap.parse_args()
 
+    use_compile_cache()
     tracer = None
     if args.trace:
         from repro.trace import Tracer
         tracer = Tracer()
-    cfg = get_config(args.arch, smoke=True)
+    cfg = get_config(args.arch, smoke=args.smoke)
     engine = build_engine(cfg, args.batch, args.max_seq, trace=tracer)
     t0 = time.perf_counter()
     if args.run_async:
@@ -65,12 +75,14 @@ def main() -> None:
         finished = engine.run()
     dt = time.perf_counter() - t0
     toks = sum(len(r.output) for r in finished)
+    dev = jax.devices()[0]
     for r in sorted(finished, key=lambda r: r.rid):
         print(f"req {r.rid:2d} ({'greedy' if r.temperature == 0 else 'T=.8'})"
               f": {r.prompt} -> {r.output}")
     print(f"\n{len(finished)} requests, {toks} tokens in {dt:.1f}s through "
           f"{args.batch} continuous-batching slots "
-          f"({toks / dt:.1f} tok/s on CPU)")
+          f"({toks / dt:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          f"compilation included)")
     if args.run_async:
         cc = engine.compile_counts
         print(f"async front-end: {cc['decode_step']} decode-step "

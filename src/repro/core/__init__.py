@@ -9,9 +9,9 @@ from .cache import (
     default_cache_dir, spec_fingerprint,
 )
 from .device_model import (
-    DTYPE_BYTES, V5E, V5P, DeviceModel, HardwareParams, KernelTraffic,
-    ProbeBatch, ProbeRecord, RowProbe, TrafficOperand, TrafficTable,
-    V5eSimulator, dtype_bytes,
+    DEVICE_KINDS, DTYPE_BYTES, V5E, V5P, DeviceModel, HardwareParams,
+    KernelTraffic, ProbeBatch, ProbeRecord, RowProbe, TrafficOperand,
+    TrafficTable, V5eSimulator, dtype_bytes, hardware_for, target_hardware,
 )
 from .device_plan import (
     BucketedDispatch, DevicePlanTable, build_bucketed_dispatch, pack_shape32,
@@ -51,9 +51,10 @@ __all__ = [
     "BucketLattice", "pad_to", "pow2_span",
     "CacheEntry", "DriverCache", "PlanEntry", "cache_key", "default_cache",
     "default_cache_dir", "spec_fingerprint",
-    "DTYPE_BYTES", "V5E", "V5P", "DeviceModel", "HardwareParams",
-    "KernelTraffic", "ProbeBatch", "ProbeRecord", "RowProbe",
-    "TrafficOperand", "TrafficTable", "V5eSimulator", "dtype_bytes",
+    "DEVICE_KINDS", "DTYPE_BYTES", "V5E", "V5P", "DeviceModel",
+    "HardwareParams", "KernelTraffic", "ProbeBatch", "ProbeRecord",
+    "RowProbe", "TrafficOperand", "TrafficTable", "V5eSimulator",
+    "dtype_bytes", "hardware_for", "target_hardware",
     "ChoiceEvent", "DriverProgram", "WarmStartSummary", "choose_or_default",
     "dkey", "get_choice_listener", "get_driver", "memo_key",
     "register_driver",

@@ -113,7 +113,8 @@ def spec_fingerprint(spec: KernelSpec) -> dict:
         "program_params": list(spec.program_params),
         "grid": [[a.name, a.data, a.block] for a in spec.grid],
         "operands": [[op.name, list(op.tile), list(op.deps),
-                      op.dtype_bytes, op.is_output] for op in spec.operands],
+                      op.dtype_bytes, op.is_output, op.vmem_only]
+                     for op in spec.operands],
         "flops_per_point": spec.flops_per_point,
         "constraints": list(spec.constraints),
         "mxu_fraction": spec.mxu_fraction,

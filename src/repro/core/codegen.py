@@ -62,6 +62,7 @@ import numpy as np
 KERNEL = {kernel!r}
 DEVICE = {device!r}
 VMEM_BYTES = {vmem}
+VMEM_LIMIT_BYTES = {vmem_limit}
 MAX_STAGES = {max_stages}
 DATA_PARAMS = {data_params!r}
 PROGRAM_PARAMS = {program_params!r}
@@ -106,7 +107,7 @@ def _constraint_vectorizable(c: str, spec: KernelSpec,
     env: dict = {p: np.array([8, 16], dtype=np.int64)
                  for p in spec.program_params}
     env.update({d: 64 for d in spec.data_params})
-    env["vmem"] = hw.vmem_bytes
+    env["vmem"] = hw.vmem_limit_bytes
     try:
         res = eval(c, {"__builtins__": {}, "math": math, "np": np}, env)
         np.broadcast_to(np.asarray(res, dtype=bool), (2,))
@@ -126,6 +127,7 @@ def generate_driver_source(
                   for p in spec.program_params}
     parts = [_HEADER.format(
         kernel=spec.name, device=hw.name, vmem=hw.vmem_bytes,
+        vmem_limit=hw.vmem_limit_bytes,
         max_stages=max_stages, data_params=tuple(spec.data_params),
         program_params=tuple(spec.program_params),
         param_candidates=cand_lists,
@@ -187,7 +189,7 @@ def generate_driver_source(
     p_names = list(spec.program_params)
     unpack = "\n".join(f"    {p} = cols[{p!r}]" for p in p_names)
     scalars = ("{" + ", ".join([f"{d!r}: {d}" for d in spec.data_params]
-                               + ["'vmem': VMEM_BYTES"]) + "}")
+                               + ["'vmem': VMEM_LIMIT_BYTES"]) + "}")
     mask_srcs = [
         f"    mask &= ({c})" if _constraint_vectorizable(c, spec, hw)
         else f"    mask &= _row_mask({i}, {scalars}, cols)"
@@ -208,10 +210,10 @@ def generate_driver_source(
                   for p in PROGRAM_PARAMS], indexing="ij")
             cols = {{p: g.reshape(-1) for p, g in zip(PROGRAM_PARAMS, grids)}}
         ''') + unpack + f'''
-    vmem = VMEM_BYTES
+    vmem = VMEM_LIMIT_BYTES
     mask = np.ones({p_names[0]}.shape, dtype=bool)
 ''' + (mask_lines + "\n" if mask_lines else "") + f'''\
-    mask &= (stage_bytes({sig}) * {spec.pipeline_buffers} <= VMEM_BYTES)
+    mask &= (stage_bytes({sig}) * {spec.pipeline_buffers} <= VMEM_LIMIT_BYTES)
     return {{p: c[mask] for p, c in cols.items()}}
 ''')
 
@@ -270,7 +272,7 @@ def generate_driver_source(
     row_scalars = ("{" + ", ".join(
         [f"{d!r}: int(_d_flat[{i}][_s])"
          for i, d in enumerate(spec.data_params)]
-        + ["'vmem': VMEM_BYTES"]) + "}")
+        + ["'vmem': VMEM_LIMIT_BYTES"]) + "}")
     nv_block = "" if not nv_idx else f'''\
     for _ci in {tuple(nv_idx)!r}:
         for _s in range(S):
@@ -299,10 +301,11 @@ def generate_driver_source(
           for p in PROGRAM_PARAMS], indexing="ij")
     cols = {{p: g.reshape(-1) for p, g in zip(PROGRAM_PARAMS, grids)}}
 ''' + unpack + f'''
-    vmem = VMEM_BYTES
+    vmem = VMEM_LIMIT_BYTES
     feas = np.ones((S, {p_names[0]}.shape[0]), dtype=bool)
 ''' + (feas_lines + "\n" if feas_lines else "") + nv_block + f'''\
-    feas = feas & (stage_bytes({sig}) * {spec.pipeline_buffers} <= VMEM_BYTES)
+    feas = feas & (stage_bytes({sig}) * {spec.pipeline_buffers}
+                   <= VMEM_LIMIT_BYTES)
     with np.errstate(all="ignore"):
         est = np.asarray(estimate({sig}), dtype=np.float64)
     est = np.broadcast_to(est, feas.shape).copy()

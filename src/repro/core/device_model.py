@@ -26,7 +26,8 @@ from typing import Callable, Mapping, Sequence
 import numpy as np
 
 __all__ = [
-    "HardwareParams", "V5E", "V5P", "ProbeRecord", "ProbeBatch", "RowProbe",
+    "HardwareParams", "V5E", "V5P", "DEVICE_KINDS", "hardware_for",
+    "target_hardware", "ProbeRecord", "ProbeBatch", "RowProbe",
     "DeviceModel", "KernelTraffic", "TrafficTable", "TrafficOperand",
     "V5eSimulator", "InterpretTimer", "DTYPE_BYTES", "dtype_bytes",
 ]
@@ -72,13 +73,22 @@ def dtype_bytes(dt) -> int:
 
 @dataclass(frozen=True)
 class HardwareParams:
-    """Hardware parameters H (paper Section II): fixed per target device."""
+    """Hardware parameters H (paper Section II): fixed per target device.
+
+    ``vmem_bytes`` is the physical VMEM of one TensorCore (the pipeline
+    occupancy model's capacity).  ``vmem_limit_bytes`` is the scoped VMEM
+    budget one kernel may use: the tuner admits only configs whose modelled
+    footprint fits it, and every kernel passes the same number to Mosaic as
+    ``vmem_limit_bytes``, so a config the tuner admits is one the compiler
+    accepts.
+    """
 
     name: str
     peak_flops_bf16: float          # MXU peak, FLOP/s
     peak_flops_f32: float
     hbm_bw: float                   # bytes/s
     vmem_bytes: int
+    vmem_limit_bytes: int
     ici_bw_per_link: float          # bytes/s per ICI link
     ici_links: int                  # links per chip (2D torus: 4)
     mxu_dim: int = 128
@@ -92,30 +102,71 @@ class HardwareParams:
         return self.sublanes_f32 * max(1, 4 // dtype_bytes)
 
 
-# Target of this build (roofline constants from the assignment).
+# TPU v5e.  Peaks: Google Cloud TPU documentation, "TPU v5e" (197 TFLOP/s
+# bf16, 16 GiB HBM at 819 GB/s, 1,600 Gbit/s ICI = 4 links x 50 GB/s).  No
+# f32 peak is published; a quarter of bf16 is assumed.  VMEM: 128 MiB per
+# TensorCore, of which Mosaic grants a kernel 16 MiB unless the kernel asks
+# for more.  The budget asks for half of it: enough for the largest tiles
+# the candidate grids hold (a 2048x2048 flash tile counts 26.5 MiB in the
+# compiler), with the other half left for the compiler's own scratch that
+# the footprint model does not see.
 V5E = HardwareParams(
     name="tpu_v5e",
     peak_flops_bf16=197e12,
     peak_flops_f32=49.25e12,
     hbm_bw=819e9,
     vmem_bytes=128 * 2**20,
+    vmem_limit_bytes=64 * 2**20,
     ici_bw_per_link=50e9,
     ici_links=4,
     hbm_bytes=16 * 2**30,
 )
 
-# A second device profile: performance portability experiments (the paper's
-# point that optimal configs differ across devices) re-tune against this.
+# TPU v5p: a second device profile, so performance-portability experiments
+# (the paper's point that optimal configs differ across devices) re-tune
+# against it.  Peaks: Google Cloud TPU documentation, "TPU v5p" (459 TFLOP/s
+# bf16, 95 GiB HBM at 2,765 GB/s, 4,800 Gbit/s ICI = 6 links x 100 GB/s).
+# f32 peak and VMEM are assumed as for v5e; no v5p has run this code.
 V5P = HardwareParams(
     name="tpu_v5p",
     peak_flops_bf16=459e12,
     peak_flops_f32=114.75e12,
     hbm_bw=2765e9,
     vmem_bytes=128 * 2**20,
+    vmem_limit_bytes=64 * 2**20,
     ici_bw_per_link=100e9,
     ici_links=6,
     hbm_bytes=95 * 2**30,
 )
+
+# ``jax.Device.device_kind`` -> HardwareParams.  A chip that is not here is
+# an error (``hardware_for``), never a silent v5e default.
+DEVICE_KINDS = {"TPU v5 lite": V5E, "TPU v5": V5P}
+
+
+def hardware_for(device_kind: str) -> HardwareParams:
+    """HardwareParams of a chip, by its ``device_kind``."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no hardware parameters for device kind {device_kind!r}; "
+            f"known kinds: {sorted(DEVICE_KINDS)}") from None
+
+
+def target_hardware() -> HardwareParams:
+    """HardwareParams of the chip this process compiles kernels for.
+
+    On a TPU backend: the table entry of ``jax.devices()[0]`` (an unknown
+    chip raises).  On any other backend -- the CPU test path, where kernels
+    are interpreted or compiled for a described v5e -- the build's target,
+    ``V5E``.
+    """
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return hardware_for(jax.devices()[0].device_kind)
+    return V5E
 
 
 @dataclass

@@ -184,6 +184,8 @@ class Operand:
 
     ``tile``: each entry is a program-param name, data-param name, or literal.
     ``deps``: grid axis names the BlockSpec index_map depends on.
+    ``vmem_only``: a VMEM scratch buffer or body intermediate -- it counts
+    toward the stage footprint but moves no HBM bytes.
     """
 
     name: str
@@ -191,6 +193,7 @@ class Operand:
     deps: tuple[str, ...]
     dtype_bytes: int = 2
     is_output: bool = False
+    vmem_only: bool = False
 
     def tile_shape(self, D: Dims, P: Dims) -> tuple[int, ...]:
         out = []
@@ -254,6 +257,8 @@ class KernelSpec:
 
     def _fetches(self, op: Operand, extents: tuple[int, ...]) -> int:
         """Fetch count under lexicographic grid order, last axis fastest."""
+        if op.vmem_only:
+            return 0
         names = [a.name for a in self.grid]
         dep_pos = [names.index(d) for d in op.deps if d in names]
         if not dep_pos:
@@ -365,7 +370,9 @@ class KernelSpec:
         operands = []
         for op in self.operands:
             dep_pos = [names.index(d) for d in op.deps if d in names]
-            if not dep_pos:
+            if op.vmem_only:
+                fetches = np.zeros(n, dtype=np.int64)
+            elif not dep_pos:
                 fetches = np.ones(n, dtype=np.int64)
             else:
                 fetches = np.ones(n, dtype=np.int64)
@@ -415,7 +422,7 @@ class KernelSpec:
         mask = np.ones(n, dtype=bool)
         env: dict[str, object] = {k: int(v) for k, v in D.items()}
         env.update(table.columns)
-        env["vmem"] = hw.vmem_bytes
+        env["vmem"] = hw.vmem_limit_bytes
         known = set(env) | {"math", "np"}
         globs = {"__builtins__": {}, "math": math, "np": np}
         for c in self.constraints:
@@ -427,16 +434,17 @@ class KernelSpec:
                 ok = np.zeros(n, dtype=bool)
                 for i in range(n):
                     row = {**{k: int(v) for k, v in D.items()},
-                           **table.row(i), "vmem": hw.vmem_bytes}
+                           **table.row(i), "vmem": hw.vmem_limit_bytes}
                     try:
                         ok[i] = bool(eval(c, globs, row))
                     except Exception:
                         ok[i] = False
                 mask &= ok
-        # Built-in constraint: pipeline_buffers stage buffers must fit VMEM
+        # Built-in constraint: pipeline_buffers stage buffers must fit the
+        # kernel's scoped VMEM budget -- the limit the kernel hands Mosaic
         # (the TPU occupancy analogue of registers/shared-memory limits).
         stage = self.vmem_stage_bytes_batch(D, table, hw)
-        mask &= stage * self.pipeline_buffers <= hw.vmem_bytes
+        mask &= stage * self.pipeline_buffers <= hw.vmem_limit_bytes
         # Tiles may not exceed their data extents beyond one padded block.
         for a in self.grid:
             if a.block is not None and isinstance(a.data, str):
@@ -486,8 +494,8 @@ def matmul_spec(dtype_bytes: int = 2) -> KernelSpec:
             Operand("out", ("bm", "bn"), ("i", "j"), dtype_bytes,
                     is_output=True),
             # f32 accumulator scratch lives in VMEM but moves no HBM bytes;
-            # accounted in stage bytes via a 4-byte pseudo-operand with no deps.
-            Operand("acc", ("bm", "bn"), (), 4),
+            # accounted in stage bytes via a 4-byte VMEM-only pseudo-operand.
+            Operand("acc", ("bm", "bn"), (), 4, vmem_only=True),
         ),
         flops_per_point=2.0,  # over the (m, n, k) domain: one FMA per point
         constraints=(
@@ -529,9 +537,12 @@ def flash_attention_spec(head_dim: int = 128, causal: bool = True,
             Operand("out", ("bq", head_dim), ("b", "iq"), dtype_bytes,
                     is_output=True),
             # VMEM scratch, in kernel declaration order (no HBM traffic):
-            Operand("rowmax", ("bq", 128), (), 4),         # running max m
-            Operand("rowsum", ("bq", 128), (), 4),         # running sum l
-            Operand("acc", ("bq", head_dim), (), 4),       # o accumulator
+            Operand("rowmax", ("bq", 128), (), 4, vmem_only=True),  # max m
+            Operand("rowsum", ("bq", 128), (), 4, vmem_only=True),  # sum l
+            Operand("acc", ("bq", head_dim), (), 4, vmem_only=True),
+            # f32 (bq, bkv) score / probability intermediates, which Mosaic
+            # keeps in VMEM too (26.5 MiB at bq = bkv = 2048, d = 128).
+            Operand("scores", ("bq", "bkv"), (), 4, vmem_only=True),
         ),
         flops_per_point=f,
         constraints=("bq <= sq", "bkv <= skv",
@@ -567,7 +578,7 @@ def moe_gmm_spec(dtype_bytes: int = 2) -> KernelSpec:
             Operand("weights", ("bk", "bn"), ("ex", "l", "j"), dtype_bytes),
             Operand("out", ("bg", "bn"), ("ex", "i", "j"), dtype_bytes,
                     is_output=True),
-            Operand("acc", ("bg", "bn"), (), 4),
+            Operand("acc", ("bg", "bn"), (), 4, vmem_only=True),
         ),
         flops_per_point=2.0,
         constraints=("bg <= 8 * g", "bn <= n", "bk <= k",
@@ -611,7 +622,9 @@ def ssd_scan_spec(d_head: int = 64, d_state: int = 128,
             Operand("decay", (1, 128), ("b",), 4),
             Operand("out", ("chunk", d_head), ("b", "c"), dtype_bytes,
                     is_output=True),
-            Operand("state", (d_state, d_head), (), 4),
+            Operand("state", (d_state, d_head), (), 4, vmem_only=True),
+            # f32 (chunk, chunk) gate / score intermediates kept in VMEM.
+            Operand("scores", ("chunk", "chunk"), (), 4, vmem_only=True),
         ),
         # dominant intra-chunk matmul term: 2 * chunk * d_head per point is
         # chunk-dependent; expressed by treating "chunkflops" as a data param

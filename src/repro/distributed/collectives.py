@@ -17,20 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["hierarchical_pmean", "compressed_pmean", "shard_map_compat"]
-
-
-def shard_map_compat(body, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental (and renamed check_rep ->
-    check_vma) across versions; accept any combination of the two."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    try:
-        return sm(body, **kw, check_vma=False)
-    except TypeError:   # older signature: the kwarg is still check_rep
-        return sm(body, **kw, check_rep=False)
+__all__ = ["hierarchical_pmean", "compressed_pmean"]
 
 f32 = jnp.float32
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .collectives import shard_map_compat
 
 __all__ = ["gpipe_forward", "bubble_fraction"]
 
@@ -87,10 +86,11 @@ def gpipe_forward(
             jnp.where(stage == n_stages - 1, buf, jnp.zeros_like(buf)), axis)
         return total[None]
 
-    sm = shard_map_compat(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )
     # stage_params: leading dim n_stages -> sharded over axis; x replicated
     # per stage via a broadcast leading axis.

@@ -7,7 +7,9 @@ Walks the captured kernel-body jaxpr (trace.py) and accumulates:
   * ``vpu_flops``  -- one FLOP per output element of every arithmetic /
     transcendental / reduction primitive (the VPU work),
   * ``minor_dims`` -- the set of minor-most (lane) dimension sizes of every
-    array value in the body, operands and intermediates alike.
+    array value in the body, operands and intermediates alike,
+  * ``values``     -- (shape, itemsize) of every rank >= 2 value the body
+    computes, in walk order (the VMEM-intermediate candidates).
 
 Conditional sub-jaxprs (``pl.when`` -> ``cond``) are *excluded* from the
 FLOP counts: they are pipeline-boundary work (accumulator init, final
@@ -28,7 +30,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 __all__ = ["BodyCost", "body_cost"]
 
@@ -52,6 +54,7 @@ class BodyCost:
     dot_flops: float = 0.0
     vpu_flops: float = 0.0
     minor_dims: set = field(default_factory=set)
+    values: list = field(default_factory=list)
 
     @property
     def total_flops(self) -> float:
@@ -71,6 +74,12 @@ def _shape(atom) -> tuple[int, ...]:
         return tuple(int(d) for d in shape)
     except TypeError:
         return ()
+
+
+def _itemsize(atom) -> int:
+    aval = getattr(atom, "aval", None)
+    inner = getattr(aval, "inner_aval", aval)
+    return int(inner.dtype.itemsize)
 
 
 def _note_minor(cost: BodyCost, atom) -> None:
@@ -95,6 +104,10 @@ def _walk(jaxpr, cost: BodyCost, count_flops: bool) -> None:
         for atom in list(eqn.invars) + list(eqn.outvars):
             if not isinstance(atom, jax_core.Literal):
                 _note_minor(cost, atom)
+        for atom in eqn.outvars:
+            shape = _shape(atom)
+            if len(shape) >= 2:
+                cost.values.append((shape, _itemsize(atom)))
         name = eqn.primitive.name
         if name == "dot_general":
             if count_flops:

@@ -21,6 +21,14 @@ the cost walk saw the parameter as a minor-most dimension anywhere in the
 body, sublane granularity (8) otherwise.  VMEM capacity is enforced by the
 same built-in pipeline-buffer check every spec gets.
 
+The VMEM footprint counts one body intermediate besides the blocks and the
+scratch: the largest value that no operand buffer can hold, i.e. whose
+(shape, element width) matches no block -- flash attention's (bq, bkv)
+scores, the SSD's (chunk, chunk) gate, layernorm's f32 upcast of a bf16
+row block.  Mosaic keeps such values in VMEM; without them the footprint
+undercounts what the compiler allocates by up to 2.6x (a 2048 x 2048 flash
+tile).
+
 FLOPs per grid-domain point come from the cost walk: per-step FLOPs divided
 by the product of the blocked program parameters, cross-checked between the
 two traces (a mismatch means the FLOP density depends on P itself --
@@ -138,10 +146,61 @@ def _match_operands(gs: GridSpec, cap1: Capture, cap2: Capture,
             deps=tuple(axes[a].name for a in op1.dep_axes),
             dtype_bytes=dtype_bytes(op1.dtype),
             is_output=op1.is_output,
+            vmem_only=op1.is_scratch,
         ))
     if n_in == 0:
         raise IntrospectError(f"{gs.name}: kernel has no input operands")
     return tuple(out)
+
+
+def _block_key(shape: tuple[int, ...]) -> tuple[int, ...]:
+    """A block shape with its leading mapped 1-dims squeezed (as the body
+    sees the loaded block)."""
+    while len(shape) > 2 and shape[0] == 1:
+        shape = shape[1:]
+    return tuple(shape)
+
+
+def _derive_intermediate(gs: GridSpec, cap1: Capture, cap2: Capture,
+                         c1, c2, D1: Dims, P1: Dims, D2: Dims, P2: Dims
+                         ) -> Operand | None:
+    """The largest body intermediate that no operand buffer can hold, as a
+    VMEM-only pseudo-operand, or None.
+
+    "Largest" is judged at the largest candidate tiles, not at the small
+    trace points: a (128, chunk) row form outweighs the (chunk, chunk) gate
+    at a traced chunk of 112 but not at the chunks the tuner chooses.
+    """
+    if len(c1.values) != len(c2.values):
+        raise IntrospectError(
+            f"{gs.name}: kernel body structure changed between traces")
+    blocks1 = {(_block_key(op.block_shape), dtype_bytes(op.dtype))
+               for op in cap1.operands}
+    blocks2 = {(_block_key(op.block_shape), dtype_bytes(op.dtype))
+               for op in cap2.operands}
+    p_max = {p: max(gs.param_candidates.get(p, (2048,)))
+             for p in gs.program_params}
+    best = None
+    for (s1, itemsize), (s2, _) in zip(c1.values, c2.values):
+        if ((s1, itemsize) in blocks1 or (s2, itemsize) in blocks2
+                or len(s1) != len(s2)):
+            continue
+        try:
+            tile = tuple(
+                _match_dim(gs.name, f"intermediate dim {j}", v1, v2,
+                           D1, P1, D2, P2)
+                for j, (v1, v2) in enumerate(zip(s1, s2)))
+        except IntrospectError:
+            continue
+        size = itemsize * math.prod(
+            p_max.get(t, D2.get(t)) if isinstance(t, str) else t
+            for t in tile)
+        if best is None or size > best[0]:
+            best = (size, tile, itemsize)
+    if best is None:
+        return None
+    return Operand(name="interm", tile=best[1], deps=(),
+                   dtype_bytes=best[2], vmem_only=True)
 
 
 def _derive_flops(gs: GridSpec, c1, c2,
@@ -227,6 +286,10 @@ def spec_from_kernel(fn, grid_spec: GridSpec, *,
         # One cost walk per capture, shared by the FLOP and constraint
         # passes.
         cost1, cost2 = body_cost(cap1.body), body_cost(cap2.body)
+        interm = _derive_intermediate(grid_spec, cap1, cap2, cost1, cost2,
+                                      D1, P1, D2, P2)
+        if interm is not None:
+            operands = operands + (interm,)
         flops, mxu = _derive_flops(grid_spec, cost1, cost2, axes, P1, P2)
         constraints = _derive_constraints(grid_spec, axes, cap1, cap2,
                                           cost1, cost2, P1, P2)

@@ -26,7 +26,7 @@ from dataclasses import dataclass
 from typing import Any, Mapping
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .gridspec import GridSpec, IntrospectError
 
@@ -152,9 +152,11 @@ def capture_kernel(fn, grid_spec: GridSpec, D: Dims, P: Dims) -> Capture:
 
     operands: list[OperandCapture] = []
     for i, bm in enumerate(gm.block_mappings):
-        shape, dtype = _ref_shape_dtype(body_invars[i].aval)
-        block = tuple(int(b) if b is not None else s
-                      for b, s in zip(bm.block_shape, shape))
+        _, dtype = _ref_shape_dtype(body_invars[i].aval)
+        # Block dims are Pallas ``Blocked(n)`` entries; a ``Squeezed`` dim
+        # has no block size and spans one element.
+        block = tuple(int(getattr(b, "block_size", 1))
+                      for b in bm.block_shape)
         operands.append(OperandCapture(
             block_shape=block,
             dep_axes=_index_map_axes(bm.index_map_jaxpr),

@@ -24,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["flash_attention_pallas"]
 
@@ -142,8 +140,6 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),   # running sum l
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret_mode(interpret),
     )(q, k, v)

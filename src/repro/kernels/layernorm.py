@@ -24,9 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.introspect import GridSpec
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["layernorm_pallas", "layernorm_grid_spec"]
 
@@ -72,8 +70,8 @@ def layernorm_pallas(
         ],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret_mode(interpret),
     )(x, res, g2, b2)
 
 

@@ -19,9 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["matmul_pallas"]
 
@@ -77,8 +75,6 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret_mode(interpret),
     )(x, y)

@@ -19,9 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["moe_gmm_pallas"]
 
@@ -69,9 +67,7 @@ def moe_gmm_pallas(
         out_specs=pl.BlockSpec((1, bg, bn), lambda ex, i, j, l: (ex, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, g, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bg, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel", "parallel", "parallel",
+                                        "arbitrary"),
+        interpret=interpret_mode(interpret),
     )(x, w)

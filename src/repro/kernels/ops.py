@@ -3,8 +3,10 @@
 Each op consults the driver-program registry *immediately before launch*
 (paper Section V-C: one IO call per kernel call, data parameters in, launch
 parameters out), then invokes the Pallas kernel with the chosen BlockSpec
-tiles.  With no driver registered (or on the CPU/dry-run path) the op falls
-back to the static heuristic defaults or the pure-jnp reference.
+tiles.  With no driver registered the op falls back to the static heuristic
+defaults; with ``use_pallas=False`` it runs the pure-jnp reference.  The
+kernels run in the Pallas interpreter only on the CPU backend (the test
+path); on a TPU every op compiles its kernel (``kernels/common.py``).
 
 Because JAX shapes are static at trace time, the "launch" moment is trace
 time: one decision per distinct shape, memoized in the driver's history
@@ -55,6 +57,7 @@ from repro.core.step_plan import active_step_plan
 from repro.trace import trace_span
 
 from . import ref
+from .common import interpret_mode
 from .flash_attention import flash_attention_pallas
 from .matmul import matmul_pallas
 from .moe_gmm import moe_gmm_pallas
@@ -129,8 +132,8 @@ def _batched_matmul(bm: int, bn: int, bk: int, interpret: bool,
 
 
 def matmul(x: jax.Array, y: jax.Array, *, use_pallas: bool = False,
-           interpret: bool = True, out_dtype=None, plan=None,
-           in_graph=None, dims=None) -> jax.Array:
+           out_dtype=None, plan=None, in_graph=None,
+           dims=None) -> jax.Array:
     """Tuned matmul over the last two dims; leading dims are batched.
 
     With ``in_graph=`` (a ``BucketedDispatch``) the operands must be 2-D
@@ -140,6 +143,7 @@ def matmul(x: jax.Array, y: jax.Array, *, use_pallas: bool = False,
     padded k contributes zero partial products, padded rows/cols land in
     the sliced-off tail).
     """
+    interpret = interpret_mode()
     if in_graph is not None:
         if x.ndim != 2:
             raise ValueError("in-graph matmul takes 2-D envelope-padded "
@@ -188,8 +192,7 @@ def flash_attention(
     num_q_heads: int, num_kv_heads: int,
     causal: bool = True, window: int | None = None,
     softcap: float | None = None, scale: float | None = None,
-    use_pallas: bool = False, interpret: bool = True,
-    q_chunk: int | None = None, plan=None,
+    use_pallas: bool = False, q_chunk: int | None = None, plan=None,
     in_graph=None, dims=None,
 ) -> jax.Array:
     """(b*hq, sq, d) x (b*hkv, skv, d)^2 -> (b*hq, sq, d), tuned tiles.
@@ -201,6 +204,7 @@ def flash_attention(
     masked out for every *valid* query row, and padded query rows land in
     the sliced-off tail.
     """
+    interpret = interpret_mode()
     if in_graph is not None:
         if not causal:
             raise ValueError(
@@ -244,8 +248,9 @@ def flash_attention(
 
 
 def moe_gmm(x: jax.Array, w: jax.Array, *, use_pallas: bool = False,
-            interpret: bool = True, plan=None) -> jax.Array:
+            plan=None) -> jax.Array:
     """(e, g, k) @ (e, k, n) -> (e, g, n), tuned tiles."""
+    interpret = interpret_mode()
     if not use_pallas:
         return ref.moe_gmm_ref(x, w)
     e, g, k = x.shape
@@ -260,8 +265,9 @@ def moe_gmm(x: jax.Array, w: jax.Array, *, use_pallas: bool = False,
 
 def ssd_scan(x: jax.Array, dt: jax.Array, B: jax.Array, C: jax.Array,
              A: jax.Array, *, use_pallas: bool = False,
-             interpret: bool = True, plan=None) -> jax.Array:
+             plan=None) -> jax.Array:
     """Mamba-2 SSD scan with tuned chunk length."""
+    interpret = interpret_mode()
     if not use_pallas:
         return ref.ssd_scan_ref(x, dt, B, C, A)
     bh, s, dh = x.shape
@@ -300,8 +306,8 @@ def _colsum_auto(dtype_bytes: int):
 
 def layernorm(x: jax.Array, res: jax.Array, gamma: jax.Array,
               beta: jax.Array, *, eps: float = 1e-6,
-              use_pallas: bool = False, interpret: bool = True,
-              plan=None, in_graph=None, dims=None) -> jax.Array:
+              use_pallas: bool = False, plan=None, in_graph=None,
+              dims=None) -> jax.Array:
     """Fused layernorm + residual with an introspection-tuned row tile.
 
     With ``in_graph=`` the inputs are row-padded to the envelope and
@@ -309,6 +315,7 @@ def layernorm(x: jax.Array, res: jax.Array, gamma: jax.Array,
     zero row (finite: eps keeps the rsqrt bounded) and end up in the
     sliced-off tail, so the valid rows are unaffected.
     """
+    interpret = interpret_mode()
     if in_graph is not None:
         from .layernorm import layernorm_pallas
 
@@ -341,8 +348,7 @@ def layernorm(x: jax.Array, res: jax.Array, gamma: jax.Array,
 
 
 def blocked_colsum(x: jax.Array, *, use_pallas: bool = False,
-                   interpret: bool = True, plan=None,
-                   in_graph=None, dims=None) -> jax.Array:
+                   plan=None, in_graph=None, dims=None) -> jax.Array:
     """Column sums of (r, c) with introspection-tuned (br, bc) tiles.
 
     With ``in_graph=`` the input is envelope-padded and ``dims`` carries
@@ -350,6 +356,7 @@ def blocked_colsum(x: jax.Array, *, use_pallas: bool = False,
     and padded columns land in the sliced-off tail, so the result is
     exact.
     """
+    interpret = interpret_mode()
     if in_graph is not None:
         from .reduce import colsum_pallas
 

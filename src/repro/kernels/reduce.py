@@ -23,9 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.introspect import GridSpec
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["colsum_pallas", "colsum_grid_spec"]
 
@@ -64,9 +62,8 @@ def colsum_pallas(
         out_specs=pl.BlockSpec((8, bc), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((8, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, bc), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret_mode(interpret),
     )(x)
 
 

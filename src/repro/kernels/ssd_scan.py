@@ -25,9 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from .common import compiler_params, interpret_mode
 
 __all__ = ["ssd_scan_pallas"]
 
@@ -39,22 +37,32 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)        # (L, dh)
-    dt = dt_ref[0].astype(jnp.float32)      # (L, 128) broadcast; col 0 valid
+    dt = dt_ref[0].astype(jnp.float32)      # (L, 128): dt_t on every lane
     B = b_ref[0].astype(jnp.float32)        # (L, n)
     C = c_ref[0].astype(jnp.float32)        # (L, n)
-    a = a_ref[0, 0, 0]                      # scalar decay rate A (negative)
+    a = a_ref[0]                            # (1, 128): decay rate A (< 0)
 
-    dt0 = dt[:, :1]                          # (L, 1)
-    adt = a * dt0                            # (L, 1) log-decay per step
-    cum = jnp.cumsum(adt, axis=0)            # (L, 1) inclusive cumsum
-
-    # Intra-chunk quadratic term: scores[i, j] = exp(cum_i - cum_j) * dt_j
-    # for i >= j (the decay from step j+1..i applied to input at j).
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = li >= lj
+    # Inclusive cumulative log-decay as a lower-triangular ones matmul (the
+    # SSD form: Mosaic lowers no cumsum).  HIGHEST keeps the f32 sum exact
+    # instead of rounding it through bf16 MXU passes.
+    cum = jax.lax.dot_general(
+        jnp.where(causal, 1.0, 0.0), a * dt, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                # (L, 128)
+    cum0 = cum[:, :1]                                      # (L, 1)
+    dt0 = dt[:, :1]                                        # (L, 1)
+
+    # Intra-chunk quadratic term: scores[i, j] = exp(cum_i - cum_j) * dt_j
+    # for i >= j (the decay from step j+1..i applied to input at j).  The
+    # row forms come from lane-aligned (L, 128) transposes.
+    cum_row = cum.T[:1, :]                                 # (1, L)
+    dt_row = dt.T[:1, :]                                   # (1, L)
     # mask the exponent before exp: i < j entries would overflow to inf
-    expnt = jnp.where(li >= lj, cum - cum.T, -1e30)
-    gate = jnp.exp(expnt) * jnp.where(li >= lj, dt0.T, 0.0)
+    expnt = jnp.where(causal, cum0 - cum_row, -1e30)
+    gate = jnp.exp(expnt) * jnp.where(causal, dt_row, 0.0)
     scores = jax.lax.dot_general(
         C, B, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * gate        # (L, L)
@@ -65,16 +73,19 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, state_ref,
     # Inter-chunk term: y_i += C_i @ (exp(cum_i) * state_in).
     state_in = state_ref[...]                              # (n, dh)
     y_inter = jax.lax.dot_general(
-        C * jnp.exp(cum), state_in, (((1,), (0,)), ((), ())),
+        C * jnp.exp(cum0), state_in, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                # (L, dh)
 
     o_ref[0] = (y_intra + y_inter).astype(o_ref.dtype)
 
     # State update: state_out = exp(total) * state_in
     #             + sum_j exp(total - cum_j) * dt_j * outer(B_j, x_j).
-    total = cum[-1:, :]                                    # (1, 1)
-    w = jnp.exp(total - cum) * dt0                         # (L, 1)
-    state_ref[...] = jnp.exp(total[0, 0]) * state_in + jax.lax.dot_general(
+    total = cum0[chunk - 1:, :]                            # (1, 1)
+    w = jnp.exp(total - cum0) * dt0                        # (L, 1)
+    # Broadcast the chunk decay along lanes first, then sublanes: Mosaic
+    # will not broadcast a (1, 1) value in both at once.
+    decay = jnp.exp(total * jnp.ones((1, state_in.shape[1]), jnp.float32))
+    state_ref[...] = decay * state_in + jax.lax.dot_general(
         B * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                # (n, dh)
 
@@ -112,8 +123,6 @@ def ssd_scan_pallas(
         out_specs=pl.BlockSpec((1, chunk, dh), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret_mode(interpret),
     )(x, dt3, B, C, a3)

@@ -50,8 +50,6 @@ def _compile_cell(cfg, preset, mesh):
         lowered = bundle.lower()
         compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)

@@ -12,12 +12,8 @@ __all__ = ["make_production_mesh", "make_mesh", "mesh_chips"]
 
 
 def _make(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # axis_types / AxisType only exist on newer jax; older versions default
-    # to the same (auto) behavior.
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -36,6 +36,7 @@ import jax
 
 from repro.configs import get_config
 from repro.distributed.sharding import Sharder, decode_rules
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model, init_params
 from repro.serving import Request, ServingEngine
 
@@ -194,6 +195,7 @@ def main() -> None:
                          "the duration of the run; implies --telemetry")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     ledger = None
     if args.ledger:

@@ -29,6 +29,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapePreset
 from repro.data import Prefetcher, SyntheticConfig, SyntheticStream
 from repro.distributed import Watchdog, shardings_for_specs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import build_step
 from repro.models import init_params
 from repro.optim import AdamWConfig, adamw_init, warmup_cosine
@@ -157,6 +158,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     preset = ShapePreset("cli", "train", args.seq, args.batch)
     loop = TrainLoop(cfg, preset, mesh=None, ckpt_dir=args.ckpt_dir,

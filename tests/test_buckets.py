@@ -291,8 +291,7 @@ class TestInGraphOps:
         @jax.jit
         def step(xp, yp, dims):
             traces["n"] += 1
-            return ops.matmul(xp, yp, in_graph=disp, dims=dims,
-                              interpret=True)
+            return ops.matmul(xp, yp, in_graph=disp, dims=dims)
 
         rng = np.random.default_rng(0)
         for (m, n, k) in [(40, 200, 200), (64, 256, 256), (100, 130, 250),

@@ -62,8 +62,6 @@ class TestDryRunCell(object):
                 compiled = lowered.compile()
             mem = compiled.memory_analysis()
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):   # older jax returns a list
-                ca = ca[0]
             st = collective_bytes(compiled.as_text())
             assert ca["flops"] > 0
             assert mem.temp_size_in_bytes > 0

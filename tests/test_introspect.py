@@ -277,11 +277,11 @@ class TestAutoKernelPipeline:
         res = jax.random.normal(jax.random.PRNGKey(1), (64, 256), jnp.float32)
         g = jax.random.normal(jax.random.PRNGKey(2), (256,), jnp.float32)
         b = jax.random.normal(jax.random.PRNGKey(3), (256,), jnp.float32)
-        y = ops.layernorm(x, res, g, b, use_pallas=True, interpret=True)
+        y = ops.layernorm(x, res, g, b, use_pallas=True)
         np.testing.assert_allclose(
             np.asarray(y), np.asarray(ref.layernorm_ref(x, res, g, b)),
             atol=1e-5)
-        s = ops.blocked_colsum(x, use_pallas=True, interpret=True)
+        s = ops.blocked_colsum(x, use_pallas=True)
         np.testing.assert_allclose(
             np.asarray(s), np.asarray(ref.colsum_ref(x)), rtol=1e-5)
 
@@ -329,7 +329,7 @@ class TestDtypeTableDedup:
     def test_introspected_dtypes_from_table(self, pairs):
         _, intro = pairs["ssd_scan_h64_n128"]
         assert [op.dtype_bytes for op in intro.operands] == \
-            [2, 4, 2, 2, 4, 2, 4]
+            [2, 4, 2, 2, 4, 2, 4, 4]
 
 
 class TestIntrospectErrors:

@@ -437,7 +437,7 @@ class TestOpsDispatch:
         events = []
         set_choice_listener(events.append)
         with use_step_plan(plan):
-            out = ops.matmul(x, y, use_pallas=True, interpret=True)
+            out = ops.matmul(x, y, use_pallas=True)
         assert out.shape == (16, 128)
         np.testing.assert_allclose(np.asarray(out), 128.0)
         assert events == []                 # dispatched from the plan

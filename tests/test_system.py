@@ -164,7 +164,7 @@ class TestKlaraptorIntegration:
         from repro.kernels import ops
         x = jnp.asarray(np.random.RandomState(0).randn(256, 256), jnp.float32)
         w = jnp.asarray(np.random.RandomState(1).randn(256, 256), jnp.float32)
-        out = ops.matmul(x, w, use_pallas=True, interpret=True)
+        out = ops.matmul(x, w, use_pallas=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w),
                                    atol=1e-3)
         hist = build.driver.namespace["_HISTORY"]

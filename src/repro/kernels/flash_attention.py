@@ -142,4 +142,6 @@ def flash_attention_pallas(
         ],
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret_mode(interpret),
+        # The device op's name, whatever jitted function wraps the call.
+        name="flash_attention_pallas",
     )(q, k, v)

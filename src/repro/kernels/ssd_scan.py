@@ -125,4 +125,6 @@ def ssd_scan_pallas(
         scratch_shapes=[pltpu.VMEM((n, dh), jnp.float32)],
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret_mode(interpret),
+        # The device op's name, whatever jitted function wraps the call.
+        name="ssd_scan_pallas",
     )(x, dt3, B, C, a3)

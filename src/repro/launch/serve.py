@@ -21,6 +21,9 @@ writes a Chrome trace-event file at exit (open in ui.perfetto.dev);
 refits -- implies --telemetry) for later replay with
 ``python -m repro.launch.status --ledger run.jsonl``.
 
+After the run it prints the median and p90 time to first token, from
+the engine's request stamps (submit to first token on the host).
+
 ``--async`` serves through the engine's async front-end (scheduler
 thread, thread-safe submit, chunked jitted prefill -- see
 serving/engine.py) and prints the compile counts afterwards; ``--buckets``
@@ -33,6 +36,7 @@ from __future__ import annotations
 import argparse
 
 import jax
+import numpy as np
 
 from repro.configs import get_config
 from repro.distributed.sharding import Sharder, decode_rules
@@ -42,7 +46,18 @@ from repro.serving import Request, ServingEngine
 
 __all__ = ["main", "build_engine", "build_telemetry",
            "default_plan_envelope", "default_bucket_lattices",
-           "build_auto_kernels"]
+           "build_auto_kernels", "time_to_first_token_ms"]
+
+
+def time_to_first_token_ms(requests) -> dict | None:
+    """Median and p90 of submit-to-first-token, in ms, over the requests
+    that emitted a token; ``None`` if none did."""
+    ttft = [1e3 * (r.t_first - r.t_submit) for r in requests
+            if r.t_first is not None and r.t_submit is not None]
+    if not ttft:
+        return None
+    return {"median": float(np.median(ttft)),
+            "p90": float(np.percentile(ttft, 90)), "requests": len(ttft)}
 
 
 def default_plan_envelope(batch: int, max_seq: int) -> dict:
@@ -265,6 +280,10 @@ def main() -> None:
     finished = engine.run_async() if args.run_async else engine.run()
     for r in sorted(finished, key=lambda r: r.rid):
         print(f"req {r.rid}: prompt={r.prompt} -> output={r.output}")
+    ttft = time_to_first_token_ms(finished)
+    if ttft is not None:
+        print(f"time to first token: median {ttft['median']:.1f} ms, "
+              f"p90 {ttft['p90']:.1f} ms over {ttft['requests']} requests")
     if args.run_async:
         cc = engine.compile_counts
         print(f"async front-end: {cc['decode_step']} decode-step compile(s), "

@@ -26,6 +26,33 @@ Two front-ends share those slots:
   steps -- the "one compiled step serves every shape" invariant is
   ``compile_counts["decode_step"] == 1`` across a whole traffic mix.
 
+The scheduler's host phases are ``repro.trace`` spans, so a profiler
+trace (or an installed ``Tracer``) shows which phase each device gap
+falls in.  No span waits on the device; with neither sink on, each costs
+a flag check.
+
+  ============================  ===========================================
+  span                          host phase (attributes)
+  ============================  ===========================================
+  ``engine.wait``               asleep on the condition with no work
+  ``engine.prefill``            admitting one request to a slot (``rid``,
+                                ``slot``, ``tokens`` prefilled, ``queued``
+                                requests still pending)
+  ``engine.prefill_chunk``      one prefill dispatch: ``lanes`` steps of
+                                the ``slots``-wide decode step, each
+                                advancing ``slot`` alone
+  ``engine.decode``             one decode round over ``slots`` slots,
+                                ``active`` of them holding a request
+  ``engine.sample``             inside decode: key split, the greedy and
+                                sampled tokens, their copy to the host
+  ``engine.emit``               inside decode: bucket replay and per-slot
+                                bookkeeping
+  ============================  ===========================================
+
+Each ``Request`` carries its lifecycle on the host's ``perf_counter``
+clock: ``t_submit`` (queued), ``t_admit`` (taken into a slot),
+``t_first`` (first token on the host) and ``t_done`` (finished).
+
 Passing ``bucket_lattices=`` (kernel name -> ``core.buckets.BucketLattice``
 or a prebuilt ``core.device_plan.BucketedDispatch``) opts the engine into
 per-step bucket accounting: each decode step replays the in-graph bucket
@@ -71,6 +98,7 @@ envelope.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 
 import jax
@@ -80,7 +108,7 @@ import numpy as np
 from repro.core.device_model import V5E
 from repro.core.driver import choose_or_default, warm_start_from_cache
 from repro.serving.sampling import greedy, sample
-from repro.trace import trace_span, tracing
+from repro.trace import trace_span
 
 __all__ = ["Request", "ServingEngine"]
 
@@ -93,6 +121,11 @@ class Request:
     temperature: float = 0.0
     output: list[int] = field(default_factory=list)
     done: bool = False
+    # Lifecycle stamps, ``time.perf_counter()`` seconds, each set once.
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_first: float | None = None
+    t_done: float | None = None
 
 
 class ServingEngine:
@@ -233,6 +266,7 @@ class ServingEngine:
     # -- public API -----------------------------------------------------------
     def submit(self, req: Request) -> None:
         """Queue a request; thread-safe, wakes the scheduler if running."""
+        req.t_submit = time.perf_counter()
         with self._cv:
             self.pending.append(req)
             self._cv.notify_all()
@@ -327,7 +361,8 @@ class ServingEngine:
                     return
                 if not self._has_work():
                     self._cv.notify_all()
-                    self._cv.wait(0.05)
+                    with trace_span("engine.wait"):
+                        self._cv.wait(0.05)
                     continue
             self._fill_slots(chunked=True)
             self._decode_once()
@@ -422,28 +457,16 @@ class ServingEngine:
         """One jitted step under the active step plan (rebuilt first if the
         registry generation moved -- the rebuild re-resolves against the
         new state, so a fresh override or refit takes effect on the very
-        next trace).
-
-        When a tracer is installed, the step is wrapped in an
-        ``engine.step`` span and the output is blocked on before the span
-        closes, so device time is attributed to the step that spent it,
-        not just the async dispatch.  With no tracer, dispatch stays
-        async and span-free.
+        next trace).  The dispatch is asynchronous.
         """
         if self._step_plan is not None and self._step_plan.stale():
             self._refresh_step_plan()
-        with trace_span("engine.step",
-                        step_plan=self._step_plan is not None):
-            if self._step_plan is None:
-                out = self._step(self.params, tok, ps, self.cache)
-            else:
-                from repro.core.step_plan import use_step_plan
+        if self._step_plan is None:
+            return self._step(self.params, tok, ps, self.cache)
+        from repro.core.step_plan import use_step_plan
 
-                with use_step_plan(self._step_plan):
-                    out = self._step(self.params, tok, ps, self.cache)
-            if tracing():
-                out = jax.block_until_ready(out)
-        return out
+        with use_step_plan(self._step_plan):
+            return self._step(self.params, tok, ps, self.cache)
 
     def _fill_slots(self, chunked: bool = False) -> None:
         for s in range(self.batch):
@@ -453,9 +476,11 @@ class ServingEngine:
                 if not self.pending:
                     break
                 req = self.pending.pop(0)
+                queued = len(self.pending)
+            req.t_admit = time.perf_counter()
             # prefill the prompt through the shared decode step
-            with trace_span("engine.prefill", rid=req.rid,
-                            tokens=len(req.prompt) - 1, chunked=chunked):
+            with trace_span("engine.prefill", rid=req.rid, slot=s,
+                            tokens=len(req.prompt) - 1, queued=queued):
                 if chunked:
                     self._prefill_chunked(s, req.prompt)
                 else:
@@ -514,12 +539,13 @@ class ServingEngine:
         staleness contract as ``_run_step``)."""
         if self._step_plan is not None and self._step_plan.stale():
             self._refresh_step_plan()
-        args = (self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(pos0, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32),
-                jnp.asarray(base_tok), jnp.asarray(base_pos))
         with trace_span("engine.prefill_chunk", slot=int(slot),
-                        n_valid=int(n_valid)):
+                        slots=self.batch, lanes=int(n_valid)):
+            args = (self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(pos0, jnp.int32),
+                    jnp.asarray(n_valid, jnp.int32),
+                    jnp.asarray(base_tok), jnp.asarray(base_pos))
             if self._step_plan is None:
                 self.cache = self._prefill_step(*args)
             else:
@@ -527,42 +553,56 @@ class ServingEngine:
 
                 with use_step_plan(self._step_plan):
                     self.cache = self._prefill_step(*args)
-            if tracing():
-                self.cache = jax.block_until_ready(self.cache)
 
     def _single(self, slot: int, token: int, pos: int) -> None:
-        tok = np.array(self.slot_last, np.int32)
-        ps = np.array(self.slot_pos, np.int32)
-        tok[slot] = token
-        ps[slot] = pos
-        _, self.cache = self._run_step(jnp.asarray(tok), jnp.asarray(ps))
+        """One prompt token through the whole decode step: a one-lane
+        prefill dispatch."""
+        with trace_span("engine.prefill_chunk", slot=slot, slots=self.batch,
+                        lanes=1):
+            tok = np.array(self.slot_last, np.int32)
+            ps = np.array(self.slot_pos, np.int32)
+            tok[slot] = token
+            ps[slot] = pos
+            _, self.cache = self._run_step(jnp.asarray(tok), jnp.asarray(ps))
 
     def _decode_once(self) -> None:
         active = [s for s in range(self.batch) if self.slot_req[s] is not None]
         if not active:
             return
-        with trace_span("engine.decode", active=len(active)):
+        with trace_span("engine.decode", active=len(active),
+                        slots=self.batch):
             logits, self.cache = self._run_step(
                 jnp.asarray(self.slot_last), jnp.asarray(self.slot_pos))
-            self.key, sub = jax.random.split(self.key)
-            temps = {r.temperature for s, r in enumerate(self.slot_req)
-                     if r is not None}
-            greedy_tok = np.asarray(greedy(logits))
-            sampled_tok = np.asarray(sample(logits, sub, temperature=max(
-                temps | {1.0})))
-            self._note_bucket_stats(active)
-            for s in active:
-                req = self.slot_req[s]
-                nxt = int(greedy_tok[s] if req.temperature <= 0.0
-                          else sampled_tok[s])
-                req.output.append(nxt)
-                self.slot_pos[s] += 1
-                self.slot_last[s] = nxt
-                self.slot_budget[s] -= 1
-                if (nxt == self.eos_id or self.slot_budget[s] <= 0
-                        or self.slot_pos[s] >= self.max_seq - 1):
-                    req.done = True
-                    with self._cv:
-                        self.finished.append(req)
-                        self.slot_req[s] = None  # freed: continuous batching
-                        self._cv.notify_all()
+            with trace_span("engine.sample"):
+                self.key, sub = jax.random.split(self.key)
+                temps = {r.temperature for s, r in enumerate(self.slot_req)
+                         if r is not None}
+                greedy_tok = np.asarray(greedy(logits))
+                sampled_tok = np.asarray(sample(logits, sub, temperature=max(
+                    temps | {1.0})))
+            with trace_span("engine.emit"):
+                self._emit(active, greedy_tok, sampled_tok)
+
+    def _emit(self, active: list[int], greedy_tok, sampled_tok) -> None:
+        """Append each active slot's token, and free the slots whose
+        request finished."""
+        self._note_bucket_stats(active)
+        now = time.perf_counter()
+        for s in active:
+            req = self.slot_req[s]
+            nxt = int(greedy_tok[s] if req.temperature <= 0.0
+                      else sampled_tok[s])
+            req.output.append(nxt)
+            if req.t_first is None:
+                req.t_first = now
+            self.slot_pos[s] += 1
+            self.slot_last[s] = nxt
+            self.slot_budget[s] -= 1
+            if (nxt == self.eos_id or self.slot_budget[s] <= 0
+                    or self.slot_pos[s] >= self.max_seq - 1):
+                req.done = True
+                req.t_done = now
+                with self._cv:
+                    self.finished.append(req)
+                    self.slot_req[s] = None  # freed: continuous batching
+                    self._cv.notify_all()

@@ -2,13 +2,15 @@
 
 The observability layer for the tune->serve pipeline: ``trace_span``
 spans with thread-local nesting (zero-cost when no ``Tracer`` is
-installed), a bounded flight-recorder ring with per-name duration
-histograms, an append-only JSONL ``Ledger`` of decisions / probes /
-drift / refits / spans, and Chrome trace-event export for Perfetto.
+installed and no profiler records), written into a running JAX
+profiler's trace as annotations, a bounded flight-recorder ring with
+per-name duration histograms, an append-only JSONL ``Ledger`` of
+decisions / probes / drift / refits / spans, and Chrome trace-event
+export for Perfetto.
 
-Intentionally stdlib-only and imported from nothing inside ``repro``,
-so every layer (core, introspect, telemetry, serving, launch) can
-instrument itself without import cycles.
+Imports the standard library and ``jax.profiler`` alone, and nothing
+inside ``repro``, so every layer (core, introspect, telemetry, serving,
+launch) can instrument itself without import cycles.
 """
 
 from .chrome import chrome_trace, write_chrome_trace

@@ -3,9 +3,19 @@
 KLARAPTOR's runtime half exists because the paper could *see* what every
 launch cost (CUPTI); this module is the host-side equivalent for the whole
 reproduction pipeline.  A ``Span`` is one timed region with attributes --
-``trace_span("collect.batch", kernel=..., strategy=...)`` -- nested per
-thread, timed on the monotonic clock, and recorded into a process-wide
-``Tracer``:
+``trace_span("collect.batch", kernel=..., strategy=...)`` -- with two
+sinks.
+
+While a JAX profiler session is recording, every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, with its attributes as
+the event's stats (``Span.set`` forwards to ``set_metadata``).  The span
+then lands in the profile's host plane, on the same clock as the device's
+operations, so a trace shows which host phase each device gap falls in.
+Attribute values should be numbers or strings without commas: the
+profiler's encoding splits on them.
+
+With a ``Tracer`` installed, the span is also nested per thread, timed on
+the monotonic clock, and recorded into that process-wide ``Tracer``:
 
   * a bounded ring of completed spans (the flight recorder -- always
     queryable, never unbounded),
@@ -15,9 +25,11 @@ thread, timed on the monotonic clock, and recorded into a process-wide
     record survives the process.
 
 Zero-cost-when-off discipline (same contract as the driver's listener-gated
-``_notify``): with no tracer installed, ``trace_span`` is one module-global
-``is None`` check returning a shared no-op span -- no allocation beyond the
-kwargs dict, no clock read, no lock.  Instrumented hot paths stay hot.
+``_notify``): with no tracer installed and no profiler recording,
+``trace_span`` is one module-global ``is None`` check and one
+``TraceAnnotation.is_enabled()`` call returning a shared no-op span -- no
+allocation beyond the kwargs dict, no clock read, no lock.  Instrumented
+hot paths stay hot.  No sink waits on the device: a span times the host.
 """
 
 from __future__ import annotations
@@ -28,10 +40,13 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 # Bound once: the enabled span path runs these on every enter/exit, and a
 # module-global load beats an attribute chain in the hot path.
 _monotonic_ns = time.monotonic_ns
 _bisect_left = bisect.bisect_left
+_profiling = TraceAnnotation.is_enabled
 
 __all__ = ["HISTOGRAM_BOUNDS_S", "NULL_SPAN", "Span", "SpanHistogram",
            "Tracer", "get_tracer", "set_tracer", "trace_span", "traced",
@@ -85,7 +100,7 @@ class Span:
     """
 
     __slots__ = ("name", "attrs", "t0_ns", "t1_ns", "tid", "thread_name",
-                 "depth", "_tracer")
+                 "depth", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         # Timing/thread slots are written by __enter__/__exit__ -- a span is
@@ -94,6 +109,7 @@ class Span:
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._annotation = None
 
     @property
     def duration_s(self) -> float:
@@ -102,9 +118,14 @@ class Span:
     def set(self, **attrs) -> "Span":
         """Attach attributes to the running span (chains)."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         local = self._tracer._local
         try:
             stack = local.stack
@@ -129,6 +150,12 @@ class Span:
             stack.pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
+        ann = self._annotation
+        if ann is not None:
+            if exc_type is not None:
+                ann.set_metadata(error=exc_type.__name__)
+            ann.__exit__(None, None, None)
+            self._annotation = None
         self.tid = local.tid
         self.thread_name = local.tname
         # Recording is inlined and lock-free: the ring append is
@@ -183,6 +210,15 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _ProfilerSpan(TraceAnnotation):
+    """The span while a profiler records and no ``Tracer`` is installed:
+    the profiler's annotation alone, with the span's ``set``."""
+
+    def set(self, **attrs) -> "_ProfilerSpan":
+        self.set_metadata(**attrs)
+        return self
 
 
 class Tracer:
@@ -330,8 +366,7 @@ def get_tracer() -> Tracer | None:
 
 
 def tracing() -> bool:
-    """Is a tracer installed?  (For gating work that only serves tracing,
-    e.g. ``block_until_ready`` so device time lands inside the span.)"""
+    """Is a tracer installed?"""
     return _active is not None
 
 
@@ -339,21 +374,24 @@ def trace_span(name: str, **attrs):
     """Open a span named ``name`` with the given attributes.
 
     The workhorse context manager: ``with trace_span("fit", kernel=k):``.
-    With no tracer installed this returns the shared no-op ``NULL_SPAN``
-    and the block runs untimed at (near-)zero cost.
+    With no tracer installed it returns the profiler's annotation alone
+    while a profiler records, else the shared no-op ``NULL_SPAN``, and the
+    block runs untimed at (near-)zero cost.
     """
     t = _active
-    if t is None:
-        return NULL_SPAN
-    return Span(t, name, attrs)
+    if t is not None:
+        return Span(t, name, attrs)
+    if _profiling():
+        return _ProfilerSpan(name, **attrs)
+    return NULL_SPAN
 
 
 def traced(name: str | None = None, **attrs):
     """Decorator form: time every call of the wrapped function.
 
     ``@traced()`` uses the function's qualname; ``@traced("collect")``
-    names the span explicitly.  The disabled path adds one global load and
-    one ``is None`` check per call.
+    names the span explicitly.  The disabled path adds one global load,
+    one ``is None`` check and one ``is_enabled()`` call per call.
     """
     def deco(fn):
         span_name = name if name is not None else fn.__qualname__
@@ -361,9 +399,13 @@ def traced(name: str | None = None, **attrs):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             t = _active
-            if t is None:
+            if t is not None:
+                span = Span(t, span_name, dict(attrs))
+            elif _profiling():
+                span = _ProfilerSpan(span_name, **attrs)
+            else:
                 return fn(*args, **kwargs)
-            with Span(t, span_name, dict(attrs)):
+            with span:
                 return fn(*args, **kwargs)
         return wrapper
     return deco

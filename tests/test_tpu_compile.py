@@ -8,10 +8,14 @@ chooses at that shape, and check that a Mosaic kernel (``tpu_custom_call``)
 came out.  Widths: llama3.2-1b for the ops on its path, mamba2-130m for the
 SSD scan, qwen3-moe-235b-a22b for the expert matmul, plus the matmul and
 flash shapes where the tuner used to choose tiles the compiler refused.
+The tuned kernels are also compiled without their jitted wrappers, to
+check that each keeps the device op name the benchmark's readers match.
 """
 
 import functools
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +27,16 @@ from repro.core import (Klaraptor, V5eSimulator, flash_attention_spec,
                         matmul_spec, moe_gmm_spec, ssd_scan_spec)
 from repro.introspect import spec_from_kernel
 from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.kernels.layernorm import layernorm_grid_spec, layernorm_pallas
 from repro.kernels.reduce import colsum_grid_spec, colsum_pallas
 from repro.models.layers import moe_capacity
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+from bench.readers import KERNEL_OPS  # noqa: E402
 
 LLAMA = get_config("llama3.2-1b")
 MAMBA = get_config("mamba2-130m")
@@ -136,3 +147,43 @@ def test_compiles_to_mosaic(case, one_chip, plan, monkeypatch):
     assert kernel in plan.chosen, f"{kernel} was never dispatched"
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{kernel} with {plan.chosen[kernel]}: no Mosaic kernel")
+
+
+INTERNLM2 = get_config("internlm2-1.8b")
+# Each tuned kernel called through its ``pallas_call`` alone, inside a
+# jitted function of another name, at the widths of its benchmark model.
+UNWRAPPED = {
+    "flash_attention": (
+        "repro.kernels.flash_attention",
+        lambda q, k, v: flash_attention_pallas.__wrapped__(
+            q, k, v, num_q_heads=INTERNLM2.n_heads,
+            num_kv_heads=INTERNLM2.n_kv_heads),
+        [(INTERNLM2.n_heads, 1024, INTERNLM2.head_dim, BF),
+         (INTERNLM2.n_kv_heads, 1024, INTERNLM2.head_dim, BF),
+         (INTERNLM2.n_kv_heads, 1024, INTERNLM2.head_dim, BF)]),
+    "ssd_scan": (
+        "repro.kernels.ssd_scan",
+        lambda x, dt, B, C, A: ssd_scan_pallas.__wrapped__(x, dt, B, C, A),
+        [(_BH, 1024, _DH, BF), (_BH, 1024, F32), (_BH, 1024, _N, BF),
+         (_BH, 1024, _N, BF), (_BH, F32)]),
+}
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+) = ", re.M)
+
+
+@pytest.mark.parametrize("kernel", sorted(UNWRAPPED))
+def test_kernel_op_keeps_its_name_unwrapped(kernel, one_chip, monkeypatch):
+    module, fn, shapes = UNWRAPPED[kernel]
+    monkeypatch.setattr(f"{module}.interpret_mode",
+                        lambda requested=True: False)
+    args = [jax.ShapeDtypeStruct(s[:-1], s[-1], sharding=one_chip)
+            for s in shapes]
+
+    def step(*xs):
+        return fn(*xs)
+    text = jax.jit(step).lower(*args).compile().as_text()
+    names = _INSTRUCTION.findall(text)
+    calls = [n for n, line in zip(names, _INSTRUCTION.split(text)[2::2])
+             if "custom-call(" in line.split("\n", 1)[0]]
+    assert any(re.search(KERNEL_OPS[kernel], n) for n in names), (
+        f"no instruction matches {KERNEL_OPS[kernel]!r}; custom calls: "
+        f"{calls}")

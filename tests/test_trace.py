@@ -135,6 +135,74 @@ class TestSpans:
         assert rows[0]["max_s"] >= rows[0]["mean_s"] > 0
 
 
+def _profiled_events(trace_dir, prefix):
+    """(name, stats) of each host-plane event whose name starts with
+    ``prefix`` in the profile written under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefix)]
+
+
+class TestProfilerSink:
+    """While a JAX profiler records, spans land in its trace."""
+
+    @pytest.mark.parametrize("with_tracer", [False, True])
+    def test_span_reads_back_from_the_profile(self, tmp_path, with_tracer):
+        import jax
+
+        tracer = Tracer().install() if with_tracer else None
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace_span("probe.outer", active=16) as sp:
+                sp.set(extra=3)
+                with trace_span("probe.inner", label="x"):
+                    pass
+
+            @traced("probe.deco", n=2)
+            def f():
+                return 1
+            assert f() == 1
+        finally:
+            jax.profiler.stop_trace()
+            set_tracer(None)
+        events = dict(_profiled_events(tmp_path, "probe."))
+        assert events == {"probe.outer": {"active": 16, "extra": 3},
+                          "probe.inner": {"label": "x"},
+                          "probe.deco": {"n": 2}}
+        if with_tracer:
+            assert sorted(s.name for s in tracer.spans()) == \
+                ["probe.deco", "probe.inner", "probe.outer"]
+            outer = [s for s in tracer.spans() if s.name == "probe.outer"]
+            assert outer[0].attrs == {"active": 16, "extra": 3}
+
+    def test_error_reaches_the_profile(self, tmp_path):
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with Tracer():
+                with pytest.raises(ValueError):
+                    with trace_span("probe.boom", k=1):
+                        raise ValueError("x")
+        finally:
+            jax.profiler.stop_trace()
+        assert _profiled_events(tmp_path, "probe.") == \
+            [("probe.boom", {"k": 1, "error": "ValueError"})]
+
+    def test_neither_sink_gives_the_null_span(self):
+        from jax.profiler import TraceAnnotation
+
+        assert not TraceAnnotation.is_enabled() and not tracing()
+        assert trace_span("engine.decode", active=1) is NULL_SPAN
+
+
 class TestChromeExport:
     def test_chrome_trace_schema_and_containment(self, tmp_path):
         with Tracer() as tr:

@@ -115,6 +115,8 @@ def spec_fingerprint(spec: KernelSpec) -> dict:
         "operands": [[op.name, list(op.tile), list(op.deps),
                       op.dtype_bytes, op.is_output, op.vmem_only]
                      for op in spec.operands],
+        # A number, or the text of a P-dependent FLOP expression: a spec
+        # that changes either never finds the other's drivers.
         "flops_per_point": spec.flops_per_point,
         "constraints": list(spec.constraints),
         "mxu_fraction": spec.mxu_fraction,

@@ -40,6 +40,7 @@ from .rational_program import Ceil, Const, Expr, Floor, Max, Min, ceil_div, var
 __all__ = [
     "Operand", "GridAxis", "KernelSpec", "CandidateTable", "SpecError",
     "matmul_spec", "flash_attention_spec", "moe_gmm_spec", "ssd_scan_spec",
+    "ssd_scan_flops",
     "POLYBENCH_SUITE", "polybench_suite",
 ]
 
@@ -47,9 +48,11 @@ Dims = Mapping[str, int]
 
 
 class SpecError(ValueError):
-    """A kernel-spec constraint string is malformed or references a symbol
-    that is neither a data parameter, a program parameter, nor one of the
-    evaluation built-ins (``vmem``, ``math``, ``np``).
+    """A kernel-spec expression (a constraint, or a FLOP density that
+    depends on the parameters) is malformed, fails to evaluate, or
+    references a symbol that is neither a data parameter, a program
+    parameter, nor one of the evaluation built-ins (``math``, ``np``, and
+    ``vmem`` in constraints).
 
     Constraint strings are user input (the paper's Section V-A configuration
     files); a typo'd symbol used to surface as a bare ``NameError`` swallowed
@@ -59,36 +62,34 @@ class SpecError(ValueError):
 
 
 @functools.lru_cache(maxsize=4096)
-def _constraint_names(constraint: str) -> frozenset[str]:
-    """Bare symbols referenced by a constraint expression (cached: the AST
-    parse would otherwise re-run for every feasible_mask call of every
+def _expression_names(expr: str) -> frozenset[str]:
+    """Bare symbols referenced by a spec expression (cached: the AST parse
+    would otherwise re-run for every feasible_mask call of every
     collect/search loop).  Only ``Name`` loads count, so ``math.ceil``
     checks ``math``, not ``ceil``.  Raises SpecError on syntax errors."""
     try:
-        tree = ast.parse(constraint, mode="eval")
+        tree = ast.parse(expr, mode="eval")
     except SyntaxError as e:
         raise SpecError(
-            f"constraint {constraint!r} is not a valid Python expression: "
+            f"expression {expr!r} is not a valid Python expression: "
             f"{e.msg}") from e
     return frozenset(n.id for n in ast.walk(tree)
                      if isinstance(n, ast.Name))
 
 
-def _check_constraint_symbols(constraint: str, known: set[str],
-                              spec_name: str) -> None:
-    """Raise SpecError naming the offending symbol(s) of a constraint."""
+def _check_symbols(expr: str, known: set[str], spec_name: str,
+                   what: str) -> None:
+    """Raise SpecError naming the offending symbol(s) of an expression."""
     try:
-        names = _constraint_names(constraint)
+        names = _expression_names(expr)
     except SpecError as e:
-        raise SpecError(f"spec {spec_name!r}: {e}") from None
+        raise SpecError(f"spec {spec_name!r}: {what}: {e}") from None
     unknown = sorted(names - known)
     if unknown:
         raise SpecError(
-            f"constraint {constraint!r} of spec {spec_name!r} references "
-            f"unknown symbol(s) {', '.join(map(repr, unknown))}; known "
-            f"symbols are the data/program parameters "
-            f"{sorted(known - {'vmem', 'math', 'np'})} plus 'vmem', "
-            f"'math' and 'np'")
+            f"{what} {expr!r} of spec {spec_name!r} references unknown "
+            f"symbol(s) {', '.join(map(repr, unknown))}; known symbols are "
+            f"{sorted(known - {'math', 'np'})} plus 'math' and 'np'")
 
 
 def _pad(x, m):
@@ -212,9 +213,11 @@ class KernelSpec:
     program_params: tuple[str, ...]
     grid: tuple[GridAxis, ...]
     operands: tuple[Operand, ...]
-    flops_per_point: float                  # FLOPs per grid-domain point
-    # FLOP domain: product over these axes of (data extents) -- defaults to
-    # product of all grid axes' *data* extents.
+    # FLOPs per grid-domain point (the domain is the product of every grid
+    # axis' *data* extent): a number, or a Python-syntax expression over the
+    # data and program parameters for a kernel whose work per point depends
+    # on its tiles (the SSD's chunk-quadratic intra-chunk term).
+    flops_per_point: float | str
     constraints: tuple[str, ...] = ()       # python-syntax strings over D u P
     mxu_fraction: float = 1.0
     # candidate values per program param (powers of two by default)
@@ -249,11 +252,24 @@ class KernelSpec:
             e = e * a.extent_expr()
         return e
 
-    def flops_total(self, D: Dims, P: Dims) -> float:
+    def _flop_domain(self, D: Dims) -> float:
         n = 1.0
         for a in self.grid:
             n *= D[a.data] if isinstance(a.data, str) else a.data
-        return self.flops_per_point * n
+        return n
+
+    def flops_per_point_batch(self, D: Dims,
+                              table: CandidateTable) -> np.ndarray:
+        """(n,) float64 FLOPs per grid-domain point over the table."""
+        if not isinstance(self.flops_per_point, str):
+            return np.full(len(table), float(self.flops_per_point))
+        return self._evaluate(self.flops_per_point, D, table, {},
+                              "flops_per_point").astype(np.float64)
+
+    def flops_total(self, D: Dims, P: Dims) -> float:
+        table = CandidateTable.from_rows(self.program_params, [P])
+        return float(self.flops_per_point_batch(D, table)[0]) \
+            * self._flop_domain(D)
 
     def _fetches(self, op: Operand, extents: tuple[int, ...]) -> int:
         """Fetch count under lexicographic grid order, last axis fastest."""
@@ -388,12 +404,10 @@ class KernelSpec:
         steps = np.ones(n, dtype=np.int64)
         for e in extents:
             steps = steps * e
-        flops = 1.0
-        for a in self.grid:
-            flops *= D[a.data] if isinstance(a.data, str) else a.data
         return TrafficTable(
             grid_steps=steps,
-            flops_total=np.full(n, self.flops_per_point * flops),
+            flops_total=self.flops_per_point_batch(D, table)
+            * self._flop_domain(D),
             operands=operands,
             vmem_stage_bytes=self.vmem_stage_bytes_batch(D, table, hw),
             mxu_fraction=self.mxu_fraction,
@@ -405,41 +419,58 @@ class KernelSpec:
         table = CandidateTable.from_rows(self.program_params, [P])
         return bool(self.feasible_mask(D, table, hw)[0])
 
+    def _evaluate(self, expr: str, D: Dims, table: CandidateTable,
+                  extra: Mapping[str, object], what: str,
+                  row_error: object = None) -> np.ndarray:
+        """(n,) values of a Python-syntax spec expression over the table.
+
+        The expression is evaluated once with ndarray columns bound to the
+        program parameters; one that resists array evaluation falls back
+        to per-row scalar evaluation.  Evaluation happens in a restricted
+        namespace (no builtins; only the data and program parameters,
+        ``extra``, ``math`` and ``np``), and a reference to any other
+        symbol raises :class:`SpecError` naming it.  A row whose scalar
+        evaluation raises reads ``row_error``, or raises SpecError when
+        ``row_error`` is None.
+        """
+        n = len(table)
+        scalars: dict[str, object] = {k: int(v) for k, v in D.items()}
+        scalars.update(extra)
+        env = {**scalars, **table.columns}
+        _check_symbols(expr, set(env) | {"math", "np"}, self.name, what)
+        globs = {"__builtins__": {}, "math": math, "np": np}
+        try:
+            return np.broadcast_to(np.asarray(eval(expr, globs, env)), (n,))
+        except Exception:
+            pass
+        out = []
+        for i in range(n):
+            try:
+                out.append(eval(expr, globs, {**scalars, **table.row(i)}))
+            except Exception as e:
+                if row_error is None:
+                    raise SpecError(
+                        f"{what} {expr!r} of spec {self.name!r} fails at "
+                        f"{table.row(i)}: {e}") from e
+                out.append(row_error)
+        return np.asarray(out).reshape(n)
+
     def feasible_mask(self, D: Dims, table: CandidateTable,
                       hw: HardwareParams = V5E) -> np.ndarray:
         """Vectorized constraint evaluation: (n,) bool over the table.
 
         The user-written Python-syntax constraint strings (Section V-A) are
-        evaluated once with ndarray columns bound to the program parameters;
-        a constraint that resists array evaluation falls back to per-row
-        scalar evaluation for just that constraint.  Evaluation happens in a
-        restricted namespace (no builtins; only the spec's parameters plus
-        ``vmem``, ``math`` and ``np``), and a constraint referencing any
-        other symbol raises :class:`SpecError` naming it instead of
-        silently masking every configuration infeasible.
+        evaluated by ``_evaluate`` with ``vmem`` bound to the scoped VMEM
+        budget; a constraint referencing an unknown symbol raises
+        :class:`SpecError` naming it instead of silently masking every
+        configuration infeasible, and a row on which a constraint raises is
+        infeasible.
         """
-        n = len(table)
-        mask = np.ones(n, dtype=bool)
-        env: dict[str, object] = {k: int(v) for k, v in D.items()}
-        env.update(table.columns)
-        env["vmem"] = hw.vmem_limit_bytes
-        known = set(env) | {"math", "np"}
-        globs = {"__builtins__": {}, "math": math, "np": np}
+        mask = np.ones(len(table), dtype=bool)
+        extra = {"vmem": hw.vmem_limit_bytes}
         for c in self.constraints:
-            _check_constraint_symbols(c, known, self.name)
-            try:
-                res = eval(c, globs, dict(env))
-                mask &= np.broadcast_to(np.asarray(res, dtype=bool), (n,))
-            except Exception:
-                ok = np.zeros(n, dtype=bool)
-                for i in range(n):
-                    row = {**{k: int(v) for k, v in D.items()},
-                           **table.row(i), "vmem": hw.vmem_limit_bytes}
-                    try:
-                        ok[i] = bool(eval(c, globs, row))
-                    except Exception:
-                        ok[i] = False
-                mask &= ok
+            mask &= self._evaluate(c, D, table, extra, "constraint",
+                                   row_error=False).astype(bool)
         # Built-in constraint: pipeline_buffers stage buffers must fit the
         # kernel's scoped VMEM budget -- the limit the kernel hands Mosaic
         # (the TPU occupancy analogue of registers/shared-memory limits).
@@ -593,16 +624,30 @@ def moe_gmm_spec(dtype_bytes: int = 2) -> KernelSpec:
     )
 
 
+def ssd_scan_flops(d_head: int = 64, d_state: int = 128) -> str:
+    """MXU FLOPs the SSD scan body issues per (bh, s) point, as an
+    expression over the chunk length.
+
+    Per token of a chunk, the body contracts over the chunk three times:
+    the inclusive cumsum of the log-decay as a (chunk, chunk) @ (chunk, 128)
+    ones matmul at ``Precision.HIGHEST``, which the MXU runs as 6 bf16
+    passes (2 * 128 * 6); the C @ B^T scores (2 * d_state); and scores @ x
+    (2 * d_head).  The inter-chunk read C @ state and the state update
+    (B * w)^T @ x add 4 * d_state * d_head whatever the chunk.
+    """
+    return (f"chunk * (2 * 128 * 6 + 2 * {d_state} + 2 * {d_head})"
+            f" + 4 * {d_state} * {d_head}")
+
+
 def ssd_scan_spec(d_head: int = 64, d_state: int = 128,
                   dtype_bytes: int = 2) -> KernelSpec:
     """Mamba-2 SSD chunked scan (state-space duality, arXiv:2405.21060).
 
-    D: bh (batch*heads), s (sequence).  P: chunk (the SSD chunk length --
-    the launch parameter the technique tunes for the attention-free arch).
-    Per (bh, s) point: intra-chunk "attention" term ~ 2*chunk*d_head +
-    state update terms ~ 4*d_state*d_head / chunk-amortized; we fold the
-    chunk-dependence into the grid/tiles and keep flops_per_point for the
-    dominant quadratic-in-chunk term.
+    D: bh (batch*heads), s (sequence), and ``chunkflops``, a constant 1 the
+    dispatch key carries.  P: chunk (the SSD chunk length -- the launch
+    parameter the technique tunes for the attention-free arch).  The work
+    per (bh, s) point grows linearly with the chunk (``ssd_scan_flops``),
+    so the whole call's grows with its square.
     """
     return KernelSpec(
         name=f"ssd_scan_h{d_head}_n{d_state}",
@@ -626,11 +671,7 @@ def ssd_scan_spec(d_head: int = 64, d_state: int = 128,
             # f32 (chunk, chunk) gate / score intermediates kept in VMEM.
             Operand("scores", ("chunk", "chunk"), (), 4, vmem_only=True),
         ),
-        # dominant intra-chunk matmul term: 2 * chunk * d_head per point is
-        # chunk-dependent; expressed by treating "chunkflops" as a data param
-        # set to 1 and scaling flops in the driver; simpler: use mean chunk
-        # cost at reference chunk 256.
-        flops_per_point=2.0 * 256 * 1.0 + 4.0 * d_state,
+        flops_per_point=ssd_scan_flops(d_head, d_state),
         constraints=("chunk <= s", "chunk % 128 == 0"),
         mxu_fraction=0.7,
         param_candidates={"chunk": (128, 256, 512, 1024, 2048)},

@@ -31,9 +31,9 @@ tile).
 
 FLOPs per grid-domain point come from the cost walk: per-step FLOPs divided
 by the product of the blocked program parameters, cross-checked between the
-two traces (a mismatch means the FLOP density depends on P itself --
-impossible to express in the ``flops_per_point`` model -- and demands an
-explicit GridSpec hint).
+two traces (a mismatch means the FLOP density depends on P itself, which
+two traces cannot pin down, and demands an explicit GridSpec hint; the hint
+may be an expression over D and P, passed into the spec unchanged).
 """
 
 from __future__ import annotations
@@ -205,13 +205,14 @@ def _derive_intermediate(gs: GridSpec, cap1: Capture, cap2: Capture,
 
 def _derive_flops(gs: GridSpec, c1, c2,
                   axes: tuple[GridAxis, ...],
-                  P1: Dims, P2: Dims) -> tuple[float, float]:
+                  P1: Dims, P2: Dims) -> tuple[float | str, float]:
     """(flops_per_point, mxu_fraction) from the cost walk, or the hints."""
     mxu = gs.mxu_fraction
     if mxu is None:
         mxu = c1.mxu_fraction_estimate
-    if gs.flops_per_point is not None:
-        return float(gs.flops_per_point), float(mxu)
+    hint = gs.flops_per_point
+    if hint is not None:
+        return (hint if isinstance(hint, str) else float(hint)), float(mxu)
     blocked1 = math.prod(P1[a.block] for a in axes if a.block) or 1
     blocked2 = math.prod(P2[a.block] for a in axes if a.block) or 1
     step1 = c1.dot_flops if c1.dot_flops else c1.vpu_flops

@@ -33,7 +33,8 @@ class IntrospectError(RuntimeError):
     model: no (or several) ``pallas_call`` sites, grid extents or tile
     dimensions that match no data/program parameter, scalar-prefetch or
     dynamic-grid features, or a FLOP density that depends on the program
-    parameters (needs an explicit ``flops_per_point`` hint).
+    parameters (needs an explicit ``flops_per_point`` hint, which may be an
+    expression over D and P).
     """
 
 
@@ -65,10 +66,11 @@ class GridSpec:
     extra_constraints: tuple[str, ...] = ()
     # FLOP policy: ``flops_per_point`` overrides the cost walk entirely
     # (needed when per-step FLOPs are not proportional to the tile product,
-    # e.g. the ssd chunk-quadratic term); ``flop_scale`` multiplies the
-    # derived count (e.g. 0.5 for causal masking, which the dense jaxpr
-    # cannot see).
-    flops_per_point: float | None = None
+    # e.g. the ssd chunk-quadratic term) and passes into the KernelSpec as
+    # written, a number or an expression over D and P; ``flop_scale``
+    # multiplies the derived count (e.g. 0.5 for causal masking, which the
+    # dense jaxpr cannot see).
+    flops_per_point: float | str | None = None
     flop_scale: float = 1.0
     mxu_fraction: float | None = None
     pipeline_buffers: int = 2

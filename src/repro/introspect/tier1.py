@@ -3,11 +3,12 @@
 These declare exactly what KLARAPTOR's users put in configuration files --
 parameter names, candidate grids, probe hints, and the two genuinely
 non-derivable FLOP policies (flash's causal 0.5 discount, ssd's
-chunk-quadratic density frozen at the reference chunk) -- and *nothing*
-structural.  ``spec_from_kernel`` over these must reproduce the hand-written
-specs in ``core/kernel_spec.py`` behaviorally (same grid, candidates,
-traffic, feasible set, chosen configs); ``tests/test_introspect.py`` and
-``benchmarks/bench_introspect.py`` hold that equivalence.
+chunk-dependent density stated as an expression over the chunk) -- and
+*nothing* structural.  ``spec_from_kernel`` over these must reproduce the
+hand-written specs in ``core/kernel_spec.py`` behaviorally (same grid,
+candidates, traffic, feasible set, chosen configs);
+``tests/test_introspect.py`` and ``benchmarks/bench_introspect.py`` hold
+that equivalence.
 
 Production tier-1 dispatch keeps the hand specs; these GridSpecs exist as
 the ground-truth check that introspection is faithful.
@@ -17,6 +18,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.kernel_spec import ssd_scan_flops
 
 from .gridspec import GridSpec
 
@@ -119,10 +122,10 @@ def ssd_scan_grid_spec(d_head: int = 64, d_state: int = 128,
         fit_vars={"mem_step": ("chunk",), "cmp_step": ("chunk",),
                   "ovh_step": ("chunk",)},
         probe_hints={"bh": (2, 8), "chunkflops": (1,)},
-        # The intra-chunk attention term is quadratic in the chunk length,
-        # so per-point FLOPs depend on P -- exactly the case the cost walk
-        # rejects.  Frozen at the reference chunk 256, like the hand spec.
-        flops_per_point=2.0 * 256 * 1.0 + 4.0 * d_state,
+        # The intra-chunk work per point grows with the chunk length, so
+        # the FLOP density depends on P -- the case the cost walk rejects.
+        # Stated as the same expression the hand spec carries.
+        flops_per_point=ssd_scan_flops(d_head, d_state),
         mxu_fraction=0.7,
         defaults={"chunk": 256},
     )

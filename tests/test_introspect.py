@@ -174,6 +174,24 @@ class TestDerivation:
         assert all(P1[p] != P2[p] for p in P1)
         assert all(D1[d] != D2[d] for d in D1)
 
+    def test_flops_expression_hint_passes_through(self, pairs):
+        """ssd's P-dependent FLOP hint reaches the derived spec as the
+        expression the hand spec carries, not a frozen number."""
+        from repro.core.kernel_spec import ssd_scan_flops
+
+        hand, intro = pairs["ssd_scan_h64_n128"]
+        assert intro.flops_per_point == hand.flops_per_point \
+            == ssd_scan_flops(64, 128)
+
+    @pytest.mark.parametrize("name,flops", [
+        ("matmul_b16", 2.0), ("flash_attn_d128_causal", 256.0),
+        ("moe_gmm_b16", 2.0)])
+    def test_derived_constant_densities_stay_numbers(self, pairs, name,
+                                                     flops):
+        _, intro = pairs[name]
+        assert isinstance(intro.flops_per_point, float)
+        assert intro.flops_per_point == flops
+
     def test_p_dependent_flops_need_hint(self):
         """ssd's chunk-quadratic FLOP density is rejected without a hint."""
         from repro.introspect.tier1 import ssd_scan_grid_spec

@@ -92,6 +92,23 @@ class TestOtherKernels:
                             {"bh": 48, "s": 65536, "chunkflops": 1})
         assert r["ratio"] >= 0.7, r
 
+    def test_ssd_chunk_follows_its_work(self):
+        """Built as the benchmark builds it, the SSD driver no longer
+        launches the mamba2-130m forward's scan at the largest chunk, whose
+        chunk-quadratic work the FLOP model now counts; the flash driver's
+        choice at the internlm2-1.8b forward's shape stays where it was."""
+        build = lambda spec: Klaraptor(V5eSimulator(V5E), hw=V5E,
+                                       cache=False).build_driver(
+            spec, register=False)
+        ssd = ssd_scan_spec(d_head=64, d_state=128)
+        D = {"bh": 384, "s": 8192, "chunkflops": 1}
+        r = selection_ratio(ssd, V5eSimulator(V5E), build(ssd).driver, D)
+        assert r["chosen"]["chunk"] < 2048, r
+        assert r["ratio"] >= 0.7, r
+        flash = flash_attention_spec(head_dim=128, causal=True)
+        assert build(flash).driver.choose(
+            {"bh": 64, "sq": 8192, "skv": 8192}) == {"bq": 2048, "bkv": 2048}
+
 
 class TestPerformancePortability:
     def test_different_device_different_choice_possible(self, sim):

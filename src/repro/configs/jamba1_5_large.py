@@ -5,6 +5,12 @@ d_ff=24576 vocab=65536, MoE 16e top-2, Mamba+attention 1:7 interleave.
 Pattern period 8: one attention layer per 8 (position 0), seven Mamba
 layers; MoE MLP on alternating (odd) positions, dense MLP on even ones.
 Optimizer state is bf16 for this arch (DESIGN.md section 4).
+
+Not the published mechanism: Jamba's Mamba layers are Mamba-1 (selective
+scan with d_state 16 and a dt projection of rank 256), and the ``mamba``
+blocks here run the Mamba-2 SSD (state 128, heads of 64) in their place.
+The sizes above are Jamba's; the mixer is a stand-in.  For a hybrid whose
+mixer is the Mamba-2 SSD as published, see ``granite4_h_small``.
 """
 
 from repro.models.config import BlockDesc, ModelConfig

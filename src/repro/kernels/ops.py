@@ -48,6 +48,7 @@ caller slices ``[:m, :n]`` on the host where the raw dims are concrete.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -60,11 +61,12 @@ from . import ref
 from .common import interpret_mode
 from .flash_attention import flash_attention_pallas
 from .matmul import matmul_pallas
+from .moe_experts import moe_experts_pallas
 from .moe_gmm import moe_gmm_pallas
 from .ssd_scan import ssd_scan_pallas
 
-__all__ = ["matmul", "flash_attention", "moe_gmm", "ssd_scan",
-           "layernorm", "blocked_colsum"]
+__all__ = ["matmul", "flash_attention", "moe_gmm", "moe_experts",
+           "ssd_scan", "layernorm", "blocked_colsum"]
 
 # Static heuristic defaults (the "multiple of 32"-style baseline the paper
 # contrasts with -- what a programmer would hard-code).
@@ -261,6 +263,20 @@ def moe_gmm(x: jax.Array, w: jax.Array, *, use_pallas: bool = False,
     bn = _fit_tile(n, cfg["bn"], 128)
     bk = _fit_tile(k, cfg["bk"], 128)
     return moe_gmm_pallas(x, w, bg=bg, bn=bn, bk=bk, interpret=interpret)
+
+
+def moe_experts(lhs: jax.Array, rhs: jax.Array, *, group_sizes: jax.Array,
+                use_pallas: bool = False) -> jax.Array:
+    """Ragged expert matmul: rows of (m, k) ``lhs`` sorted by expert,
+    ``group_sizes[e]`` of them for expert e of (experts, k, n) ``rhs``.
+    Rows past the last expert's come back zero (XLA) or undefined
+    (Pallas).  The Pallas kernel launches at fixed tiles: its grid's
+    length is data, which the tuner's specs cannot state yet."""
+    if not use_pallas:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return moe_experts_pallas(lhs, rhs, group_sizes,
+                              tm=math.gcd(lhs.shape[0], 512),
+                              interpret=interpret_mode())
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, B: jax.Array, C: jax.Array,

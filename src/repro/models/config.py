@@ -52,6 +52,11 @@ class ModelConfig:
     causal: bool = True
     tie_embeddings: bool = False
     scale_embed: bool = False       # gemma-style sqrt(d_model) embed scaling
+    use_rope: bool = True           # False: no positional encoding (NoPE)
+    attn_scale: float | None = None  # query-key scale; None: 1/sqrt(head_dim)
+    embedding_multiplier: float = 1.0   # token embeddings times this
+    residual_multiplier: float = 1.0    # each sub-block's output times this
+    logits_scaling: float = 1.0         # logits divided by this
 
     # MoE
     n_experts: int = 0
@@ -60,6 +65,11 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     moe_group: int = 1024           # tokens per local-dispatch group
+    shared_d_ff: int = 0            # width of an always-on shared expert
+    experts_held: int = 0           # experts computed here, of n_experts
+    #                                 (expert-parallel share; routing stays
+    #                                 over all n_experts, dropless); 0: all
+    #                                 experts, capacity-dropping dispatch
 
     # Mamba-2 (SSD)
     ssm_state: int = 0

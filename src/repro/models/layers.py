@@ -15,6 +15,7 @@ TPU-native formulation (everything is an MXU matmul).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -27,7 +28,8 @@ from repro.models.module import ParamSpec
 
 __all__ = [
     "rmsnorm", "rope", "spec_attention", "attention", "attention_decode",
-    "spec_mlp", "mlp", "spec_moe", "moe", "spec_mamba", "mamba",
+    "spec_mlp", "mlp", "spec_moe", "moe", "moe_held", "shared_expert",
+    "spec_mamba", "mamba",
     "mamba_decode", "ssd_parallel",
 ]
 
@@ -109,7 +111,7 @@ def attention(cfg: ModelConfig, p: dict, xq: jax.Array, sharder,
     B, Sq, _ = xq.shape
     Skv = xkv.shape[1]
     q, k, v = _project_qkv(cfg, p, xq, xkv, prefix)
-    if not cross:
+    if not cross and cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     q = sharder.act(q, ("batch", "act_seq", "act_heads", None))
@@ -123,7 +125,8 @@ def attention(cfg: ModelConfig, p: dict, xq: jax.Array, sharder,
     out = ops.flash_attention(
         qf, kf, vf, num_q_heads=cfg.n_heads, num_kv_heads=cfg.n_kv_heads,
         causal=causal, window=desc.window, softcap=cfg.attn_softcap,
-        use_pallas=cfg.use_pallas, q_chunk=cfg.attn_chunk)
+        scale=cfg.attn_scale, use_pallas=cfg.use_pallas,
+        q_chunk=cfg.attn_chunk)
     out = out.reshape(B, cfg.n_heads, Sq, cfg.head_dim).transpose(0, 2, 1, 3)
     out = out.reshape(B, Sq, cfg.q_dim)
     return out @ p[f"{prefix}wo"]
@@ -152,15 +155,16 @@ def attention_decode(cfg: ModelConfig, p: dict, x1: jax.Array, sharder,
         v1 = (x1 @ p[f"{prefix}wv"]).reshape(B, 1, KV, dh)
         if cfg.qk_norm and f"{prefix}k_norm" in p:
             k1 = rmsnorm(k1, p[f"{prefix}k_norm"], cfg.rms_eps)
-        q = rope(q, pos[:, None], cfg.rope_theta)
-        k1 = rope(k1, pos[:, None], cfg.rope_theta)
+        if cfg.use_rope:
+            q = rope(q, pos[:, None], cfg.rope_theta)
+            k1 = rope(k1, pos[:, None], cfg.rope_theta)
         cache_k = _write_cache(cache_k, k1, pos)
         cache_v = _write_cache(cache_v, v1, pos)
 
     # Keep the cache in its storage dtype: upcasting (B, S, KV, dh) to f32
     # would materialize a second full cache; accumulate in f32 instead.
     qf = q.reshape(B, KV, group, dh).astype(cache_k.dtype)
-    scale = dh ** -0.5
+    scale = cfg.attn_scale if cfg.attn_scale is not None else dh ** -0.5
     s = jnp.einsum("bkgd,bskd->bkgs", qf, cache_k,
                    preferred_element_type=f32) * scale     # (B, KV, g, S)
     if cfg.attn_softcap is not None:
@@ -218,17 +222,36 @@ def mlp(cfg: ModelConfig, p: dict, x: jax.Array, sharder) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def spec_moe(cfg: ModelConfig) -> dict:
+    """Router over all ``n_experts``; weights of the experts held here;
+    the shared expert's where the config has one."""
     d, f, E = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.n_experts
-    return {
+    held = cfg.experts_held or E
+    p = {
         "mlp_norm": ParamSpec((d,), f32, (None,), "zeros"),
         "router": ParamSpec((d, E), f32, ("embed", None), "scaled"),
-        "we_gate": ParamSpec((E, d, f), cfg.dtype,
+        "we_gate": ParamSpec((held, d, f), cfg.dtype,
                              ("experts", "embed", "mlp"), "scaled"),
-        "we_up": ParamSpec((E, d, f), cfg.dtype,
+        "we_up": ParamSpec((held, d, f), cfg.dtype,
                            ("experts", "embed", "mlp"), "scaled"),
-        "we_down": ParamSpec((E, f, d), cfg.dtype,
+        "we_down": ParamSpec((held, f, d), cfg.dtype,
                              ("experts", "mlp", "embed"), "scaled"),
     }
+    if cfg.shared_d_ff:
+        fs = cfg.shared_d_ff
+        p.update({
+            "ws_gate": ParamSpec((d, fs), cfg.dtype, ("embed", "mlp"),
+                                 "scaled"),
+            "ws_up": ParamSpec((d, fs), cfg.dtype, ("embed", "mlp"),
+                               "scaled"),
+            "ws_down": ParamSpec((fs, d), cfg.dtype, ("mlp", "embed"),
+                                 "scaled"),
+        })
+    return p
+
+
+def shared_expert(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
+    """The expert every token passes through, beside the routed ones."""
+    return (_act(cfg, x @ p["ws_gate"]) * (x @ p["ws_up"])) @ p["ws_down"]
 
 
 def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
@@ -237,10 +260,12 @@ def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max(8, ((cap + 7) // 8) * 8)
 
 
-def moe(cfg: ModelConfig, p: dict, x: jax.Array, sharder
-        ) -> tuple[jax.Array, jax.Array]:
+def moe(cfg: ModelConfig, p: dict, x: jax.Array, sharder,
+        first_expert: int = 0) -> tuple[jax.Array, jax.Array]:
     """Top-k MoE: grouped local dispatch + expert-sharded einsum (GShard
-    groups, sort-based slots).
+    groups, sort-based slots).  With ``cfg.experts_held`` set, the
+    dropless part of experts ``first_expert`` onwards instead
+    (``moe_held``).
 
     Tokens are split into groups of ``moe_group``; each group sorts its own
     (token, expert) pairs and scatters into capacity-padded slots.  The
@@ -253,6 +278,8 @@ def moe(cfg: ModelConfig, p: dict, x: jax.Array, sharder
 
     Returns (y, aux_loss).
     """
+    if cfg.experts_held:
+        return moe_held(cfg, p, x, first_expert)
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
     T = B * S
@@ -309,12 +336,69 @@ def moe(cfg: ModelConfig, p: dict, x: jax.Array, sharder
     y = jax.vmap(combine_one)(out_rows, st, sg, keep, slot)
     y = sharder.act(y, ("moe_groups", None, "moe_token_d"))
 
-    # Switch-style load-balance auxiliary loss.
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(expert[..., 0], E, dtype=f32), axis=(0, 1))
-    mean_prob = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac_tokens * mean_prob)
-    return y.reshape(B, S, d), aux.astype(f32)
+    aux = _balance_loss(probs, expert[..., 0], E)
+    return y.reshape(B, S, d), aux
+
+
+def _balance_loss(probs: jax.Array, top1: jax.Array, E: int) -> jax.Array:
+    """Switch-style load-balance auxiliary loss."""
+    axes = tuple(range(top1.ndim))
+    frac_tokens = jnp.mean(jax.nn.one_hot(top1, E, dtype=f32), axis=axes)
+    mean_prob = jnp.mean(probs, axis=axes)
+    return (E * jnp.sum(frac_tokens * mean_prob)).astype(f32)
+
+
+def moe_held(cfg: ModelConfig, p: dict, x: jax.Array, first: int = 0
+             ) -> tuple[jax.Array, jax.Array]:
+    """The part of a top-k MoE that experts ``first`` .. ``first + held``
+    give, dropless: one chip's share under expert parallelism.
+
+    The router keeps its full width: f32 logits over all ``n_experts``,
+    the top ``k`` of them, gates a softmax over those ``k``.  The (token,
+    expert) pairs that fall on held experts are sorted by expert into a
+    buffer of ``T * min(k, held)`` rows, which no routing can overflow
+    (a token picks an expert once), and three ragged expert matmuls
+    (``ops.moe_experts``) compute only the tiles those rows cover.  The
+    rows are added back to their tokens weighted by their gates, in the
+    activations' dtype as the published Granite code does.  What the
+    other experts would add is not computed: their chips add it under
+    expert parallelism.  Returns (y, aux_loss).
+    """
+    B, S, d = x.shape
+    E, k, held = cfg.n_experts, cfg.top_k, cfg.experts_held
+    if not 0 <= first <= E - held:
+        raise ValueError(f"experts {first}..{first + held - 1} are not "
+                         f"among the router's {E}")
+    T = B * S
+    xt = x.reshape(T, d)
+    logits = jnp.dot(xt.astype(f32), p["router"].astype(f32))   # (T, E)
+    top, expert = jax.lax.top_k(logits, k)                      # (T, k)
+    gates = jax.nn.softmax(top, axis=-1)
+
+    # Sort the pairs by held expert; the pairs of other experts sort last.
+    local = (expert - first).reshape(T * k)
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    rows = -(-T * min(k, held) // 128) * 128        # tile-aligned bound
+    pad = max(rows - T * k, 0)
+    key = jnp.concatenate([key, jnp.full((pad,), held, key.dtype)])
+    order = jnp.argsort(key, stable=True)[:rows]
+    sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    valid = jnp.arange(rows) < jnp.sum(sizes)
+    token = jnp.where(valid, order // k, 0)
+    gate = jnp.concatenate([gates.reshape(T * k),
+                            jnp.zeros((pad,), f32)])[order]
+
+    xs = xt[token]                                              # (rows, d)
+    gmm = functools.partial(ops.moe_experts, group_sizes=sizes,
+                            use_pallas=cfg.use_pallas)
+    h = _act(cfg, gmm(xs, p["we_gate"])) * gmm(xs, p["we_up"])
+    # The gate scales a row before the (linear) down projection; the rows
+    # past the routed ones hold no result and are dropped, never read.
+    out = gmm(h * gate[:, None].astype(h.dtype), p["we_down"])
+    y = jnp.zeros((T, d), x.dtype).at[jnp.where(valid, token, T)].add(
+        out, mode="drop")
+    return (y.reshape(B, S, d),
+            _balance_loss(jax.nn.softmax(logits, axis=-1), expert[:, 0], E))
 
 
 # ---------------------------------------------------------------------------

@@ -90,6 +90,8 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.scale_embed:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype=x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype=x.dtype)
     return x
 
 
@@ -97,6 +99,8 @@ def unembed(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     """Hidden states -> (softcapped) logits over the padded vocab."""
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     z = jnp.einsum("...d,dv->...v", x.astype(f32), head.astype(f32))
+    if cfg.logits_scaling != 1.0:
+        z = z / cfg.logits_scaling
     if cfg.final_softcap is not None:
         z = cfg.final_softcap * jnp.tanh(z / cfg.final_softcap)
     # mask vocab padding columns
@@ -106,30 +110,48 @@ def unembed(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     return z
 
 
+def _residual(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """A sub-block's output added to the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, dtype=y.dtype)
+    return x + y
+
+
+def _feed_forward(cfg: ModelConfig, desc: BlockDesc, p: dict, x: jax.Array,
+                  sharder) -> tuple[jax.Array, jax.Array | None]:
+    """The block's MLP or MoE sub-block on the residual stream x: (its
+    output, the MoE's auxiliary loss or None)."""
+    h = L.rmsnorm(x, p["mlp_norm"], cfg.rms_eps)
+    if not desc.moe:
+        return L.mlp(cfg, p, h, sharder), None
+    y, aux = L.moe(cfg, p, h, sharder)
+    if cfg.shared_d_ff:
+        y = y + L.shared_expert(cfg, p, h)
+    return y, aux
+
+
 def _apply_block(cfg: ModelConfig, desc: BlockDesc, p: dict, x: jax.Array,
                  sharder, positions: jax.Array,
                  enc_out: jax.Array | None, causal: bool) -> tuple:
     aux = jnp.zeros((), f32)
     if desc.kind == "attn":
         h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
-        x = x + L.attention(cfg, p, h, sharder, desc, positions,
-                            causal=causal)
+        x = _residual(cfg, x, L.attention(cfg, p, h, sharder, desc,
+                                          positions, causal=causal))
     else:
         h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
-        x = x + L.mamba(cfg, p, h, sharder)
+        x = _residual(cfg, x, L.mamba(cfg, p, h, sharder))
     if desc.cross_attn:
         assert enc_out is not None
         h = L.rmsnorm(x, p["x_norm"], cfg.rms_eps)
-        x = x + L.attention(cfg, p, h, sharder, desc, positions,
-                            xkv=enc_out, prefix="x_")
+        x = _residual(cfg, x, L.attention(cfg, p, h, sharder, desc,
+                                          positions, xkv=enc_out,
+                                          prefix="x_"))
     if desc.mlp:
-        h = L.rmsnorm(x, p["mlp_norm"], cfg.rms_eps)
-        if desc.moe:
-            y, a = L.moe(cfg, p, h, sharder)
+        y, a = _feed_forward(cfg, desc, p, x, sharder)
+        if a is not None:
             aux = aux + a
-        else:
-            y = L.mlp(cfg, p, h, sharder)
-        x = x + y
+        x = _residual(cfg, x, y)
     return x, aux
 
 
@@ -303,25 +325,21 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array,
                 h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
                 y, nc["k"], nc["v"] = L.attention_decode(
                     cfg, p, h, sharder, desc, pos, c["k"], c["v"])
-                x = x + y
+                x = _residual(cfg, x, y)
             else:
                 h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
                 y, nc["conv"], nc["ssm"] = L.mamba_decode(
                     cfg, p, h, c["conv"], c["ssm"])
-                x = x + y
+                x = _residual(cfg, x, y)
             if desc.cross_attn:
                 h = L.rmsnorm(x, p["x_norm"], cfg.rms_eps)
                 y, nc["xk"], nc["xv"] = L.attention_decode(
                     cfg, p, h, sharder, desc, pos, c["xk"], c["xv"],
                     cross=True, prefix="x_")
-                x = x + y
+                x = _residual(cfg, x, y)
             if desc.mlp:
-                h = L.rmsnorm(x, p["mlp_norm"], cfg.rms_eps)
-                if desc.moe:
-                    y, _ = L.moe(cfg, p, h, sharder)
-                else:
-                    y = L.mlp(cfg, p, h, sharder)
-                x = x + y
+                y, _ = _feed_forward(cfg, desc, p, x, sharder)
+                x = _residual(cfg, x, y)
             newc[f"pos{i}"] = nc
         return x, newc
 

@@ -11,7 +11,8 @@ class TestShapeApplicability:
     def test_long_500k_only_for_subquadratic(self):
         runnable = [a for a in ARCH_IDS
                     if shape_applicable(get_config(a), "long_500k")[0]]
-        assert sorted(runnable) == ["jamba-1.5-large-398b", "mamba2-130m"]
+        assert sorted(runnable) == ["granite-4.0-h-small",
+                                    "jamba-1.5-large-398b", "mamba2-130m"]
 
     def test_cell_count(self):
         cells = 0
@@ -19,7 +20,7 @@ class TestShapeApplicability:
             for s in SHAPES:
                 if shape_applicable(get_config(a), s)[0]:
                     cells += 1
-        assert cells == 32   # 10 archs x 4 shapes - 8 long_500k skips
+        assert cells == 36   # 11 archs x 4 shapes - 8 long_500k skips
 
     def test_input_specs_cover_all_inputs(self):
         import jax

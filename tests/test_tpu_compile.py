@@ -6,10 +6,12 @@ each op of ``kernels/ops.py`` for one chip of a described (not attached)
 ``v5e:2x2`` topology, with the config that a ``V5eSimulator``-built driver
 chooses at that shape, and check that a Mosaic kernel (``tpu_custom_call``)
 came out.  Widths: llama3.2-1b for the ops on its path, mamba2-130m for the
-SSD scan, qwen3-moe-235b-a22b for the expert matmul, plus the matmul and
-flash shapes where the tuner used to choose tiles the compiler refused.
-The tuned kernels are also compiled without their jitted wrappers, to
-check that each keeps the device op name the benchmark's readers match.
+SSD scan, qwen3-moe-235b-a22b for the expert matmul, granite-4.0-h-small's
+chip share for the ragged matmul of the experts held (fixed tiles), plus
+the matmul and flash shapes where the tuner used to choose tiles the
+compiler refused.  The tuned kernels and the ragged matmul are also
+compiled without their jitted wrappers, to check that each keeps the
+device op name the benchmark's readers match.
 """
 
 import functools
@@ -28,6 +30,7 @@ from repro.core import (Klaraptor, V5eSimulator, flash_attention_spec,
 from repro.introspect import spec_from_kernel
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.moe_experts import moe_experts_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.kernels.layernorm import layernorm_grid_spec, layernorm_pallas
 from repro.kernels.reduce import colsum_grid_spec, colsum_pallas
@@ -36,7 +39,7 @@ from repro.models.layers import moe_capacity
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
-from bench.readers import KERNEL_OPS  # noqa: E402
+from bench.family_readers import KERNEL_OPS  # noqa: E402
 
 LLAMA = get_config("llama3.2-1b")
 MAMBA = get_config("mamba2-130m")
@@ -150,6 +153,7 @@ def test_compiles_to_mosaic(case, one_chip, plan, monkeypatch):
 
 
 INTERNLM2 = get_config("internlm2-1.8b")
+GRANITE = get_config("granite-4.0-h-small-ep8")
 # Each tuned kernel called through its ``pallas_call`` alone, inside a
 # jitted function of another name, at the widths of its benchmark model.
 UNWRAPPED = {
@@ -166,6 +170,12 @@ UNWRAPPED = {
         lambda x, dt, B, C, A: ssd_scan_pallas.__wrapped__(x, dt, B, C, A),
         [(_BH, 1024, _DH, BF), (_BH, 1024, F32), (_BH, 1024, _N, BF),
          (_BH, 1024, _N, BF), (_BH, F32)]),
+    "moe_experts": (
+        "repro.kernels.moe_experts",
+        lambda x, w, sizes: moe_experts_pallas.__wrapped__(x, w, sizes),
+        [(4096, GRANITE.d_model, BF),
+         (GRANITE.experts_held, GRANITE.d_model, GRANITE.moe_d_ff, BF),
+         (GRANITE.experts_held, jnp.int32)]),
 }
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+) = ", re.M)
 
@@ -187,3 +197,22 @@ def test_kernel_op_keeps_its_name_unwrapped(kernel, one_chip, monkeypatch):
     assert any(re.search(KERNEL_OPS[kernel], n) for n in names), (
         f"no instruction matches {KERNEL_OPS[kernel]!r}; custom calls: "
         f"{calls}")
+
+
+# The held experts' buffer in granite4h-fwd-8k: 4 x 8192 tokens, each with
+# at most 9 of the chip's experts among its top 10.
+_ROWS = 4 * 8192 * GRANITE.experts_held
+
+
+@pytest.mark.parametrize("k,n", [(GRANITE.d_model, GRANITE.moe_d_ff),
+                                 (GRANITE.moe_d_ff, GRANITE.d_model)])
+def test_moe_experts_compiles_to_mosaic(k, n, one_chip, monkeypatch):
+    """The ragged expert matmul at its fixed tiles, through the op, for the
+    cell's gate/up and down projections."""
+    monkeypatch.setattr(ops, "interpret_mode", lambda requested=True: False)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((_ROWS, k), BF), ((GRANITE.experts_held, k, n), BF),
+        ((GRANITE.experts_held,), jnp.int32))]
+    compiled = jax.jit(lambda x, w, g: ops.moe_experts(
+        x, w, group_sizes=g, use_pallas=True)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -11,11 +11,15 @@ Walks the captured kernel-body jaxpr (trace.py) and accumulates:
   * ``values``     -- (shape, itemsize) of every rank >= 2 value the body
     computes, in walk order (the VMEM-intermediate candidates).
 
-Conditional sub-jaxprs (``pl.when`` -> ``cond``) are *excluded* from the
-FLOP counts: they are pipeline-boundary work (accumulator init, final
-store) amortized over the whole reduction chain, not steady-state per-step
-work.  They still contribute to ``minor_dims`` -- a dimension that must be
-lane-aligned is lane-aligned no matter how often the code runs.
+Conditional sub-jaxprs (``pl.when`` -> ``cond``) with no ``dot_general``
+are *excluded* from the FLOP counts: they are pipeline-boundary work
+(accumulator init, final store) amortized over the whole reduction chain,
+not steady-state per-step work.  A conditional that holds MXU work is the
+step's own work behind a block predicate (flash attention's skip of masked
+blocks): its costliest branch counts as one step's, and how many steps
+run it is the spec's FLOP policy.  Every branch still contributes to
+``minor_dims`` -- a dimension that must be lane-aligned is lane-aligned no
+matter how often the code runs.
 
 ``minor_dims`` drives the alignment-constraint derivation: a program
 parameter whose traced value appears as the minor-most axis of any value
@@ -118,12 +122,31 @@ def _walk(jaxpr, cost: BodyCost, count_flops: bool) -> None:
         elif name in _REDUCTIONS:
             if count_flops:
                 cost.vpu_flops += math.prod(_shape(eqn.invars[0])) or 1
-        # Recurse into nested jaxprs.  Conditional branches (pl.when) keep
-        # contributing lane dimensions but not steady-state FLOPs.
-        sub_count = count_flops and name not in ("cond", "while")
+        if name == "cond":
+            _walk_cond(eqn, cost, count_flops)
+            continue
+        # Recurse into nested jaxprs.  Loops keep contributing lane
+        # dimensions but not steady-state FLOPs.
+        sub_count = count_flops and name != "while"
         for v in eqn.params.values():
             for sub in _sub(v):
                 _walk(sub, cost, sub_count)
+
+
+def _walk_cond(eqn, cost: BodyCost, count_flops: bool) -> None:
+    """Walk every branch; count the costliest one's FLOPs if any branch
+    holds MXU work, else none (pipeline-boundary work)."""
+    branches = []
+    for sub in _sub(eqn.params["branches"]):
+        branch = BodyCost()
+        _walk(sub, branch, count_flops)
+        cost.minor_dims |= branch.minor_dims
+        cost.values.extend(branch.values)
+        branches.append(branch)
+    if count_flops and any(b.dot_flops for b in branches):
+        top = max(branches, key=lambda b: (b.dot_flops, b.vpu_flops))
+        cost.dot_flops += top.dot_flops
+        cost.vpu_flops += top.vpu_flops
 
 
 def _sub(param):

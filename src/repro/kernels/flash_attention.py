@@ -10,9 +10,16 @@ Supports the assigned-architecture attention variants:
   * sliding-window (local) attention -- gemma2's alternating local layers,
   * logit soft-capping -- gemma2 (cap * tanh(s / cap)).
 
-The kv-position mask is computed from broadcasted iotas, so non-divisible
-final blocks and fully-masked blocks are correct (just not skipped; the
-tuner's cost model sees the causal 0.5 factor instead).
+Each ``(iq, ikv)`` grid step classifies its block from positions alone
+(``block_class``) and skips the body on a dead block: every key after
+every query under ``causal``, or before every query's window.  Live blocks
+run the one body, mask built from broadcasted iotas; on an interior block
+(every pair live) the mask is all true.  Non-causal calls with no window
+run the body on every step.  Skipped steps still fetch their k/v blocks:
+the index maps are not clamped, so the operand traffic the tuner's spec
+and the introspected spec count stays the dense square's.  The tuner's
+cost model still sees the flat causal 0.5 factor, not the
+``(nb + 1) / (2 nb)`` of the square the kernel now runs.
 """
 
 from __future__ import annotations
@@ -26,9 +33,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .common import compiler_params, interpret_mode
 
-__all__ = ["flash_attention_pallas"]
+__all__ = ["block_class", "flash_attention_pallas", "flash_block_counts"]
 
 _NEG_INF = -1e30
+
+
+def block_class(iq, ikv, bq: int, bkv: int, causal: bool,
+                window: int | None):
+    """``(live, interior)`` of the ``(iq, ikv)`` block of the score square.
+
+    Live: at least one (query, key) pair of the block is unmasked.
+    Interior: every pair is.  A block that is live but not interior lies
+    on the mask's edge.  ``iq`` and ``ikv`` may be Python ints (counting)
+    or traced ``program_id``s (the kernel).
+    """
+    q_lo, q_hi = iq * bq, iq * bq + bq - 1
+    k_lo, k_hi = ikv * bkv, ikv * bkv + bkv - 1
+    live = interior = True
+    if causal:
+        live = k_lo <= q_hi
+        interior = k_hi <= q_lo
+    if window is not None:
+        live = live & (q_lo - k_hi < window)
+        interior = interior & (q_hi - k_lo < window)
+    return live, interior
+
+
+def flash_block_counts(bh: int, sq: int, skv: int, bq: int, bkv: int,
+                       causal: bool, window: int | None) -> dict:
+    """Live, interior and total grid blocks of one launch."""
+    live = interior = 0
+    for iq in range(sq // bq):
+        for ikv in range(skv // bkv):
+            is_live, is_interior = block_class(iq, ikv, bq, bkv, causal,
+                                               window)
+            live += bool(is_live)
+            interior += bool(is_interior)
+    return {"live": bh * live, "interior": bh * interior,
+            "total": bh * (sq // bq) * (skv // bkv)}
 
 
 def _flash_kernel(
@@ -44,39 +86,47 @@ def _flash_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)          # (bq, d)
-    k = k_ref[0].astype(jnp.float32)          # (bkv, d)
-    v = v_ref[0].astype(jnp.float32)          # (bkv, d)
+    def update():
+        q = q_ref[0].astype(jnp.float32)          # (bq, d)
+        k = k_ref[0].astype(jnp.float32)          # (bkv, d)
+        v = v_ref[0].astype(jnp.float32)          # (bkv, d)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (bq, bkv)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # (bq, bkv)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
 
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-    kpos = ikv * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-    mask = jnp.ones((bq, bkv), dtype=jnp.bool_)
-    if causal:
-        mask = jnp.logical_and(mask, qpos >= kpos)
-    if window is not None:
-        mask = jnp.logical_and(mask, qpos - kpos < window)
-    s = jnp.where(mask, s, _NEG_INF)
+        qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+        kpos = ikv * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        mask = jnp.ones((bq, bkv), dtype=jnp.bool_)
+        if causal:
+            mask = jnp.logical_and(mask, qpos >= kpos)
+        if window is not None:
+            mask = jnp.logical_and(mask, qpos - kpos < window)
+        s = jnp.where(mask, s, _NEG_INF)
 
-    m_prev = m_ref[:, :1]                                # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)            # (bq, 1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                               # (bq, bkv)
-    p = jnp.where(mask, p, 0.0)
-    alpha = jnp.exp(m_prev - m_new)                      # (bq, 1)
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        m_prev = m_ref[:, :1]                                # (bq, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)            # (bq, 1)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)                               # (bq, bkv)
+        p = jnp.where(mask, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)                      # (bq, 1)
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
 
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    if causal or window is not None:
+        # A dead block would leave m, l and acc exactly as they are.
+        live, _ = block_class(iq, ikv, bq, bkv, causal, window)
+        pl.when(live)(update)
+    else:
+        update()
 
     @pl.when(ikv == kv_steps - 1)
     def _finalize():

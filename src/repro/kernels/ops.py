@@ -59,7 +59,7 @@ from repro.trace import trace_span
 
 from . import ref
 from .common import interpret_mode
-from .flash_attention import flash_attention_pallas
+from .flash_attention import flash_attention_pallas, flash_block_counts
 from .matmul import matmul_pallas
 from .moe_experts import moe_experts_pallas
 from .moe_gmm import moe_gmm_pallas
@@ -92,7 +92,8 @@ def _switch_dispatch(disp, dims, branch_for_config, operands):
     return jax.lax.switch(idx, branches, operands)
 
 
-def _resolve(kernel: str, D: dict, default: dict, plan) -> dict:
+def _resolve(kernel: str, D: dict, default: dict, plan,
+             span_attrs=None) -> dict:
     """Launch-config resolution for one op call.
 
     An explicit ``plan=`` argument wins; otherwise the ambient step plan
@@ -100,7 +101,9 @@ def _resolve(kernel: str, D: dict, default: dict, plan) -> dict:
     zero-registry-traffic path; a miss -- including a plan gone stale
     because the registry generation moved (refit, new override) -- falls
     through to the full ``choose_or_default`` chain, which is what keeps
-    pinned overrides ranked above any frozen step plan.
+    pinned overrides ranked above any frozen step plan.  ``span_attrs``,
+    if given, maps the chosen config to attributes of that chain's
+    ``dispatch.choose`` span, to record what the launch will do.
     """
     if plan is None:
         plan = active_step_plan()
@@ -110,8 +113,11 @@ def _resolve(kernel: str, D: dict, default: dict, plan) -> dict:
             return cfg
     # Only the fall-through is traced: dispatch happens at trace time (once
     # per distinct shape), and the plan-hit path above must stay span-free.
-    with trace_span("dispatch.choose", kernel=kernel):
-        return choose_or_default(kernel, D, default)
+    with trace_span("dispatch.choose", kernel=kernel) as span:
+        cfg = choose_or_default(kernel, D, default)
+        if span_attrs is not None:
+            span.set(**span_attrs(cfg))
+        return cfg
 
 
 @functools.lru_cache(maxsize=128)
@@ -239,10 +245,17 @@ def flash_attention(
     bh, sq, d = q.shape
     skv = k.shape[1]
     key = f"flash_attn_d{d}" + ("_causal" if causal else "")
+
+    def tiles(cfg):
+        return _fit_tile(sq, cfg["bq"], 8), _fit_tile(skv, cfg["bkv"], 128)
+
+    def blocks(cfg):
+        # How much of the score square the launch computes.
+        return flash_block_counts(bh, sq, skv, *tiles(cfg), causal, window)
+
     cfg = _resolve(key, {"bh": bh, "sq": sq, "skv": skv},
-                   FLASH_DEFAULT, plan)
-    bq = _fit_tile(sq, cfg["bq"], 8)
-    bkv = _fit_tile(skv, cfg["bkv"], 128)
+                   FLASH_DEFAULT, plan, span_attrs=blocks)
+    bq, bkv = tiles(cfg)
     return flash_attention_pallas(
         q, k, v, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
         bq=bq, bkv=bkv, causal=causal, window=window, softcap=softcap,

@@ -206,9 +206,11 @@ def test_expert_rows_past_the_routed_ones_are_never_computed():
 # NoPE, the attention scale, the shared expert, experts held): each field
 # at its default must leave these programs as they were.  Kernels launch
 # at their static defaults, so no tuned driver in the process moves them.
+# The internlm2 Pallas forward's hash is the one after flash attention
+# began skipping its fully masked blocks, which alone changed that program.
 PROGRAM_HASHES = {
     ("internlm2-1.8b", False): ("b151dfdefd5b22c6", "ec9815adea8e826d"),
-    ("internlm2-1.8b", True): ("9b8ff716b3338ee9", "ec9815adea8e826d"),
+    ("internlm2-1.8b", True): ("2dd2406521140e94", "ec9815adea8e826d"),
     ("mamba2-130m", False): ("73505ce9472af452", "60db7e36fba1fdd5"),
     ("mamba2-130m", True): ("43b419110dd4cfee", "60db7e36fba1fdd5"),
     ("qwen3-moe-235b-a22b", False): ("7bc27acd781ad923", "0aa63b185a76de91"),

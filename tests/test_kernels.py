@@ -1,11 +1,18 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracle."""
 
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.core import V5E, registry
+from repro.core.driver import set_choice_listener
+from repro.kernels import ops, ref
+from repro.kernels.flash_attention import (block_class,
+                                           flash_attention_pallas,
+                                           flash_block_counts)
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
@@ -129,3 +136,111 @@ class TestSSDScan:
         out = ssd_parallel(x, dt, B, C, A, chunk=128)
         exp = ref.ssd_scan_ref(x, dt, B, C, A)
         np.testing.assert_allclose(out, exp, atol=5e-3, rtol=1e-3)
+
+
+def _score_mask(s, causal, window):
+    qpos = np.arange(s)[:, None]
+    kpos = np.arange(s)[None, :]
+    mask = np.ones((s, s), dtype=bool)
+    if causal:
+        mask &= qpos >= kpos
+    if window is not None:
+        mask &= qpos - kpos < window
+    return mask
+
+
+class TestFlashBlockSkip:
+    @pytest.mark.parametrize("bq,bkv", [(64, 128), (128, 128), (256, 128),
+                                        (128, 256)])
+    @pytest.mark.parametrize("causal,window", [
+        (True, None), (True, 128), (True, 129), (True, 191), (True, 200),
+        (False, None), (False, 200)])
+    def test_block_class_matches_mask(self, bq, bkv, causal, window):
+        """Dead iff the block's mask has no live pair, interior iff it has
+        no masked one, for every block of the square.  Windows 129 and 191
+        put a block's nearest or farthest pair exactly at the window's
+        edge."""
+        s = 512
+        mask = _score_mask(s, causal, window)
+        for iq in range(s // bq):
+            for ikv in range(s // bkv):
+                block = mask[iq * bq:(iq + 1) * bq, ikv * bkv:(ikv + 1) * bkv]
+                live, interior = block_class(iq, ikv, bq, bkv, causal,
+                                             window)
+                assert bool(live) == block.any(), (iq, ikv)
+                assert bool(interior) == block.all(), (iq, ikv)
+
+    @pytest.mark.parametrize("variant", ["causal", "window64", "window200",
+                                         "softcap", "gqa4"])
+    def test_skipping_matches_ref(self, variant):
+        """At s 512 with 128 tiles 6 of each head's 16 blocks are dead."""
+        rng = np.random.RandomState(16)
+        b, hq, hkv, s, d = 1, 4, 2, 512, 64
+        kw = dict(causal=True)
+        if variant == "gqa4":
+            hkv = 1
+        elif variant.startswith("window"):
+            kw["window"] = int(variant[len("window"):])
+        elif variant == "softcap":
+            kw["softcap"] = 30.0
+        q = (rng.randn(b * hq, s, d) * 0.3).astype(np.float32)
+        k = (rng.randn(b * hkv, s, d) * 0.3).astype(np.float32)
+        v = (rng.randn(b * hkv, s, d) * 0.3).astype(np.float32)
+        out = flash_attention_pallas(q, k, v, num_q_heads=hq,
+                                     num_kv_heads=hkv, bq=128, bkv=128,
+                                     interpret=True, **kw)
+        exp = ref.flash_attention_ref(q, k, v, num_q_heads=hq,
+                                      num_kv_heads=hkv, **kw)
+        np.testing.assert_allclose(out, exp, atol=2e-3, rtol=1e-3)
+
+
+class TestFlashBlockCounter:
+    KERNEL = "flash_attn_d128_causal"
+    TILES = {"bq": 2048, "bkv": 2048}
+
+    @pytest.fixture()
+    def clean(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("KLARAPTOR_CACHE_DIR", str(tmp_path / "cache"))
+        registry.clear()
+        set_choice_listener(None)
+        yield
+        registry.clear()
+
+    @pytest.mark.parametrize("bh,live,interior,total", [
+        (64, 640, 384, 1024),       # internlm2-1.8b, 4 x 8192 tokens
+        (128, 1280, 768, 2048),     # granite-4.0-h-small, 4 x 8192 tokens
+        (1, 10, 6, 16)])
+    def test_counts_at_the_forward_launch(self, bh, live, interior, total):
+        assert flash_block_counts(bh, 8192, 8192, 2048, 2048, True,
+                                  None) == {"live": live,
+                                            "interior": interior,
+                                            "total": total}
+
+    def _trace_launch(self, **kw):
+        from repro.trace import Tracer
+
+        bf = jnp.bfloat16
+        args = [jax.ShapeDtypeStruct((64, 8192, 128), bf),
+                jax.ShapeDtypeStruct((32, 8192, 128), bf),
+                jax.ShapeDtypeStruct((32, 8192, 128), bf)]
+        fn = functools.partial(ops.flash_attention, num_q_heads=16,
+                               num_kv_heads=8, use_pallas=True, **kw)
+        with Tracer() as tracer:
+            jax.eval_shape(fn, *args)
+        return tracer.spans()
+
+    def test_recorded_once_on_the_choose_path(self, clean):
+        registry.note_override(self.KERNEL, V5E.name,
+                               {"bh": 64, "sq": 8192, "skv": 8192},
+                               self.TILES)
+        spans = self._trace_launch()
+        assert [s.attrs for s in spans] == [
+            {"kernel": self.KERNEL, "live": 640, "interior": 384,
+             "total": 1024}]
+
+    def test_no_span_on_the_plan_hit_path(self, clean):
+        class Plan:
+            def resolve(self, kernel, D):
+                return TestFlashBlockCounter.TILES
+
+        assert self._trace_launch(plan=Plan()) == []

@@ -9,8 +9,8 @@ Three claims of the repro.introspect subsystem, measured end to end:
     stays in interactive territory (milliseconds, measured per kernel);
   * **zero-hand-spec tuning** -- the two auto-specced kernels (layernorm
     fusion, blocked column reduction) go introspect -> collect/fit ->
-    choose -> plan-table dispatch with no KernelSpec written anywhere, and
-    land within ``RATIO_BAR`` of the exhaustive optimum.
+    choose -> dispatch with no KernelSpec written anywhere, and land
+    within ``RATIO_BAR`` of the exhaustive optimum.
 
 Writes ``BENCH_introspect.json`` next to this file.
 
@@ -18,8 +18,10 @@ Writes ``BENCH_introspect.json`` next to this file.
     PYTHONPATH=src python benchmarks/bench_introspect.py --smoke    # CI gate
 
 ``--smoke`` exits non-zero on any fidelity disagreement, any auto-kernel
-selection ratio below the bar, or a plan-dispatch config that disagrees
+selection ratio below the bar, or a dispatched config that disagrees
 with the driver -- the loud-failure gate for introspection regressions.
+The latencies are CPU wall-clock times; the ratios are against the
+simulated v5e.
 """
 
 from __future__ import annotations
@@ -33,7 +35,6 @@ import numpy as np
 
 from repro.core import (Klaraptor, V5eSimulator, choose_or_default, registry,
                         selection_ratio)
-from repro.core.plan import precompile_plans
 from repro.introspect import spec_from_kernel
 from repro.introspect.tier1 import tier1_pairs
 
@@ -131,23 +132,16 @@ def bench_auto(seed: int = 11) -> list[dict]:
         for D in AUTO_SHAPES[label]:
             r = selection_ratio(ak.spec, sim, build.driver, D)
             ratios.append(r["ratio"])
-        # Plan-table serving: precompile the derived envelope, then check
-        # the O(1) dispatch path serves (plan hit) and returns the driver's
-        # config for an in-envelope shape.
-        env = ak.plan_envelope()
-        summary = precompile_plans({ak.name: env}, cache=False)
-        D_in = {d: int(v[len(v) // 2]) for d, v in env.items()}
-        before = registry.stats()["plan_hits"]
-        cfg = choose_or_default(ak.name, D_in, ak.defaults)
-        plan_agree = (registry.stats()["plan_hits"] == before + 1
-                      and cfg == build.driver.choose(D_in))
+        # Dispatch: the registered driver serves the op's launch config.
+        D = AUTO_SHAPES[label][0]
+        dispatch_agree = (choose_or_default(ak.name, D, ak.defaults)
+                          == build.driver.choose(D))
         rows.append({
             "kernel": ak.name,
             "introspect_ms": introspect_s * 1e3,
             "min_ratio": min(ratios),
             "ratios": ratios,
-            "plan_entries": summary["entries"],
-            "plan_agree": bool(plan_agree),
+            "dispatch_agree": bool(dispatch_agree),
             "probe_device_s": build.probe_device_seconds,
             "build_wall_s": build.build_wall_seconds,
             "n_operands": len(ak.spec.operands),
@@ -168,7 +162,7 @@ def run(seed: int = 11) -> dict:
         "auto": auto,
         "all_agree": all(r["agree"] for r in fidelity),
         "min_auto_ratio": min(r["min_ratio"] for r in auto),
-        "all_plan_agree": all(r["plan_agree"] for r in auto),
+        "all_dispatch_agree": all(r["dispatch_agree"] for r in auto),
         "max_introspect_ms": max(r["introspect_ms_cold"] for r in fidelity),
     }
 
@@ -190,8 +184,8 @@ def main(argv=None) -> list[str]:
         lines.append(
             f"introspect/auto_{r['kernel']},"
             f"{r['introspect_ms'] * 1e3:.0f},"
-            f"ratio={r['min_ratio']:.3f} plan_agree={r['plan_agree']} "
-            f"plan_entries={r['plan_entries']}")
+            f"ratio={r['min_ratio']:.3f} "
+            f"dispatch_agree={r['dispatch_agree']}")
     failures = []
     if not report["all_agree"]:
         failures.append("introspected tier-1 spec disagrees with hand spec")
@@ -199,8 +193,8 @@ def main(argv=None) -> list[str]:
         failures.append(
             f"auto-kernel selection ratio {report['min_auto_ratio']:.3f} "
             f"< {RATIO_BAR}")
-    if not report["all_plan_agree"]:
-        failures.append("auto-kernel plan dispatch disagrees with driver")
+    if not report["all_dispatch_agree"]:
+        failures.append("auto-kernel dispatch disagrees with driver")
     if report["max_introspect_ms"] > INTROSPECT_MS_BAR:
         failures.append(
             f"introspection took {report['max_introspect_ms']:.0f}ms "

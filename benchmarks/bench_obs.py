@@ -1,11 +1,12 @@
-"""Observatory benchmark: the acting-SLO loop closes, replay is exact,
-and observability-off dispatch stays free.
+"""Observatory benchmark on the CPU: the acting-SLO loop closes, and
+replay is exact.
 
-Three stages:
+Two stages:
 
   * **closed loop** -- a serving process with a deliberately corrupted fit
     (v5p physics published under the v5e name, as in bench_telemetry) and
-    an injected padding-waste regression runs under a full Observatory
+    a synthetic padding-waste regression (``bucket_step`` events written
+    to the ledger and the bus) runs under a full Observatory
     (metrics bus + burn-rate SLO rules + scorecard + retune queue), with
     telemetry in monitoring-only mode (``refit_enabled=False``) so the
     *SLO path* -- not the telemetry loop's own reflex -- must drive the
@@ -17,11 +18,7 @@ Three stages:
   * **replay** -- the run's JSONL ledger replayed through
     ``replay_ledgers`` must rebuild the live bus ``snapshot_json()``
     bit-identically (same event dicts, same anchored wall times, same
-    window rotation);
-  * **disabled overhead** -- with no bus installed and no listener, the
-    memoized ``choose_or_default`` path must stay within the same
-    floor-relative budget bench_trace gates: ``max(1us, 2x dict-probe
-    floor, 1.05x the committed BENCH_dispatch memo_vs_floor baseline)``.
+    window rotation).
 
 Writes ``BENCH_obs.json`` (schema ``version: 1``) next to this file.
 
@@ -40,7 +37,7 @@ import tempfile
 import time
 
 from repro.core import (Klaraptor, V5E, V5P, V5eSimulator, choose_or_default,
-                        lattice, matmul_spec, registry)
+                        matmul_spec, registry)
 from repro.fleet import RetuneQueue
 from repro.obs import Observatory, get_metrics_bus, replay_ledgers
 from repro.search import SearchBudget
@@ -50,11 +47,6 @@ from repro.trace import Ledger, read_ledger
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_PATH = os.path.join(HERE, "BENCH_obs.json")
-DISPATCH_BASELINE_PATH = os.path.join(HERE, "BENCH_dispatch.json")
-
-REGRESSION_MULT = 1.05       # vs the committed memo_vs_floor baseline
-MEMO_LATENCY_BAR_S = 1e-6    # absolute escape hatch (same as bench_trace)
-MEMO_FLOOR_MULT = 2.0        # ... and the floor-relative one
 
 INJECTED_WASTE = 0.75        # per-step padding waste; burn 0.75/0.35 > 2x
 MAX_STEPS = 64               # serving launches before giving up on drift
@@ -65,27 +57,13 @@ REFIT_DEVICE_SECONDS = 5.0   # retune budget: enough to rebuild a fit whose
 D_TARGET = {"m": 4096, "n": 4096, "k": 4096}
 MM_DEFAULT = {"bm": 128, "bn": 512, "bk": 512}
 
-AXES = {"m": [64, 128, 256, 512, 1024, 2048, 4096, 8192],
-        "n": [256, 512, 1024, 2048, 4096, 6144, 8192, 16384],
-        "k": [512, 1024, 2048, 4096]}
-
-
-def _time_best(fn, reps=7):
-    """Best-of-``reps`` wall time with the collector paused (the timeit
-    convention; see bench_dispatch)."""
-    import gc
-    best, out = float("inf"), None
-    was_enabled = gc.isenabled()
-    gc.disable()
-    try:
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            out = fn()
-            best = min(best, time.perf_counter() - t0)
-    finally:
-        if was_enabled:
-            gc.enable()
-    return out, best
+def _inject_bucket_step(led, waste: float, kernel: str) -> None:
+    """One synthetic padding-waste sample, into the ledger and the live
+    bus alike (so the replay stage sees the same stream)."""
+    event = {"type": "bucket_step", "hit": True, "waste": float(waste),
+             "kernel": kernel, "t_ns": time.monotonic_ns()}
+    led.append(event)
+    get_metrics_bus().ingest(event)
 
 
 def _corrupted_build(spec, seed: int):
@@ -132,7 +110,7 @@ def bench_closed_loop(seed: int = 29) -> dict:
         steps = 0
         for steps in range(1, MAX_STEPS + 1):
             choose_or_default(spec.name, D_TARGET, MM_DEFAULT)
-            tel.note_bucket_step(True, INJECTED_WASTE, kernel=spec.name)
+            _inject_bucket_step(led, INJECTED_WASTE, spec.name)
             if tel.drift_events:
                 break
 
@@ -168,7 +146,7 @@ def bench_closed_loop(seed: int = 29) -> dict:
         # shadow probes of the swapped-in fit must land back in band.
         for _ in range(MAX_STEPS):
             choose_or_default(spec.name, D_TARGET, MM_DEFAULT)
-            tel.note_bucket_step(True, 0.05, kernel=spec.name)
+            _inject_bucket_step(led, 0.05, spec.name)
         post_alerts = obs.evaluate()
         row = obs.scorecard.rows.get(row_key)
         ratio_recovered = (row.calibration() or {}).get("p50") if row else None
@@ -212,83 +190,12 @@ def bench_closed_loop(seed: int = 29) -> dict:
             os.environ["KLARAPTOR_CACHE_DIR"] = old_env
 
 
-def _baseline_memo_vs_floor(kernel: str = "matmul_b16") -> float | None:
-    """The committed PR-6 floor-relative memo cost for ``kernel``."""
-    try:
-        with open(DISPATCH_BASELINE_PATH) as f:
-            report = json.load(f)
-        for r in report["results"]:
-            if r["kernel"] == kernel:
-                return float(r["memo_vs_floor"])
-    except (OSError, KeyError, ValueError):
-        pass
-    return None
-
-
-def bench_disabled_overhead(seed: int = 23) -> dict:
-    """Memo-hit dispatch with no bus and no listener vs the dict floor.
-
-    The observatory's hot-path contract: an uninstalled bus is one module
-    global that nothing on the memoized path even reads -- so the cost
-    must be indistinguishable from the pre-observatory baseline."""
-    assert get_metrics_bus() is None
-    registry.clear()
-    spec = matmul_spec()
-    kl = Klaraptor(V5eSimulator(noise=0.03, seed=seed), cache=False)
-    kl.build_driver(spec, repeats=2, max_configs_per_size=16, register=True)
-    cols = lattice(AXES)
-    n = next(iter(cols.values())).shape[0]
-    shapes = [{d: int(cols[d][i]) for d in ("m", "n", "k")}
-              for i in range(n)]
-    default = {"bm": -1, "bn": -1, "bk": -1}
-    kernel = spec.name
-
-    live = [D for D in shapes
-            if choose_or_default(kernel, D, default) != default]
-    reps = max(1, 4096 // max(len(live), 1))
-
-    def dispatch_all():
-        for _ in range(reps):
-            for D in live:
-                choose_or_default(kernel, D, default)
-
-    _, off_s = _time_best(dispatch_all)
-    per_off = off_s / (reps * max(len(live), 1))
-
-    probe_table = {("k", "hw", tuple(D.items())): [default, "driver", 0, 0]
-                   for D in live}
-    probe_get = probe_table.get
-
-    def probe_all():
-        for _ in range(reps):
-            for D in live:
-                ent = probe_get(("k", "hw", tuple(D.items())))
-                ent[2] += 1
-
-    _, floor_s = _time_best(probe_all)
-    per_floor = floor_s / (reps * max(len(live), 1))
-    registry.clear()
-    return {
-        "n_shapes": len(live),
-        "memo_off_per_decision_s": per_off,
-        "floor_per_decision_s": per_floor,
-        "memo_vs_floor": per_off / max(per_floor, 1e-12),
-    }
-
-
 def run(seed: int = 29) -> dict:
-    loop = bench_closed_loop(seed=seed)
-    overhead = bench_disabled_overhead()
     return {
         "version": 1,
         "seed": seed,
-        "regression_mult": REGRESSION_MULT,
-        "memo_latency_bar_s": MEMO_LATENCY_BAR_S,
-        "memo_floor_mult": MEMO_FLOOR_MULT,
         "injected_waste": INJECTED_WASTE,
-        "baseline_memo_vs_floor": _baseline_memo_vs_floor(),
-        "loop": loop,
-        "overhead": overhead,
+        "loop": bench_closed_loop(seed=seed),
     }
 
 
@@ -299,7 +206,7 @@ def main(argv=None) -> list[str]:
     if not smoke:
         with open(OUT_PATH, "w") as f:
             json.dump(report, f, indent=2)
-    lp, ov = report["loop"], report["overhead"]
+    lp = report["loop"]
     lines = [
         f"obs/closed_loop,{lp['wall_seconds'] * 1e6:.0f},"
         f"breached={'+'.join(lp['slo_breached']) or 'none'} "
@@ -311,10 +218,6 @@ def main(argv=None) -> list[str]:
         f"obs/replay,{lp['ledger_events']},"
         f"bit_identical={lp['replay_bit_identical']} "
         f"ledger_events={lp['ledger_events']}",
-        f"obs/dispatch_off,{ov['memo_off_per_decision_s'] * 1e6:.3f},"
-        f"memo_vs_floor={ov['memo_vs_floor']:.2f}x "
-        f"baseline={report['baseline_memo_vs_floor']} "
-        f"shapes={ov['n_shapes']}",
     ]
 
     failures = []
@@ -338,16 +241,6 @@ def main(argv=None) -> list[str]:
     if not lp["replay_bit_identical"]:
         failures.append("ledger replay did not reproduce the live series "
                         "bit-identically")
-    floor = ov["floor_per_decision_s"]
-    budget = max(MEMO_LATENCY_BAR_S, MEMO_FLOOR_MULT * floor)
-    baseline = report["baseline_memo_vs_floor"]
-    if baseline is not None:
-        budget = max(budget, REGRESSION_MULT * baseline * floor)
-    if ov["memo_off_per_decision_s"] > budget:
-        failures.append(
-            f"bus-off memo dispatch {ov['memo_off_per_decision_s'] * 1e9:.0f}"
-            f"ns > budget {budget * 1e9:.0f}ns (floor {floor * 1e9:.0f}ns, "
-            f"baseline memo_vs_floor {baseline})")
     if failures:
         lines.append(f"obs/FAIL,0,{'; '.join(failures)}")
         if smoke:

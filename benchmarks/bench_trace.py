@@ -1,19 +1,7 @@
-"""Tracing-overhead benchmark: observability must not tax the hot path.
+"""Tracing-overhead benchmark on the CPU: the span primitives' own cost.
 
-Measures, on the memoized dispatch path (the PR-6 steady state) and the
-span primitives themselves:
+Measures:
 
-  * **disabled-tracing dispatch** -- ``choose_or_default`` memo hits with
-    no tracer installed must stay within 5% of the committed
-    ``BENCH_dispatch.json`` baseline, expressed floor-relative: the gate
-    budget is ``1.05 x baseline_memo_vs_floor x`` a dict-probe floor
-    measured *now*, so a throttled runner shifts budget and measurement
-    together (same calibration trick as bench_dispatch), with the
-    absolute-1us / 2x-floor escape hatches as a backstop;
-  * **enabled-tracing dispatch** -- the same loop with a Tracer installed:
-    the memo-hit path carries no spans, so installing a tracer must not
-    change its cost (reported as a ratio, gated loosely at the same
-    budget);
   * **span record cost** -- an enter/exit ``trace_span`` pair with a live
     tracer must cost <= max(2us, 2x a measured span floor): the floor is
     the irreducible interpreter cost of the same design (a factory call
@@ -24,7 +12,7 @@ span primitives themselves:
   * **disabled span cost** -- ``trace_span`` with no tracer (one global
     load + ``is None``) and the ``@traced`` passthrough, reported;
   * **ledger append** -- one JSONL line (json.dumps + write + flush),
-    reported (steady-state write volume is coalesced upstream).
+    reported.
 
 Writes ``BENCH_trace.json`` (schema ``version: 1``) next to this file.
 
@@ -40,29 +28,18 @@ import sys
 import tempfile
 import time
 
-from repro.core import (Klaraptor, V5eSimulator, choose_or_default, lattice,
-                        matmul_spec, registry)
 from repro.trace import Ledger, Tracer, set_tracer, trace_span
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_PATH = os.path.join(HERE, "BENCH_trace.json")
-DISPATCH_BASELINE_PATH = os.path.join(HERE, "BENCH_dispatch.json")
 
-REGRESSION_MULT = 1.05       # vs the committed memo_vs_floor baseline
-MEMO_LATENCY_BAR_S = 1e-6    # absolute escape hatch (same as bench_dispatch)
-MEMO_FLOOR_MULT = 2.0        # ... and the floor-relative one
 SPAN_RECORD_BAR_S = 2e-6     # absolute enabled enter/exit budget per span
 SPAN_FLOOR_MULT = 2.0        # ... scaled up to this x the measured span
                              # floor on boxes too slow for the absolute bar
 
-AXES = {"m": [64, 128, 256, 512, 1024, 2048, 4096, 8192],
-        "n": [256, 512, 1024, 2048, 4096, 6144, 8192, 16384],
-        "k": [512, 1024, 2048, 4096]}
-
-
 def _time_best(fn, reps=7):
     """Best-of-``reps`` wall time with the collector paused (the timeit
-    convention; see bench_dispatch)."""
+    convention)."""
     import gc
     best, out = float("inf"), None
     was_enabled = gc.isenabled()
@@ -76,82 +53,6 @@ def _time_best(fn, reps=7):
         if was_enabled:
             gc.enable()
     return out, best
-
-
-def _baseline_memo_vs_floor(kernel: str = "matmul_b16") -> float | None:
-    """The committed PR-6 floor-relative memo cost for ``kernel``."""
-    try:
-        with open(DISPATCH_BASELINE_PATH) as f:
-            report = json.load(f)
-        for r in report["results"]:
-            if r["kernel"] == kernel:
-                return float(r["memo_vs_floor"])
-    except (OSError, KeyError, ValueError):
-        pass
-    return None
-
-
-def bench_dispatch_overhead(seed: int = 23) -> dict:
-    """Memo-hit dispatch cost with tracing off vs on, plus the floor."""
-    registry.clear()
-    spec = matmul_spec()
-    kl = Klaraptor(V5eSimulator(noise=0.03, seed=seed), cache=False)
-    kl.build_driver(spec, repeats=2, max_configs_per_size=16, register=True)
-    cols = lattice(AXES)
-    n = next(iter(cols.values())).shape[0]
-    shapes = [{d: int(cols[d][i]) for d in ("m", "n", "k")}
-              for i in range(n)]
-    default = {"bm": -1, "bn": -1, "bk": -1}
-    kernel = spec.name
-
-    # Warm the decision memo: first pass per shape is the fill path.
-    live = [D for D in shapes
-            if choose_or_default(kernel, D, default) != default]
-    reps = max(1, 4096 // max(len(live), 1))
-
-    def dispatch_all():
-        for _ in range(reps):
-            for D in live:
-                choose_or_default(kernel, D, default)
-
-    set_tracer(None)
-    _, off_s = _time_best(dispatch_all)
-    per_off = off_s / (reps * max(len(live), 1))
-
-    tracer = Tracer()
-    tracer.install()
-    try:
-        _, on_s = _time_best(dispatch_all)
-    finally:
-        tracer.uninstall()
-    per_on = on_s / (reps * max(len(live), 1))
-    # the memo-hit path must stay span-free: a tracer records nothing here
-    spans_recorded = tracer.n_spans
-
-    # Machine-speed floor: bare dict probe with the same loop structure
-    # (see bench_dispatch for why the gate budgets against this).
-    probe_table = {("k", "hw", tuple(D.items())): [default, "driver", 0, 0]
-                   for D in live}
-    probe_get = probe_table.get
-
-    def probe_all():
-        for _ in range(reps):
-            for D in live:
-                ent = probe_get(("k", "hw", tuple(D.items())))
-                ent[2] += 1
-
-    _, floor_s = _time_best(probe_all)
-    per_floor = floor_s / (reps * max(len(live), 1))
-    registry.clear()
-    return {
-        "n_shapes": len(live),
-        "memo_off_per_decision_s": per_off,
-        "memo_on_per_decision_s": per_on,
-        "on_off_ratio": per_on / max(per_off, 1e-12),
-        "floor_per_decision_s": per_floor,
-        "memo_vs_floor": per_off / max(per_floor, 1e-12),
-        "spans_recorded_on_memo_path": spans_recorded,
-    }
 
 
 def _span_floor(n: int) -> float:
@@ -245,7 +146,7 @@ def bench_span_cost(n: int = 20000) -> dict:
                  "D": {"m": 1024, "n": 1024, "k": 1024},
                  "config": {"bm": 128, "bn": 512, "bk": 512},
                  "source": "driver", "predicted_s": 1e-3,
-                 "n_coalesced": 64, "t_ns": 123456789}
+                 "t_ns": 123456789}
         m = 2000
 
         def appends():
@@ -263,23 +164,13 @@ def bench_span_cost(n: int = 20000) -> dict:
     }
 
 
-def run(seed: int = 23) -> dict:
-    dispatch = bench_dispatch_overhead(seed=seed)
-    span = bench_span_cost()
-    baseline = _baseline_memo_vs_floor()
-    report = {
+def run() -> dict:
+    return {
         "version": 1,
-        "seed": seed,
-        "regression_mult": REGRESSION_MULT,
-        "memo_latency_bar_s": MEMO_LATENCY_BAR_S,
-        "memo_floor_mult": MEMO_FLOOR_MULT,
         "span_record_bar_s": SPAN_RECORD_BAR_S,
         "span_floor_mult": SPAN_FLOOR_MULT,
-        "baseline_memo_vs_floor": baseline,
-        "dispatch": dispatch,
-        "span": span,
+        "span": bench_span_cost(),
     }
-    return report
 
 
 def main(argv=None) -> list[str]:
@@ -289,15 +180,8 @@ def main(argv=None) -> list[str]:
     if not smoke:
         with open(OUT_PATH, "w") as f:
             json.dump(report, f, indent=2)
-    d, s = report["dispatch"], report["span"]
+    s = report["span"]
     lines = [
-        f"trace/dispatch_off,{d['memo_off_per_decision_s'] * 1e6:.3f},"
-        f"memo_vs_floor={d['memo_vs_floor']:.2f}x "
-        f"baseline={report['baseline_memo_vs_floor']} "
-        f"shapes={d['n_shapes']}",
-        f"trace/dispatch_on,{d['memo_on_per_decision_s'] * 1e6:.3f},"
-        f"on_off_ratio={d['on_off_ratio']:.2f} "
-        f"spans_on_memo_path={d['spans_recorded_on_memo_path']}",
         f"trace/span_record,{s['span_record_s'] * 1e6:.3f},"
         f"enabled enter/exit incl. ring+histogram "
         f"(floor {s['span_floor_s'] * 1e6:.3f}us)",
@@ -308,22 +192,6 @@ def main(argv=None) -> list[str]:
     ]
 
     failures = []
-    floor = d["floor_per_decision_s"]
-    budget = max(MEMO_LATENCY_BAR_S, MEMO_FLOOR_MULT * floor)
-    baseline = report["baseline_memo_vs_floor"]
-    if baseline is not None:
-        budget = max(budget, REGRESSION_MULT * baseline * floor)
-    for label, per in (("disabled", d["memo_off_per_decision_s"]),
-                       ("enabled", d["memo_on_per_decision_s"])):
-        if per > budget:
-            failures.append(
-                f"{label}-tracing memo dispatch {per * 1e9:.0f}ns > budget "
-                f"{budget * 1e9:.0f}ns (floor {floor * 1e9:.0f}ns, "
-                f"baseline memo_vs_floor {baseline})")
-    if d["spans_recorded_on_memo_path"] != 0:
-        failures.append(
-            f"memo-hit path recorded {d['spans_recorded_on_memo_path']} "
-            f"spans; it must stay span-free")
     span_budget = max(SPAN_RECORD_BAR_S, SPAN_FLOOR_MULT * s["span_floor_s"])
     if s["span_record_s"] > span_budget:
         failures.append(

@@ -12,11 +12,6 @@ Prints ``name,us_per_call,derived`` CSV lines:
   telemetry -- closed-loop drift-detection/refit recovery
                (BENCH_telemetry.json); prints telemetry/skipped if the
                demo cannot run here
-  dispatch -- the dispatch ladder end to end: decision-memo and plan-table
-              steady-state latency, choose_many batch-compilation speedup,
-              and the step-plan serving loop vs per-call dispatch
-              (BENCH_dispatch.json, schema v2); prints dispatch/skipped if
-              the demo cannot run here
   introspect -- spec-extraction fidelity vs the hand-written tier-1 specs
               plus zero-hand-spec tuning of the auto kernels
               (BENCH_introspect.json); prints introspect/skipped if the
@@ -25,9 +20,7 @@ Prints ``name,us_per_call,derived`` CSV lines:
               kill/hang fault recovery with bit-identical merges, and the
               ledger->retune->cache pipeline (BENCH_fleet.json); prints
               fleet/skipped if the demo cannot run here
-  serving -- bucketed in-graph dispatch (one trace over >= 32 raw shapes,
-              bucket configs bit-identical to host choose()) and the async
-              continuous-batching front-end vs the sync engine
+  serving -- the async continuous-batching front-end vs the sync engine
               (BENCH_serving.json); prints serving/skipped if the demo
               cannot run here
 """
@@ -64,14 +57,6 @@ def main() -> None:
             print(line, flush=True)
     except Exception as e:  # missing telemetry artifacts / no cache dir
         print(f"telemetry/skipped,0,{e!r}", flush=True)
-    # Trailing for the same reason: a plan-dispatch failure must not mask
-    # the benches above (and vice versa).
-    try:
-        from benchmarks import bench_dispatch
-        for line in bench_dispatch.main([]):
-            print(line, flush=True)
-    except Exception as e:
-        print(f"dispatch/skipped,0,{e!r}", flush=True)
     # Trailing: introspection fidelity + auto-spec tuning must not mask the
     # benches above (and vice versa).
     try:
@@ -96,7 +81,7 @@ def main() -> None:
             print(line, flush=True)
     except Exception as e:
         print(f"fleet/skipped,0,{e!r}", flush=True)
-    # Trailing: the bucketed-dispatch / async-serving gates must not mask
+    # Trailing: the async-serving gates must not mask
     # the benches above (and vice versa).
     try:
         from benchmarks import bench_serving
@@ -104,9 +89,8 @@ def main() -> None:
             print(line, flush=True)
     except Exception as e:
         print(f"serving/skipped,0,{e!r}", flush=True)
-    # Trailing: the observatory gates (SLO closed loop, replay fidelity,
-    # bus-off dispatch overhead) must not mask the benches above (and
-    # vice versa).
+    # Trailing: the observatory gates (SLO closed loop, replay fidelity)
+    # must not mask the benches above (and vice versa).
     try:
         from benchmarks import bench_obs
         for line in bench_obs.main([]):

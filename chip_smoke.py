@@ -153,7 +153,7 @@ def require_tuned(name: str, events) -> None:
     for ev in events:
         log(f"  {ev.kernel} at {dict(ev.D)}: config {dict(ev.config)} "
             f"(source: {ev.source})")
-        if ev.source not in ("driver", "plan"):
+        if ev.source != "driver":
             raise AssertionError(
                 f"{name}: {ev.kernel} launched with a {ev.source} config, "
                 f"not a tuned one")

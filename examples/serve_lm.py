@@ -89,14 +89,6 @@ def main() -> None:
               f"compile(s), {cc['prefill_chunk']} prefill-chunk compile(s) "
               f"across {args.requests} mixed-length prompts "
               f"(chunk={engine.prefill_chunk})")
-    if engine._step_plan is not None:
-        sp = engine._step_plan.describe()
-        print(f"step plan: {sp['entries']} kernel configs frozen at "
-              f"registry generation {sp['generation']} "
-              f"(sources: {sp['sources']}) -- traced decode steps dispatch "
-              f"from the frozen table, zero registry round-trips")
-    from repro.core.driver import registry
-    print(f"decision-memo hits this run: {registry.memo_hits()}")
     if tracer is not None:
         n = tracer.write_chrome_trace(args.trace)
         tracer.uninstall()

@@ -3,9 +3,8 @@
 Public API re-exports.  See DESIGN.md for the paper-to-TPU mapping.
 """
 
-from .buckets import BucketLattice, pad_to, pow2_span
 from .cache import (
-    CacheEntry, DriverCache, PlanEntry, cache_key, default_cache,
+    CacheEntry, DriverCache, cache_key, default_cache,
     default_cache_dir, spec_fingerprint,
 )
 from .device_model import (
@@ -13,13 +12,10 @@ from .device_model import (
     KernelTraffic, ProbeBatch, ProbeRecord, RowProbe, TrafficOperand,
     TrafficTable, V5eSimulator, dtype_bytes, hardware_for, target_hardware,
 )
-from .device_plan import (
-    BucketedDispatch, DevicePlanTable, build_bucketed_dispatch, pack_shape32,
-)
 from .driver import (
-    ChoiceEvent, DriverProgram, WarmStartSummary, choose_or_default, dkey,
-    get_choice_listener, get_driver, memo_key, register_driver, registry,
-    set_choice_listener, set_decision_memo, warm_start_from_cache,
+    ChoiceEvent, DriverProgram, WarmStartSummary, choose_or_default,
+    get_choice_listener, get_driver, register_driver, registry,
+    set_choice_listener, warm_start_from_cache,
 )
 from .fitting import FitResult, fit_auto, fit_polynomial, fit_rational
 from .kernel_spec import (
@@ -29,16 +25,8 @@ from .kernel_spec import (
 )
 from .occupancy import cuda_occupancy_program, tpu_pipeline_occupancy_program
 from .perf_model import LOW_LEVEL_METRICS, build_time_program
-from .plan import (
-    LaunchPlanTable, compile_plan, lattice, pack_shape, plan_key,
-    precompile_plans,
-)
 from .polynomial import Polynomial, design_matrix, monomial_exponents
 from .rational import RationalFunction
-from .step_plan import (
-    KernelRequest, StepPlan, active_step_plan, build_step_plan,
-    use_step_plan,
-)
 from .rational_program import (
     BinOp, Ceil, Const, Expr, Fitted, Floor, Max, Min, RationalProgram,
     Select, Var, ceil_div, const, floor_div, specialize_expr, var,
@@ -48,30 +36,21 @@ from .tuner import (
 )
 
 __all__ = [
-    "BucketLattice", "pad_to", "pow2_span",
-    "CacheEntry", "DriverCache", "PlanEntry", "cache_key", "default_cache",
+    "CacheEntry", "DriverCache", "cache_key", "default_cache",
     "default_cache_dir", "spec_fingerprint",
     "DEVICE_KINDS", "DTYPE_BYTES", "V5E", "V5P", "DeviceModel",
     "HardwareParams", "KernelTraffic", "ProbeBatch", "ProbeRecord",
     "RowProbe", "TrafficOperand", "TrafficTable", "V5eSimulator",
     "dtype_bytes", "hardware_for", "target_hardware",
     "ChoiceEvent", "DriverProgram", "WarmStartSummary", "choose_or_default",
-    "dkey", "get_choice_listener", "get_driver", "memo_key",
-    "register_driver",
-    "registry", "set_choice_listener", "set_decision_memo",
-    "warm_start_from_cache",
-    "BucketedDispatch", "DevicePlanTable", "build_bucketed_dispatch",
-    "pack_shape32",
-    "KernelRequest", "StepPlan", "active_step_plan", "build_step_plan",
-    "use_step_plan",
+    "get_choice_listener", "get_driver", "register_driver",
+    "registry", "set_choice_listener", "warm_start_from_cache",
     "FitResult", "fit_auto", "fit_polynomial", "fit_rational",
     "CandidateTable", "GridAxis", "KernelSpec", "Operand", "SpecError",
     "flash_attention_spec",
     "matmul_spec", "moe_gmm_spec", "polybench_suite", "ssd_scan_spec",
     "cuda_occupancy_program", "tpu_pipeline_occupancy_program",
     "LOW_LEVEL_METRICS", "build_time_program",
-    "LaunchPlanTable", "compile_plan", "lattice", "pack_shape", "plan_key",
-    "precompile_plans",
     "Polynomial", "design_matrix", "monomial_exponents",
     "RationalFunction",
     "BinOp", "Ceil", "Const", "Expr", "Fitted", "Floor", "Max", "Min",

@@ -22,14 +22,6 @@ Two hashes protect an entry:
 Layout: ``<root>/<kernel>/<key>.json``.  The root defaults to
 ``$KLARAPTOR_CACHE_DIR`` or ``~/.cache/klaraptor``.
 
-A second entry kind, ``PlanEntry`` (``<root>/<kernel>/<key>.plan.json``),
-stores *compiled launch plans*: the precomputed (shape -> config) tables
-produced by ``choose_many`` over a serving process's traffic envelope (see
-core/plan.py).  Plan entries carry the same two-hash protection and the
-same ``tuning_version`` generation ordering as driver entries, and
-``invalidate(below_version=...)`` evicts both kinds together -- a drift
-refit that retires a fit generation retires its plans with it.
-
 ``Klaraptor.build_driver`` writes through this store; the driver registry
 (``core/driver.py``) reads through it, so ``choose_or_default`` -- and with
 it ``kernels/ops.py`` and the serving engine -- warm-start tuned drivers
@@ -51,7 +43,7 @@ from .device_model import HardwareParams
 from .kernel_spec import KernelSpec
 
 __all__ = [
-    "DriverCache", "CacheEntry", "PlanEntry", "cache_key",
+    "DriverCache", "CacheEntry", "cache_key",
     "spec_fingerprint", "default_cache", "default_cache_dir",
 ]
 
@@ -171,22 +163,6 @@ class CacheEntry:
         return _sha(payload)
 
 
-@dataclass
-class PlanEntry:
-    """One compiled launch plan (core/plan.py LaunchPlanTable.to_json)."""
-
-    kernel: str
-    key: str
-    hw_name: str
-    plan: dict                      # LaunchPlanTable JSON payload
-    created_at: float
-    tuning_version: int = 0
-
-    def content_hash(self) -> str:
-        return _sha({"plan": self.plan,
-                     "tuning_version": self.tuning_version})
-
-
 class DriverCache:
     """On-disk, content-addressed store of generated driver artifacts."""
 
@@ -199,9 +175,6 @@ class DriverCache:
 
     def path(self, kernel: str, key: str) -> str:
         return os.path.join(self._kernel_dir(kernel), f"{key}.json")
-
-    def plan_path(self, kernel: str, key: str) -> str:
-        return os.path.join(self._kernel_dir(kernel), f"{key}.plan.json")
 
     # -- read ----------------------------------------------------------------
     def get(self, kernel: str, key: str) -> CacheEntry | None:
@@ -238,7 +211,7 @@ class DriverCache:
             return []
         out = []
         for f in sorted(names):
-            if not f.endswith(".json") or f.endswith(".plan.json"):
+            if not f.endswith(".json"):
                 continue
             p = os.path.join(d, f)
             entry = self._load(p)
@@ -278,78 +251,6 @@ class DriverCache:
             return None
         return entry
 
-    # -- plan artifacts (compiled launch plans, core/plan.py) -----------------
-    def get_plan(self, kernel: str, key: str) -> PlanEntry | None:
-        """Plan entry for an exact plan key, or None (miss / stale)."""
-        return self._load_plan(self.plan_path(kernel, key), expect_key=key)
-
-    def lookup_latest_plan(self, kernel: str,
-                           hw_name: str | None = None) -> PlanEntry | None:
-        """Newest valid plan for a kernel, ordered like ``lookup_latest``:
-        highest tuning generation first, then build timestamp."""
-        best: PlanEntry | None = None
-        for _, entry in self._plan_entries(kernel, hw_name):
-            if best is None or (entry.tuning_version, entry.created_at) > \
-                    (best.tuning_version, best.created_at):
-                best = entry
-        return best
-
-    def _plan_entries(self, kernel: str, hw_name: str | None = None
-                      ) -> list[tuple[str, PlanEntry]]:
-        d = self._kernel_dir(kernel)
-        try:
-            names = os.listdir(d)
-        except OSError:
-            return []
-        out = []
-        for f in sorted(names):
-            if not f.endswith(".plan.json"):
-                continue
-            p = os.path.join(d, f)
-            entry = self._load_plan(p)
-            if entry is not None and (hw_name is None
-                                      or entry.hw_name == hw_name):
-                out.append((p, entry))
-        return out
-
-    def _load_plan(self, path: str, expect_key: str | None = None
-                   ) -> PlanEntry | None:
-        try:
-            with open(path) as f:
-                raw = json.load(f)
-            entry = PlanEntry(
-                kernel=raw["kernel"], key=raw["key"],
-                hw_name=raw.get("hw_name", ""), plan=raw["plan"],
-                created_at=raw.get("created_at", 0.0),
-                tuning_version=int(raw.get("tuning_version", 0)))
-        except (OSError, ValueError, KeyError):
-            return None
-        if raw.get("content_hash") != entry.content_hash() or \
-                (expect_key is not None and entry.key != expect_key):
-            try:
-                os.remove(path)
-            except OSError:
-                pass
-            return None
-        return entry
-
-    def put_plan(self, entry: PlanEntry) -> str:
-        d = self._kernel_dir(entry.kernel)
-        os.makedirs(d, exist_ok=True)
-        path = self.plan_path(entry.kernel, entry.key)
-        raw = {
-            "version": _ENTRY_VERSION,
-            "kernel": entry.kernel,
-            "key": entry.key,
-            "hw_name": entry.hw_name,
-            "plan": entry.plan,
-            "created_at": entry.created_at or time.time(),
-            "tuning_version": entry.tuning_version,
-            "content_hash": entry.content_hash(),
-        }
-        _write_json_atomic(path, raw)
-        return path
-
     # -- write ---------------------------------------------------------------
     def put(self, entry: CacheEntry) -> str:
         d = self._kernel_dir(entry.kernel)
@@ -379,17 +280,12 @@ class DriverCache:
         the invalidate-on-refit path: once the telemetry loop has written a
         corrected generation-N fit, generations < N are evicted so no process
         can warm-start from the fit that drifted.  ``hw_name`` scopes the
-        eviction to one device's artifacts.  Compiled launch plans are
-        evicted under the same rule: a plan is frozen output of its fit
-        generation and must never outlive it.
+        eviction to one device's artifacts.
         """
         removed = 0
-        doomed = [
-            (path, entry.tuning_version)
-            for path, entry in (self._entries(kernel, hw_name)
-                                + self._plan_entries(kernel, hw_name))]
-        for path, version in doomed:
-            if below_version is not None and version >= below_version:
+        for path, entry in self._entries(kernel, hw_name):
+            if below_version is not None and \
+                    entry.tuning_version >= below_version:
                 continue
             try:
                 os.remove(path)

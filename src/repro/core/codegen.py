@@ -15,15 +15,7 @@ program -- with:
     parameter -- with every constraint applied as a vectorized mask,
   * ``choose(**D)``: steps 4-6's runtime selection -- evaluate E once over
     the whole candidate table, argmin + the occupancy tie-break heuristic in
-    numpy (no per-config Python loop), memoized into a decision history,
-  * ``choose_many(**D_columns)``: the launch-plan compilation entry point --
-    the same selection batched over a whole lattice of shapes in one
-    broadcast (shapes x configs) ndarray pass.  Data parameters enter as
-    (S, 1) columns so every D-only subexpression of the rational program is
-    computed once per shape (hoisted out of the per-config evaluation), and
-    all S argmin + tie-break selections happen in one set of masked
-    reductions.  Feeding a traffic envelope through it costs roughly one
-    vectorized pass instead of S ``choose()`` calls.
+    numpy (no per-config Python loop), memoized into a decision history.
 
 The generated source has no imports beyond ``numpy`` and no dependency on
 this package: it can be dropped next to any JAX program, exactly as the
@@ -248,86 +240,6 @@ def generate_driver_source(
     cfg = tuple(int(cols[p][pick]) for p in PROGRAM_PARAMS)
     _HISTORY[key] = cfg
     return dict(zip(PROGRAM_PARAMS, cfg))
-''')
-
-    # choose_many(): launch-plan compilation -- the same selection batched
-    # over S shapes in one broadcast (S, C) pass.  D columns are reshaped to
-    # (S, 1) so broadcasting hoists every D-only subexpression out of the
-    # per-config axis; the per-shape argmin + tie-break runs as masked
-    # reductions that replicate choose()'s lexsort order exactly (near-
-    # optimal first, then most pipeline buffers, then fewest grid steps,
-    # then lowest candidate index).
-    d_unpack = "\n".join(f"    {d} = _d_flat[{i}].reshape(-1, 1)"
-                         for i, d in enumerate(spec.data_params))
-    nv_idx = [i for i, c in enumerate(spec.constraints)
-              if not _constraint_vectorizable(c, spec, hw)]
-    feas_srcs = [f"    feas = feas & ({c})"
-                 for c in spec.constraints
-                 if _constraint_vectorizable(c, spec, hw)]
-    for a in spec.grid:
-        if a.block is not None and isinstance(a.data, str):
-            feas_srcs.append(
-                f"    feas = feas & ({a.block} <= (({a.data} + 7) // 8) * 8)")
-    feas_lines = "\n".join(feas_srcs)
-    row_scalars = ("{" + ", ".join(
-        [f"{d!r}: int(_d_flat[{i}][_s])"
-         for i, d in enumerate(spec.data_params)]
-        + ["'vmem': VMEM_LIMIT_BYTES"]) + "}")
-    nv_block = "" if not nv_idx else f'''\
-    for _ci in {tuple(nv_idx)!r}:
-        for _s in range(S):
-            feas[_s] &= _row_mask(_ci, {row_scalars}, cols)
-'''
-    parts.append(textwrap.dedent(f'''\
-        def choose_many({d_sig}, margin=0.02):
-            """Batched runtime selection over a lattice of data shapes.
-
-            Each data parameter is a 1-D array (scalars broadcast) of S
-            shapes; the full candidate grid is evaluated against all of
-            them in one (S, C) ndarray pass.  Returns ``(configs, ok)``:
-            ``configs`` maps each program parameter to an (S,) int64
-            column, ``ok`` flags shapes with a feasible configuration
-            (rows with ``ok`` False hold zeros).  Agrees exactly with
-            per-shape ``choose`` (same margin and tie-break); every chosen
-            row is memoized into the decision history.
-            """
-            _d_flat = np.broadcast_arrays(*[
-                np.asarray(_x, dtype=np.int64).reshape(-1)
-                for _x in ({d_sig},)])
-            S = _d_flat[0].shape[0]
-        ''') + d_unpack + f'''
-    grids = np.meshgrid(
-        *[np.asarray(PARAM_CANDIDATES[p], dtype=np.int64)
-          for p in PROGRAM_PARAMS], indexing="ij")
-    cols = {{p: g.reshape(-1) for p, g in zip(PROGRAM_PARAMS, grids)}}
-''' + unpack + f'''
-    vmem = VMEM_LIMIT_BYTES
-    feas = np.ones((S, {p_names[0]}.shape[0]), dtype=bool)
-''' + (feas_lines + "\n" if feas_lines else "") + nv_block + f'''\
-    feas = feas & (stage_bytes({sig}) * {spec.pipeline_buffers}
-                   <= VMEM_LIMIT_BYTES)
-    with np.errstate(all="ignore"):
-        est = np.asarray(estimate({sig}), dtype=np.float64)
-    est = np.broadcast_to(est, feas.shape).copy()
-    est[~(feas & np.isfinite(est))] = np.inf
-    ok = np.isfinite(est).any(axis=1)
-    near = feas & (est <= np.min(est, axis=1)[:, None] * (1.0 + margin))
-    buffers = np.broadcast_to(np.asarray(
-        pipeline_buffers({sig}), dtype=np.float64), feas.shape)
-    steps = np.broadcast_to(np.asarray(
-        grid_steps({sig}), dtype=np.float64), feas.shape)
-    tie = np.where(near, buffers, -np.inf)
-    tie_mask = near & (tie == np.max(tie, axis=1)[:, None])
-    tie = np.where(tie_mask, steps, np.inf)
-    tie_mask &= tie == np.min(tie, axis=1)[:, None]
-    pick = np.argmax(tie_mask, axis=1)
-    out = {{p: np.where(ok, c[pick], 0).astype(np.int64)
-           for p, c in cols.items()}}
-    for _s in range(S):
-        if ok[_s]:
-            _HISTORY[tuple(int(a[_s]) for a in _d_flat)] = \\
-                tuple(int(out[p][_s]) for p in PROGRAM_PARAMS)
-    return out, ok
 ''')
 
     return "\n\n".join(parts)

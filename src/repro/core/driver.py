@@ -10,8 +10,8 @@ code can ask for tuned launch parameters with one call.  The registry *reads
 through* the persistent driver-artifact cache (core/cache.py): a driver built
 by any earlier process is loaded from disk on first use instead of being
 rebuilt -- the warm-start path that lets serving fleets share tuning work.
-Decisions are memoized both inside the generated module (its _HISTORY table)
-and here.
+Decisions are memoized inside the generated module (its _HISTORY table),
+the paper's per-invocation decision history.
 """
 
 from __future__ import annotations
@@ -29,12 +29,10 @@ from repro.trace import trace_span
 
 from .codegen import compile_driver_module
 from .device_model import HardwareParams, V5E
-from .plan import LaunchPlanTable
 
 __all__ = ["ChoiceEvent", "DriverProgram", "WarmStartSummary", "registry",
            "register_driver", "get_driver", "choose_or_default",
            "set_choice_listener", "get_choice_listener",
-           "set_decision_memo", "dkey",
            "warm_start_from_cache", "fit_tile"]
 
 
@@ -62,38 +60,17 @@ logger = logging.getLogger(__name__)
 Dims = Mapping[str, int]
 
 
-def dkey(D: Dims) -> tuple:
-    """Canonical hashable form of a data-parameter dict (sorted item
-    tuple) -- the shape key used by step plans and the registry's
-    per-shape tables."""
-    return tuple(sorted(D.items()))
-
-
-def memo_key(kernel: str, hw_name: str, D: Dims) -> tuple:
-    """The decision memo's key form: D in *insertion* order, not sorted
-    (``choose_or_default``'s fast path can't afford the sort; see the
-    comment there).  Exposed so tests and tools can probe memo entries
-    without duplicating the key layout."""
-    return (kernel, hw_name, tuple(D.items()))
-
-
 @dataclass(frozen=True)
 class ChoiceEvent:
     """One launch-parameter decision, as seen by the telemetry listener.
 
-    ``source`` names the path that produced the config: ``"driver"`` (the
-    rational program chose), ``"override"`` (a telemetry-pinned per-shape
-    config), ``"plan"`` (compiled launch-plan probe),
-    ``"search"``/``"search_memo"`` (the online-search escalation), or
-    ``"default"`` (fell back to the static heuristic).  ``predicted_s``
+    ``source`` names the path that produced the config: ``"override"`` (a
+    telemetry-pinned per-shape config), ``"driver"`` (the rational program
+    chose), ``"search"``/``"search_memo"`` (the online-search escalation),
+    or ``"default"`` (fell back to the static heuristic).  ``predicted_s``
     is the driver's rational-program time estimate for the returned config
     -- the prediction that runtime observability checks against observed
     launches -- and is only computed when a listener is installed.
-
-    ``n_coalesced`` batches steady-state traffic: decision-memo hits past
-    the per-key full-fidelity window are *coalesced* into one sampled
-    event carrying how many launches it stands for, so the listener still
-    sees traffic volume without the hot path paying one event per launch.
 
     ``t_ns`` is a monotonic-clock stamp (``time.monotonic_ns``) taken at
     emission so the flight ledger and traces can order events without
@@ -107,7 +84,6 @@ class ChoiceEvent:
     source: str
     predicted_s: float | None
     hw_name: str
-    n_coalesced: int = 1
     t_ns: int | None = None
 
 
@@ -136,8 +112,7 @@ def get_choice_listener() -> Callable[[ChoiceEvent], None] | None:
 
 
 def _notify(kernel: str, D: Dims, config: dict, source: str,
-            predicted_s: float | None, hw: HardwareParams,
-            n_coalesced: int = 1) -> None:
+            predicted_s: float | None, hw: HardwareParams) -> None:
     global _listener_error_warned
     if _choice_listener is None:
         return
@@ -145,7 +120,7 @@ def _notify(kernel: str, D: Dims, config: dict, source: str,
         _choice_listener(ChoiceEvent(
             kernel=kernel, D=dict(D), config=dict(config), source=source,
             predicted_s=predicted_s, hw_name=hw.name,
-            n_coalesced=n_coalesced, t_ns=time.monotonic_ns()))
+            t_ns=time.monotonic_ns()))
     except Exception:
         if not _listener_error_warned:
             _listener_error_warned = True
@@ -155,88 +130,18 @@ def _notify(kernel: str, D: Dims, config: dict, source: str,
                 exc_info=True)
 
 
-# -- decision memo ------------------------------------------------------------
-# A per-(kernel, hw, D) memo consulted before everything else in
-# ``choose_or_default``: the steady-state serving hot path is one dict probe,
-# no registry traffic, no plan-table hash, no lock.  Entries are only ever
-# valid for one registry *generation* -- any mutation that could change a
-# decision (driver registration, refit invalidation, a pinned override, a
-# new plan table) bumps the generation and drops the whole memo, so a stale
-# config can never serve.
-#
-# With a choice listener installed, memo hits still feed telemetry: the
-# first ``MEMO_FULL_WINDOW`` hits per entry emit one full-fidelity event
-# each (original source, fresh predicted time -- indistinguishable from the
-# slow path, so drift detection sees a new fit at full rate), after which
-# hits are *coalesced* and one sampled event per ``MEMO_NOTIFY_EVERY``
-# launches carries the accumulated count.  With no listener, a memo hit
-# does no notification work at all.
-MEMO_FULL_WINDOW = 16
-MEMO_NOTIFY_EVERY = 64
-
-_memo_enabled = True
-
-
-def set_decision_memo(enabled: bool) -> bool:
-    """Enable/disable the steady-state decision memo (returns the previous
-    setting).  Disabling is for benchmarks and tests that need to measure
-    or exercise the un-memoized dispatch path; serving should leave it on."""
-    global _memo_enabled
-    prev = _memo_enabled
-    _memo_enabled = bool(enabled)
-    return prev
-
-
-def _memo_predicted(kernel: str, D: Dims, config: dict,
-                    hw: HardwareParams) -> float | None:
-    """Fresh rational-program estimate for an emitted memo event (only
-    computed for the events that are actually emitted)."""
-    drv = registry.get(kernel)
-    if drv is None:
-        return None
-    try:
-        return drv.estimate(D, config)
-    except Exception:
-        return None
-
-
-def _memo_notify(kernel: str, D: Dims, ent: list,
-                 hw: HardwareParams) -> None:
-    """Telemetry for one memo hit: full-fidelity inside the per-entry
-    window, coalesced-and-sampled after it.  ``ent`` is the mutable memo
-    entry ``[config, source, hits, pending]``."""
-    ent[2] += 1
-    ent[3] += 1
-    if ent[2] <= MEMO_FULL_WINDOW:
-        ent[3] = 0
-        _notify(kernel, D, ent[0], ent[1],
-                _memo_predicted(kernel, D, ent[0], hw), hw)
-        return
-    if ent[3] >= MEMO_NOTIFY_EVERY:
-        pending, ent[3] = ent[3], 0
-        _notify(kernel, D, ent[0], ent[1],
-                _memo_predicted(kernel, D, ent[0], hw), hw,
-                n_coalesced=pending)
-
-
 @dataclass
 class DriverProgram:
     kernel: str
     source: str
     namespace: dict = field(repr=False)
     hw: HardwareParams = V5E
-    # Tuning generation of the fit this driver was built from (0 = plain
-    # compile-time build); compiled launch plans are stamped with it so the
-    # registry can tell a plan derived from this driver from a stale one.
-    tuning_version: int = 0
 
     @classmethod
     def from_source(cls, kernel: str, source: str,
-                    hw: HardwareParams = V5E,
-                    tuning_version: int = 0) -> "DriverProgram":
+                    hw: HardwareParams = V5E) -> "DriverProgram":
         return cls(kernel=kernel, source=source,
-                   namespace=compile_driver_module(source), hw=hw,
-                   tuning_version=tuning_version)
+                   namespace=compile_driver_module(source), hw=hw)
 
     @property
     def data_params(self) -> tuple[str, ...]:
@@ -245,16 +150,6 @@ class DriverProgram:
     @property
     def program_params(self) -> tuple[str, ...]:
         return tuple(self.namespace["PROGRAM_PARAMS"])
-
-    @property
-    def source_hash(self) -> str:
-        """Identity of the generated module (stamps compiled launch plans)."""
-        h = self.namespace.get("__source_hash__")
-        if h is None:
-            import hashlib
-            h = hashlib.sha256(self.source.encode()).hexdigest()[:16]
-            self.namespace["__source_hash__"] = h
-        return h
 
     # -- step 4: rational program evaluation ---------------------------------
     def estimate(self, D: Dims, P: Dims) -> float:
@@ -274,40 +169,6 @@ class DriverProgram:
     def choose(self, D: Dims, margin: float = 0.02) -> dict[str, int]:
         return self.namespace["choose"](**D, margin=margin)
 
-    def choose_many(self, D_table: Mapping[str, "np.ndarray"],
-                    margin: float = 0.02
-                    ) -> tuple[dict[str, np.ndarray], np.ndarray]:
-        """Batched selection over a whole lattice of shapes at once.
-
-        ``D_table`` maps each data parameter to an aligned column of S
-        values.  Returns ``(configs, ok)``: per-program-param (S,) int64
-        columns and the per-shape feasibility mask.  Modern driver modules
-        run this as one broadcast (shapes x configs) numpy pass; a legacy
-        cached artifact (built before ``choose_many`` existed) degrades to
-        a per-shape ``choose`` loop with identical results.
-        """
-        cols = [np.asarray(D_table[d], dtype=np.int64).reshape(-1)
-                for d in self.data_params]
-        cols = np.broadcast_arrays(*cols)
-        n = int(cols[0].shape[0]) if cols else 0
-        registry.note_choose_many(n)
-        fn = self.namespace.get("choose_many")
-        if fn is not None:
-            return fn(**dict(zip(self.data_params, cols)), margin=margin)
-        params = self.program_params
-        out = {p: np.zeros(n, dtype=np.int64) for p in params}
-        ok = np.zeros(n, dtype=bool)
-        for s in range(n):
-            D = {d: int(c[s]) for d, c in zip(self.data_params, cols)}
-            try:
-                cfg = self.choose(D, margin=margin)
-            except (ValueError, KeyError, TypeError):
-                continue
-            ok[s] = True
-            for p in params:
-                out[p][s] = cfg[p]
-        return out, ok
-
     def save(self, path: str) -> None:
         with open(path, "w") as f:
             f.write(self.source)
@@ -324,10 +185,7 @@ _MISS = object()
 
 
 def _fresh_stats() -> dict[str, int]:
-    return {"disk_cache_hits": 0, "disk_cache_misses": 0,
-            "plan_hits": 0, "plan_misses": 0,
-            "choose_many_calls": 0, "choose_many_rows": 0,
-            "plan_invalidations": 0, "memo_invalidations": 0}
+    return {"disk_cache_hits": 0, "disk_cache_misses": 0}
 
 
 class _Registry:
@@ -338,22 +196,11 @@ class _Registry:
         self._cache_misses: set[tuple[str, str]] = set()
         self._searched: dict[tuple, dict[str, int]] = {}
         self._overrides: dict[tuple, dict[str, int]] = {}
-        # Compiled launch plans: (kernel, hw name) -> immutable probe table,
-        # plus the lazy per-shape fills for envelope misses.
-        self._plans: dict[tuple[str, str], LaunchPlanTable] = {}
-        self._plan_fills: dict[tuple, dict[str, int]] = {}
-        # Decision generation: bumped by every mutation that could change a
-        # launch decision (driver registration, refit invalidation, pinned
-        # override, plan registration).  Steady-state consumers -- the
-        # decision memo here, frozen StepPlans in core/step_plan.py --
-        # compare one int instead of re-verifying per-kernel state.
+        # Bumped by every mutation that could change a launch decision
+        # (driver registration, refit invalidation, pinned override): a
+        # gauge for the exporter, and how tools tell that serving state
+        # was left untouched.
         self._generation = 0
-        # The decision memo: (kernel, hw name, dkey(D)) -> mutable entry
-        # [config, source, hits, pending-notify].  Read without the lock on
-        # the hot path (a dict probe is atomic under the GIL); replaced
-        # wholesale on every generation bump so stale entries are
-        # unreachable, not just flagged.
-        self._memo: dict[tuple, list] = {}
         self._stats = _fresh_stats()
         self._lock = threading.Lock()
 
@@ -361,49 +208,12 @@ class _Registry:
     def generation(self) -> int:
         return self._generation
 
-    def _bump_generation_locked(self) -> None:
-        self._generation += 1
-        if self._memo:
-            # Count only bumps that actually discarded memoized decisions:
-            # that is the churn an operator cares about (each one means the
-            # steady-state fast path re-resolves every live shape).
-            self._stats["memo_invalidations"] += 1
-        self._memo = {}
-
-    def memo_size(self) -> int:
-        """Live decision-memo entry count (gauge; lock-free like the probe)."""
-        return len(self._memo)
-
-    def memo_get(self, key: tuple) -> list | None:
-        """Hot-path memo probe (lock-free; see ``_memo`` comment)."""
-        return self._memo.get(key)
-
-    def memo_store(self, generation: int, key: tuple,
-                   config: dict[str, int], source: str) -> None:
-        """Install a memo entry, unless the registry has moved on since the
-        decision was computed (a concurrent refit hot-swap between the
-        resolution and this store must not pin the old fit's choice)."""
-        with self._lock:
-            if generation == self._generation:
-                self._memo[key] = [dict(config), source, 0, 0]
-
-    def memo_hits(self) -> int:
-        """Total decision-memo hits (approximate under concurrency; summed
-        lazily from the per-entry counters so the hot path stays lock-free)."""
-        return sum(e[2] for e in list(self._memo.values()))
-
     def register(self, driver: DriverProgram) -> None:
         with self._lock:
             self._drivers[driver.kernel] = driver
             self._cache_misses = {k for k in self._cache_misses
                                   if k[0] != driver.kernel}
-            # A plan is frozen output of the driver it was compiled from;
-            # registering a *different* driver (refit, rebuild) retires the
-            # kernel's plans, while re-registering the same generated module
-            # (cache read-through) keeps them.
-            self._drop_plans_locked(driver.kernel,
-                                    keep_source_hash=driver.source_hash)
-            self._bump_generation_locked()
+            self._generation += 1
 
     def get(self, kernel: str) -> DriverProgram | None:
         return self._drivers.get(kernel)
@@ -446,9 +256,7 @@ class _Registry:
         with self._lock:
             self._overrides[self._override_key(kernel, hw_name, D)] = \
                 dict(config)
-            # An override outranks every memoized decision -- including
-            # frozen StepPlans, which check the generation before serving.
-            self._bump_generation_locked()
+            self._generation += 1
 
     def override(self, kernel: str, hw_name: str,
                  D: Dims) -> dict[str, int] | None:
@@ -459,81 +267,8 @@ class _Registry:
             self._stats["disk_cache_hits" if hit
                         else "disk_cache_misses"] += 1
 
-    # -- compiled launch plans (core/plan.py) ---------------------------------
-    def register_plan(self, plan: LaunchPlanTable) -> None:
-        """Install a compiled plan for (plan.kernel, plan.hw_name).
-
-        The plan becomes the kernel's steady-state dispatch path: an O(1)
-        array probe consulted before the driver's rational-program
-        evaluation.  Registering a new driver or ``invalidate_kernel``
-        drops it.
-        """
-        with self._lock:
-            self._plans[(plan.kernel, plan.hw_name)] = plan
-            self._bump_generation_locked()
-
-    def plan(self, kernel: str, hw_name: str) -> LaunchPlanTable | None:
-        return self._plans.get((kernel, hw_name))
-
-    def plan_lookup(self, kernel: str, hw_name: str,
-                    D: Dims) -> dict[str, int] | None:
-        """O(1) hot-path dispatch: probe the compiled plan (then the lazy
-        per-shape fills) for a precomputed config.  Hits and misses are
-        counted only when a plan is registered for the kernel -- an untuned
-        kernel costs one dict miss, not a bogus metric."""
-        table = self._plans.get((kernel, hw_name))
-        if table is None:
-            return None
-        cfg = table.lookup(D)
-        if cfg is None:
-            cfg = self._plan_fills.get(
-                (kernel, hw_name, tuple(sorted(D.items()))))
-            if cfg is not None:
-                cfg = dict(cfg)
-        with self._lock:
-            self._stats["plan_hits" if cfg is not None
-                        else "plan_misses"] += 1
-        return cfg
-
-    def note_plan_fill(self, kernel: str, hw_name: str, D: Dims,
-                       config: dict[str, int],
-                       source_hash: str | None = None) -> None:
-        """Lazy single-shape fill: a driver decision for a shape outside
-        the precompiled envelope joins the plan so repeats dispatch O(1).
-        No-op unless a plan table is registered for the kernel, and --
-        checked under the lock -- unless the registered plan was compiled
-        from the same driver that produced ``config`` (``source_hash``):
-        a config computed just before a concurrent refit hot-swap must not
-        be pinned into the new generation's plan."""
-        with self._lock:
-            table = self._plans.get((kernel, hw_name))
-            if table is None:
-                return
-            if source_hash is not None and table.source_hash and \
-                    table.source_hash != source_hash:
-                return
-            self._plan_fills[(kernel, hw_name,
-                              tuple(sorted(D.items())))] = dict(config)
-
-    def note_choose_many(self, n_shapes: int) -> None:
-        with self._lock:
-            self._stats["choose_many_calls"] += 1
-            self._stats["choose_many_rows"] += int(n_shapes)
-
-    def _drop_plans_locked(self, kernel: str,
-                           keep_source_hash: str | None = None) -> None:
-        doomed = [k for k, p in self._plans.items()
-                  if k[0] == kernel and (keep_source_hash is None
-                                         or p.source_hash != keep_source_hash)]
-        for k in doomed:
-            del self._plans[k]
-        self._stats["plan_invalidations"] += len(doomed)
-        if doomed or keep_source_hash is None:
-            self._plan_fills = {k: v for k, v in self._plan_fills.items()
-                                if k[0] != kernel}
-
     def stats(self) -> dict[str, int]:
-        """Snapshot of the registry's read-through / dispatch counters."""
+        """Snapshot of the registry's disk read-through counters."""
         with self._lock:
             return dict(self._stats)
 
@@ -541,9 +276,9 @@ class _Registry:
         """Forget everything memoized for one kernel (the hot-swap path).
 
         A refit is about to register a corrected driver: the old driver, the
-        negative disk-read memo, every searched-shape memo, every pinned
-        override and every compiled launch plan (plus its lazy fills) for
-        the kernel describe the *previous* fit and must not outlive it.
+        negative disk-read memo, every searched-shape memo and every pinned
+        override for the kernel describe the *previous* fit and must not
+        outlive it.
         """
         with self._lock:
             self._drivers.pop(kernel, None)
@@ -553,8 +288,7 @@ class _Registry:
                               if k[0] != kernel}
             self._overrides = {k: v for k, v in self._overrides.items()
                                if k[0] != kernel}
-            self._drop_plans_locked(kernel)
-            self._bump_generation_locked()
+            self._generation += 1
 
     def clear(self) -> None:
         with self._lock:
@@ -562,11 +296,7 @@ class _Registry:
             self._cache_misses.clear()
             self._searched.clear()
             self._overrides.clear()
-            self._plans.clear()
-            self._plan_fills.clear()
-            self._bump_generation_locked()
-            # After the bump: a full clear() resets the churn counters too,
-            # rather than recording itself as an invalidation.
+            self._generation += 1
             self._stats = _fresh_stats()
 
     def kernels(self) -> list[str]:
@@ -598,8 +328,7 @@ def _driver_from_entry(kernel: str, entry, hw: HardwareParams
     """
     global _bad_entry_warned
     try:
-        return DriverProgram.from_source(kernel, entry.source, hw,
-                                         tuning_version=entry.tuning_version)
+        return DriverProgram.from_source(kernel, entry.source, hw)
     except Exception as e:
         if not _bad_entry_warned:
             _bad_entry_warned = True
@@ -637,50 +366,20 @@ def get_driver(kernel: str, read_cache: bool = True,
         return None
     registry.register(drv)
     registry.note_disk_cache(hit=True)
-    _install_plan_if_matching(kernel, drv, hw, default_cache())
     return drv
-
-
-def _install_plan_if_matching(kernel: str, drv: DriverProgram | None,
-                              hw: HardwareParams, cache) -> bool:
-    """Install the newest persisted launch plan for ``kernel``, when safe.
-
-    Shared by ``get_driver``'s lazy disk read-through (a fresh process gets
-    O(1) dispatch without an explicit warm start) and
-    ``warm_start_from_cache``.  A plan is installed only if it was compiled
-    from the exact driver that will serve (same source hash) -- or, with no
-    driver at all, unconditionally: the plan is then the best tuning we
-    have.  Unparseable artifacts and mismatches are left on disk untouched.
-    Returns whether a plan was registered.
-    """
-    from .plan import LaunchPlanTable
-
-    entry = cache.lookup_latest_plan(kernel, hw_name=hw.name)
-    if entry is None:
-        return False
-    try:
-        table = LaunchPlanTable.from_json(entry.plan)
-    except (KeyError, ValueError, TypeError):
-        return False
-    if drv is not None and table.source_hash != drv.source_hash:
-        return False
-    registry.register_plan(table)
-    return True
 
 
 class WarmStartSummary(list):
     """Loaded kernel names (a plain list, for compatibility) plus warm-start
     coverage counts: how many kernels were skipped because no artifact
     matched (``skipped_no_entry``), failed to load (``skipped_bad``), or
-    were already registered (``already_registered``), and which compiled
-    launch plans were installed (``plans_loaded``)."""
+    were already registered (``already_registered``)."""
 
     def __init__(self, loaded: list[str] | None = None) -> None:
         super().__init__(loaded or [])
         self.already_registered = 0
         self.skipped_no_entry = 0
         self.skipped_bad = 0
-        self.plans_loaded: list[str] = []
 
     @property
     def loaded(self) -> list[str]:
@@ -689,7 +388,6 @@ class WarmStartSummary(list):
     def as_dict(self) -> dict:
         return {
             "loaded": list(self),
-            "plans_loaded": list(self.plans_loaded),
             "already_registered": self.already_registered,
             "skipped_no_entry": self.skipped_no_entry,
             "skipped_bad": self.skipped_bad,
@@ -697,27 +395,22 @@ class WarmStartSummary(list):
 
     def __repr__(self) -> str:
         return (f"WarmStartSummary(loaded={list(self)!r}, "
-                f"plans_loaded={self.plans_loaded!r}, "
                 f"already_registered={self.already_registered}, "
                 f"skipped_no_entry={self.skipped_no_entry}, "
                 f"skipped_bad={self.skipped_bad})")
 
 
 def warm_start_from_cache(kernels: list[str] | None = None,
-                          hw: HardwareParams = V5E,
-                          plans: bool = True) -> WarmStartSummary:
+                          hw: HardwareParams = V5E) -> WarmStartSummary:
     """Pre-load cached drivers into the registry (serving-process startup).
 
     ``kernels=None`` loads every kernel present in the cache.  Kernels
     already registered are left untouched; entries tuned for a different
     device than ``hw``, and entries whose stored source fails to load
-    (one-time warning), are skipped.  With ``plans=True`` the newest
-    compiled launch plan for each kernel is installed too, when it matches
-    the driver that will serve (same source hash) -- a plan artifact can
-    even serve alone when its driver entry is gone.  Returns a
-    ``WarmStartSummary``: the loaded names (list-compatible) plus
-    loaded/skipped coverage counts, so serving processes and benchmarks can
-    report how much of the fleet's tuning work they inherited.
+    (one-time warning), are skipped.  Returns a ``WarmStartSummary``: the
+    loaded names (list-compatible) plus loaded/skipped coverage counts, so
+    serving processes and benchmarks can report how much of the fleet's
+    tuning work they inherited.
     """
     from .cache import default_cache
 
@@ -726,25 +419,20 @@ def warm_start_from_cache(kernels: list[str] | None = None,
     summary = WarmStartSummary()
     with trace_span("warm_start", hw=hw.name) as sp:
         for name in names:
-            drv = registry.get(name)
-            if drv is not None:
+            if registry.get(name) is not None:
                 summary.already_registered += 1
-            else:
-                entry = cache.lookup_latest(name, hw_name=hw.name)
-                if entry is None:
-                    summary.skipped_no_entry += 1
-                else:
-                    drv = _driver_from_entry(name, entry, hw)
-                    if drv is None:
-                        summary.skipped_bad += 1
-                    else:
-                        registry.register(drv)
-                        summary.append(name)
-            if not plans or registry.plan(name, hw.name) is not None:
                 continue
-            if _install_plan_if_matching(name, drv, hw, cache):
-                summary.plans_loaded.append(name)
-        sp.set(loaded=len(summary), plans_loaded=len(summary.plans_loaded),
+            entry = cache.lookup_latest(name, hw_name=hw.name)
+            if entry is None:
+                summary.skipped_no_entry += 1
+                continue
+            drv = _driver_from_entry(name, entry, hw)
+            if drv is None:
+                summary.skipped_bad += 1
+            else:
+                registry.register(drv)
+                summary.append(name)
+        sp.set(loaded=len(summary),
                skipped_no_entry=summary.skipped_no_entry,
                skipped_bad=summary.skipped_bad)
     return summary
@@ -758,22 +446,13 @@ def choose_or_default(kernel: str, D: Dims,
                       device=None,
                       strategy=None,
                       budget=None) -> dict[str, int]:
-    """Tuned launch parameters if a plan, driver, or cache entry covers the
-    shape, else ``default`` -- or, opt-in, a budgeted online search.
+    """Tuned launch parameters if a driver or cache entry covers the shape,
+    else ``default`` -- or, opt-in, a budgeted online search.
 
-    Dispatch order: the generation-scoped decision memo serves repeats of
-    an already-resolved (kernel, hw, shape) in one dict probe; on a memo
-    miss, a telemetry-pinned per-shape override (measured evidence)
-    outranks everything; then the compiled launch plan (O(1) probe of
-    precomputed choices -- see core/plan.py); then the driver's vectorized
-    rational-program evaluation (whose per-shape results lazily join the
-    plan); then the search escalation or the static default.  Memo entries
-    record the source that resolved them, and any registry mutation --
-    register, invalidate, override, new plan -- drops the memo, so the
-    fast path can never serve a decision the slow path would no longer
-    make.  A memo hit returns the entry's *shared* config dict (copying
-    would double the hit cost): callers read launch parameters out of it
-    and must never mutate it.
+    Dispatch order: a telemetry-pinned per-shape override (measured
+    evidence) outranks everything; then the driver's vectorized
+    rational-program evaluation (memoized in the generated module's own
+    decision history); then the search escalation or the static default.
 
     This keeps model code runnable before any tuning has happened (the
     untuned path uses the static heuristic config, like un-instrumented CUDA
@@ -801,37 +480,9 @@ def choose_or_default(kernel: str, D: Dims,
     Telemetry-pinned per-shape overrides (measured evidence from a refit
     pass) outrank the driver's model-based choice.
     """
-    # Decision memo: the steady-state fast path.  One tuple build + one dict
-    # probe; valid entries are by construction from the current registry
-    # generation (the memo is dropped wholesale on any mutation), so the
-    # full dispatch chain below only runs once per (kernel, hw, shape) per
-    # generation.  The key uses D's *insertion* order, not sorted order:
-    # sorting costs ~2x the whole probe, and a call site always builds D
-    # the same way, so repeats hit -- two call sites that order the same
-    # shape differently just memoize it twice (both entries die together
-    # on invalidation).  The probe reads the registry's memo dict directly:
-    # the dict is replaced, never mutated, on a generation bump, so a bare
-    # .get is safe without the lock or a method-call frame.
-    if _memo_enabled:
-        mkey = (kernel, hw.name, tuple(D.items()))
-        ent = registry._memo.get(mkey)
-        if ent is not None:
-            if _choice_listener is not None:
-                _memo_notify(kernel, D, ent, hw)
-            else:
-                ent[2] += 1
-            # Shared, not copied (a copy costs ~20% of the whole hit):
-            # callers read launch parameters out of the config, never
-            # mutate it -- the same contract as StepPlan.resolve.
-            return ent[0]
-        # Snapshot before resolving: memo_store refuses the entry if a
-        # concurrent mutation moved the generation mid-resolution.
-        gen = registry.generation
     drv = get_driver(kernel, hw=hw)
     override = registry.override(kernel, hw.name, D)
     if override is not None:
-        if _memo_enabled:
-            registry.memo_store(gen, mkey, override, "override")
         if _choice_listener is not None:
             pred = None
             if drv is not None:
@@ -841,35 +492,12 @@ def choose_or_default(kernel: str, D: Dims,
                     pred = None
             _notify(kernel, D, override, "override", pred, hw)
         return dict(override)
-    # Compiled launch plan: the O(1) cold-path dispatch -- a probe of the
-    # precompiled (shape -> config) table, no rational-program evaluation.
-    # Plans can serve even with no compiled driver at all (plan artifacts
-    # warm-start independently).
-    plan_cfg = registry.plan_lookup(kernel, hw.name, D)
-    if plan_cfg is not None:
-        if _memo_enabled:
-            registry.memo_store(gen, mkey, plan_cfg, "plan")
-        if _choice_listener is not None:
-            pred = None
-            if drv is not None:
-                try:
-                    pred = drv.estimate(D, plan_cfg)
-                except Exception:
-                    pred = None
-            _notify(kernel, D, plan_cfg, "plan", pred, hw)
-        return plan_cfg
     if drv is not None:
         try:
             cfg = drv.choose(D)
         except (ValueError, KeyError, TypeError):
             cfg = None  # stale/mismatched driver: search if opted in, else
         if cfg is not None:
-            # Lazy single-shape plan fill: a shape outside the precompiled
-            # envelope pays the rational program once, then dispatches O(1).
-            registry.note_plan_fill(kernel, hw.name, D, cfg,
-                                    source_hash=drv.source_hash)
-            if _memo_enabled:
-                registry.memo_store(gen, mkey, cfg, "driver")
             if _choice_listener is not None:
                 # The prediction is telemetry garnish: a driver whose
                 # estimate() breaks must still serve its valid choice.
@@ -880,9 +508,6 @@ def choose_or_default(kernel: str, D: Dims,
                 _notify(kernel, D, cfg, "driver", pred, hw)
             return cfg
     if spec is None and device is None:
-        # Deliberately not memoized: the default is a per-call-site
-        # argument, so two callers with different heuristics must not see
-        # each other's fallback.
         if _choice_listener is not None:
             _notify(kernel, D, default, "default", None, hw)
         return dict(default)

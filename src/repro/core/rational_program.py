@@ -104,10 +104,9 @@ class Expr:
     def specialize(self, bindings: Mapping[str, float]) -> "Expr":
         """Partial evaluation: bind some free variables, fold constants.
 
-        This is the launch-plan compilation primitive: specializing the
-        rational program with respect to the data parameters D collapses
-        every subexpression that depends only on D into a ``Const`` and
-        folds decision nodes whose conditions became constant -- the
+        Specializing the rational program with respect to the data
+        parameters D collapses every subexpression that depends only on D
+        into a ``Const`` and folds decision nodes whose conditions became constant -- the
         remaining program is a (usually much smaller) rational function of
         the program parameters alone, and its piece count shrinks
         accordingly.  Unbound variables are left symbolic.
@@ -300,7 +299,7 @@ class Fitted(Expr):
         return ()
 
 
-# -- partial evaluation (launch-plan compilation) ----------------------------
+# -- partial evaluation -------------------------------------------------------
 
 def specialize_expr(e: Expr, bindings: Mapping[str, float]) -> Expr:
     """Substitute ``bindings`` into ``e`` and constant-fold in one pass.
@@ -399,8 +398,7 @@ class RationalProgram:
     def specialize(self, bindings: Mapping[str, float]) -> "RationalProgram":
         """Partially evaluate every output with respect to ``bindings``.
 
-        Specializing on the data parameters D is the compile step of a
-        launch plan: the returned program depends only on the still-free
+        Specializing on the data parameters D: the returned program depends only on the still-free
         inputs (typically the program parameters P), D-only subexpressions
         are folded to constants, and decision nodes whose conditions were
         decided by D are gone -- evaluating it over a candidate table does

@@ -180,8 +180,7 @@ class Klaraptor:
                 entry = self.cache.get(spec.name, key)
                 if entry is not None:
                     driver = DriverProgram.from_source(
-                        spec.name, entry.source, self.hw,
-                        tuning_version=entry.tuning_version)
+                        spec.name, entry.source, self.hw)
                     if register:
                         register_driver(driver)
                     bsp.set(from_cache=True)
@@ -222,8 +221,7 @@ class Klaraptor:
                     spec, program,
                     {m: f.function for m, f in fits.items()}, self.hw)
                 driver = DriverProgram.from_source(
-                    spec.name, source, self.hw,
-                    tuning_version=cache_version)
+                    spec.name, source, self.hw)
             if register:
                 register_driver(driver)
             if self.cache is not None and key is not None:

@@ -18,7 +18,7 @@ the full :class:`~repro.core.kernel_spec.KernelSpec` statically:
 Entry points: ``spec_from_kernel(fn, grid_spec, *, hw=V5E)`` for the spec
 alone; ``auto_register(fn, grid_spec)`` to wire a kernel into the driver
 registry, the artifact cache (keyed by the traced kernel's content hash),
-launch-plan serving and telemetry with zero hand-written spec code.  The
+tuned dispatch and telemetry with zero hand-written spec code.  The
 GridSpecs mirroring the four hand-written tier-1 specs live in
 ``repro.introspect.tier1`` (imported on demand; they exist to prove
 behavioral equivalence, production tier-1 dispatch keeps the hand specs).

@@ -6,7 +6,7 @@
 process-wide table.  The AutoKernel is the glue every other layer uses:
 
   * ``choose(D)``        -- launch parameters through the standard
-    ``choose_or_default`` dispatch (override > plan > driver > default),
+    ``choose_or_default`` dispatch (override > driver > default),
     i.e. the same path ``kernels/ops.py`` uses for hand-specced kernels;
   * ``ensure_driver``    -- registry / artifact-cache read-through, then a
     full collect -> fit -> codegen build against a device oracle if nothing
@@ -16,17 +16,14 @@ process-wide table.  The AutoKernel is the glue every other layer uses:
   * ``fit_config``       -- snap a chosen config onto an actual shape with
     the *derived* granularities (parsed back out of the synthesized
     ``"p % g == 0"`` constraints), replacing the hand-maintained alignment
-    constants of the hand-specced ops;
-  * ``plan_envelope``    -- a traffic lattice for ``precompile_plans`` /
-    ``ServingEngine(plan_envelope=...)`` so auto kernels get O(1) plan-table
-    dispatch like everything else.
+    constants of the hand-specced ops.
 """
 
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-from typing import Mapping, Sequence
+from typing import Mapping
 
 from repro.core.device_model import DeviceModel, HardwareParams, V5E
 from repro.core.driver import (DriverProgram, choose_or_default, fit_tile,
@@ -118,21 +115,6 @@ class AutoKernel:
 
         kl = Klaraptor(device or V5eSimulator(self.hw), hw=self.hw)
         return kl.build_driver(self.spec, **build_kwargs).driver
-
-    def plan_envelope(self, sizes: Sequence[int] = (256, 512, 1024, 2048,
-                                                    4096)) -> dict:
-        """Per-data-param value lists for launch-plan precompilation.
-
-        Data parameters with probe hints (count-like params) reuse the hint
-        values; the rest get the ``sizes`` lattice.  Infeasible lattice
-        points are dropped by ``choose_many`` at compile time, so
-        over-approximation only costs table entries.
-        """
-        env = {}
-        for d in self.spec.data_params:
-            hint = self.spec.probe_hints.get(d)
-            env[d] = list(hint) if hint is not None else list(sizes)
-        return env
 
 
 _AUTO: dict[str, AutoKernel] = {}

@@ -6,14 +6,16 @@ the KV cache, and KLARAPTOR decode-launch decisions):
     python -m repro.launch.serve --arch llama3.2-1b --smoke \
         --requests 8 --max-new 12
 
+At start the engine warm-starts every tuned driver in the artifact cache;
+each kernel launch then takes its config from a pinned override, else its
+driver, else the op's default (kernels/ops.py).
+
 ``--telemetry`` opts into the runtime observability + drift-adaptive
 retuning loop (repro.telemetry) over the tier-1 kernel specs and prints a
 Prometheus-style metrics dump after the run; ``--telemetry-json PATH``
-writes the full JSON snapshot instead.  ``--plans`` precompiles launch
-plans over a default batch x seq traffic envelope for every warm-started
-tier-1 kernel (one batched ``choose_many`` pass each, persisted through
-the artifact cache), making steady-state dispatch an O(1) plan-table
-probe.
+writes the full JSON snapshot instead.  ``--auto-kernels`` introspects and
+tunes the auto-specced kernels (layernorm fusion, blocked column
+reduction) and adds their specs to the telemetry.
 
 ``--trace out.json`` installs a repro.trace Tracer for the whole run and
 writes a Chrome trace-event file at exit (open in ui.perfetto.dev);
@@ -26,9 +28,7 @@ the engine's request stamps (submit to first token on the host).
 
 ``--async`` serves through the engine's async front-end (scheduler
 thread, thread-safe submit, chunked jitted prefill -- see
-serving/engine.py) and prints the compile counts afterwards; ``--buckets``
-adds per-step bucketed-dispatch accounting (hit/miss + padding waste) for
-the decode attention kernel.
+serving/engine.py) and prints the compile counts afterwards.
 """
 
 from __future__ import annotations
@@ -45,7 +45,6 @@ from repro.models import Model, init_params
 from repro.serving import Request, ServingEngine
 
 __all__ = ["main", "build_engine", "build_telemetry",
-           "default_plan_envelope", "default_bucket_lattices",
            "build_auto_kernels", "time_to_first_token_ms"]
 
 
@@ -58,27 +57,6 @@ def time_to_first_token_ms(requests) -> dict | None:
         return None
     return {"median": float(np.median(ttft)),
             "p90": float(np.percentile(ttft, 90)), "requests": len(ttft)}
-
-
-def default_plan_envelope(batch: int, max_seq: int) -> dict:
-    """Decode-traffic lattice for the tier-1 kernels: the shapes a serving
-    process is expected to dispatch, expressed as per-data-param value
-    lists (the envelope ``precompile_plans`` compiles in one
-    ``choose_many`` pass per kernel).  Infeasible lattice points are
-    dropped at compile time, so over-approximating costs only table
-    entries."""
-    seqs = [s for s in (128, 256, 512, 1024, 2048, 4096)
-            if s <= max_seq] or [max_seq]
-    dims = [1024, 2048, 4096]
-    heads = sorted({max(1, batch) * h for h in (8, 16, 32)})
-    return {
-        "matmul_b16": {"m": sorted({max(8, batch), 128, 1024}),
-                       "n": dims, "k": dims},
-        "flash_attn_d128_causal": {"bh": heads, "sq": seqs, "skv": seqs},
-        "moe_gmm_b16": {"e": [8], "g": [256, 512, 1024],
-                        "k": [1024, 2048], "n": [1024, 2048]},
-        "ssd_scan_h64_n128": {"bh": heads, "s": seqs, "chunkflops": [1]},
-    }
 
 
 def build_auto_kernels(d_model: int = 1024, tune_device=None):
@@ -120,42 +98,15 @@ def build_telemetry(seed: int = 0, auto_kernels=(), ledger=None):
 
 
 def build_engine(cfg, batch: int, max_seq: int, mesh=None, params=None,
-                 seed: int = 0, telemetry=None,
-                 plan_envelope=None, auto_kernels=None,
-                 step_plans: bool = True, trace=None,
-                 prefill_chunk: int = 32,
-                 bucket_lattices=None) -> ServingEngine:
+                 seed: int = 0, telemetry=None, trace=None,
+                 prefill_chunk: int = 32) -> ServingEngine:
     model = Model(cfg)
     sharder = Sharder(mesh=mesh, rules=decode_rules())
     if params is None:
         params = init_params(model.specs(), jax.random.PRNGKey(seed))
     return ServingEngine(model, params, sharder, batch=batch,
                          max_seq=max_seq, telemetry=telemetry,
-                         plan_envelope=plan_envelope,
-                         auto_kernels=auto_kernels,
-                         step_plans=step_plans, trace=trace,
-                         prefill_chunk=prefill_chunk,
-                         bucket_lattices=bucket_lattices)
-
-
-def default_bucket_lattices(cfg, batch: int, max_seq: int) -> dict:
-    """Bucket lattices for the decode step's attention kernel: log2 seq
-    buckets up to ``max_seq``, fixed batch-heads axis.  The engine replays
-    these per step for hit/miss + padding-waste accounting (and they are
-    the lattices an in-graph bucketed step would pad to)."""
-    from repro.core import BucketLattice
-
-    key = f"flash_attn_d{cfg.head_dim}" + ("_causal" if cfg.causal else "")
-    return {key: BucketLattice.from_axes(key, {
-        "bh": [batch * cfg.n_heads],
-        "sq": pow2_seqs(max_seq),
-        "skv": pow2_seqs(max_seq),
-    })}
-
-
-def pow2_seqs(max_seq: int) -> list[int]:
-    from repro.core import pow2_span
-    return list(pow2_span(1, max_seq))
+                         trace=trace, prefill_chunk=prefill_chunk)
 
 
 def main() -> None:
@@ -172,18 +123,11 @@ def main() -> None:
     ap.add_argument("--telemetry-json", metavar="PATH", default=None,
                     help="with --telemetry: write the JSON snapshot here "
                          "instead of printing Prometheus text")
-    ap.add_argument("--plans", action="store_true",
-                    help="precompile launch plans for the default decode "
-                         "traffic envelope at warm start (O(1) dispatch)")
-    ap.add_argument("--no-step-plans", action="store_true",
-                    help="disable the per-step launch plan (every traced "
-                         "kernel dispatch goes through the registry instead "
-                         "of the engine's frozen per-step config table)")
     ap.add_argument("--auto-kernels", action="store_true",
                     help="introspect + tune the auto-specced kernels "
                          "(layernorm fusion, blocked column reduction) and "
-                         "serve them through the engine: zero hand-written "
-                         "spec code")
+                         "add their specs to the telemetry: zero "
+                         "hand-written spec code")
     ap.add_argument("--async", dest="run_async", action="store_true",
                     help="serve through the async front-end (scheduler "
                          "thread + chunked prefill) instead of the "
@@ -191,11 +135,6 @@ def main() -> None:
     ap.add_argument("--prefill-chunk", type=int, default=32,
                     help="prompt tokens advanced per jitted prefill scan "
                          "on the async path (default 32)")
-    ap.add_argument("--buckets", action="store_true",
-                    help="enable per-step bucketed-dispatch accounting for "
-                         "the decode attention kernel (hit/miss + padding "
-                         "waste, printed after the run and exported by "
-                         "--telemetry)")
     ap.add_argument("--trace", metavar="PATH", default=None,
                     help="record structured spans for the whole run and "
                          "write a Chrome trace-event JSON here (open in "
@@ -236,30 +175,12 @@ def main() -> None:
     telemetry = (build_telemetry(auto_kernels=auto, ledger=ledger)
                  if args.telemetry or ledger is not None
                  or args.dash is not None else None)
-    envelope = (default_plan_envelope(args.batch, args.max_seq)
-                if args.plans else None)
-    buckets = (default_bucket_lattices(cfg, args.batch, args.max_seq)
-               if args.buckets else None)
     engine = build_engine(cfg, args.batch, args.max_seq, telemetry=telemetry,
-                          plan_envelope=envelope, auto_kernels=auto,
-                          step_plans=not args.no_step_plans, trace=tracer,
-                          prefill_chunk=args.prefill_chunk,
-                          bucket_lattices=buckets)
+                          trace=tracer, prefill_chunk=args.prefill_chunk)
     ws = engine.warm_started
     print(f"warm start: {len(ws)} driver(s) loaded {list(ws)}, "
-          f"{len(ws.plans_loaded)} plan(s), "
           f"{ws.skipped_no_entry} without artifacts, "
           f"{ws.skipped_bad} unloadable")
-    if args.plans:
-        ps = engine.plan_summary
-        print(f"launch plans: {len(ps['compiled'])} compiled, "
-              f"{len(ps['loaded'])} loaded from cache, "
-              f"{len(ps['skipped'])} skipped (no driver), "
-              f"{ps['entries']} plan entries")
-    if engine._step_plan is not None:
-        sp = engine._step_plan.describe()
-        print(f"step plan: {sp['entries']} kernel configs frozen at "
-              f"generation {sp['generation']} ({sp['sources']})")
     observatory = dash = None
     if args.dash is not None:
         # After engine construction so the observatory finds the installed
@@ -289,12 +210,6 @@ def main() -> None:
         print(f"async front-end: {cc['decode_step']} decode-step compile(s), "
               f"{cc['prefill_chunk']} prefill-chunk compile(s), "
               f"chunk={engine.prefill_chunk}")
-    if args.buckets:
-        bs = engine.bucket_stats
-        n = bs["hits"] + bs["misses"]
-        frac = bs["waste_sum"] / n if n else 0.0
-        print(f"bucket dispatch: {bs['hits']} hits, {bs['misses']} misses "
-              f"over {bs['steps']} steps, mean padding waste {frac:.3f}")
     if observatory is not None:
         alerts = observatory.evaluate()
         firing = sorted({r for r, _ in observatory.slo.firing})

@@ -5,8 +5,7 @@
     python -m repro.launch.status --snapshot telemetry.json
 
 Renders what the tuning runtime decided and observed: per-kernel
-decision-source breakdown (override / plan / memo-coalesced / driver /
-default), prediction rel-error EWMAs, drift + refit history, and the top
+decision-source breakdown (override / driver / search / default), prediction rel-error EWMAs, drift + refit history, and the top
 pipeline spans by cumulative time.  ``--ledger`` reads the JSONL flight
 ledger written by ``Telemetry(ledger=...)`` / ``serve --ledger``;
 ``--snapshot`` reads a ``MetricsExporter.json()`` dump.
@@ -134,8 +133,7 @@ def render_snapshot(snap: dict, top: int = 10) -> str:
     c = snap.get("counters", {})
     g = snap.get("gauges", {})
     lines = [f"telemetry snapshot: {c.get('choices_total', 0)} decisions, "
-             f"generation {g.get('registry_generation', '?')}, "
-             f"{g.get('decision_memo_entries', '?')} memo entries"]
+             f"generation {g.get('registry_generation', '?')}"]
 
     lines += _section("decisions by source")
     by_source = c.get("choices_by_source", {})
@@ -145,10 +143,6 @@ def render_snapshot(snap: dict, top: int = 10) -> str:
                             by_source.items())])
     else:
         lines.append("  (no decisions recorded)")
-    lines.append(f"  plan_hits={c.get('plan_hits', 0)} "
-                 f"plan_misses={c.get('plan_misses', 0)} "
-                 f"plan_invalidations={c.get('plan_invalidations', 0)} "
-                 f"memo_invalidations={c.get('memo_invalidations', 0)}")
 
     lines += _section("prediction error (rel-error EWMA)")
     keys = snap.get("keys", [])

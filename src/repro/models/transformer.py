@@ -26,7 +26,7 @@ from repro.models.module import ParamSpec, spec_tree_map
 
 __all__ = [
     "stack_specs", "model_specs", "embed_tokens", "forward", "decode_step",
-    "init_cache_specs", "unembed", "decode_kernel_requests",
+    "init_cache_specs", "unembed",
 ]
 
 f32 = jnp.float32
@@ -216,58 +216,6 @@ def forward(cfg: ModelConfig, params: dict, x: jax.Array, sharder,
             aux = aux + a
     x = L.rmsnorm(x, params["final_norm"], cfg.rms_eps)
     return x, aux
-
-
-# ---------------------------------------------------------------------------
-# kernel-launch manifest (for per-step launch plans)
-# ---------------------------------------------------------------------------
-
-def decode_kernel_requests(cfg: ModelConfig, batch: int, max_seq: int,
-                           seqs: tuple[int, ...] | None = None) -> list:
-    """The kernel launches this model's forward pass makes at serving
-    shapes, as ``core.step_plan.KernelRequest``s.
-
-    Derived purely from the config -- the same key/shape arithmetic the
-    layers use when they call into ``kernels.ops`` (attention flattens
-    heads into the batch axis, the SSD scan flattens mamba heads), so a
-    ``build_step_plan`` over these requests pre-resolves exactly the
-    configs the traced step would otherwise pull from the registry one by
-    one.  ``seqs`` defaults to ``(1, max_seq)``: the single-token forward
-    and the full-envelope prefill; the engine's jit cache means each shape
-    dispatches at most once per trace anyway, so over-declaring is cheap
-    (one extra row in the per-kernel ``choose_many`` sweep).
-    """
-    from repro.kernels.ops import FLASH_DEFAULT, SSD_DEFAULT
-    from repro.core.step_plan import KernelRequest
-
-    if seqs is None:
-        seqs = (1, max_seq)
-    reqs: list = []
-    descs = set()
-    for desc in cfg.block_pattern:
-        key = (desc.kind, bool(desc.cross_attn))
-        if key in descs:
-            continue
-        descs.add(key)
-        for s in seqs:
-            if desc.kind == "attn":
-                reqs.append(KernelRequest.make(
-                    f"flash_attn_d{cfg.head_dim}"
-                    + ("_causal" if cfg.causal else ""),
-                    {"bh": batch * cfg.n_heads, "sq": s, "skv": s},
-                    FLASH_DEFAULT))
-            else:
-                reqs.append(KernelRequest.make(
-                    f"ssd_scan_h{cfg.mamba_head_dim}_n{cfg.ssm_state}",
-                    {"bh": batch * cfg.mamba_heads, "s": s, "chunkflops": 1},
-                    SSD_DEFAULT))
-            if desc.cross_attn:
-                skv = cfg.encoder_seq if cfg.encoder_seq else s
-                reqs.append(KernelRequest.make(
-                    f"flash_attn_d{cfg.head_dim}",
-                    {"bh": batch * cfg.n_heads, "sq": s, "skv": skv},
-                    FLASH_DEFAULT))
-    return reqs
 
 
 # ---------------------------------------------------------------------------

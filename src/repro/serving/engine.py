@@ -45,54 +45,28 @@ a flag check.
                                 ``active`` of them holding a request
   ``engine.sample``             inside decode: key split, the greedy and
                                 sampled tokens, their copy to the host
-  ``engine.emit``               inside decode: bucket replay and per-slot
-                                bookkeeping
+  ``engine.emit``               inside decode: per-slot bookkeeping
   ============================  ===========================================
 
 Each ``Request`` carries its lifecycle on the host's ``perf_counter``
 clock: ``t_submit`` (queued), ``t_admit`` (taken into a slot),
 ``t_first`` (first token on the host) and ``t_done`` (finished).
 
-Passing ``bucket_lattices=`` (kernel name -> ``core.buckets.BucketLattice``
-or a prebuilt ``core.device_plan.BucketedDispatch``) opts the engine into
-per-step bucket accounting: each decode step replays the in-graph bucket
-decision on the host (bit-identical rounding) and feeds hit/miss +
-padding-waste stats to telemetry (``bucket_stats``,
-``Telemetry.note_bucket_step``).
-
-Inside each decode step the KLARAPTOR drivers pick kernel launch parameters
-for the current shapes (once, then memoized) -- the serving-side face of the
-paper's "optimal values ... for each kernel launch independently".  At
+When the traced step dispatches Pallas kernels, the KLARAPTOR drivers pick
+their launch parameters for the current shapes at trace time (once per
+shape, kept in each driver's decision history) -- the serving-side face of
+the paper's "optimal values ... for each kernel launch independently".  At
 startup the engine warm-starts every tuned driver found in the persistent
 artifact cache (core/cache.py), so a fleet of serving processes shares one
-tuning run instead of each re-deriving launch parameters.  Passing
-``plan_envelope`` (kernel -> per-data-param value lists) additionally
-precompiles *launch plans* for the expected traffic lattice: one batched
-``choose_many`` pass per kernel turns the whole envelope into an O(1)
-dispatch table (core/plan.py), persisted through the artifact cache so the
-rest of the fleet loads it instead of recompiling; shapes outside the
-envelope lazily join the plan after one driver decision.  For shapes with
+tuning run instead of each re-deriving launch parameters.  For shapes with
 *no* cached driver, ``tune_for_shape`` runs a budget-aware online search
 (repro.search) instead of falling back to static defaults forever.
-
-On top of both, ``step_plans=True`` (default) builds a *per-step launch
-plan* (core/step_plan.py) for models that dispatch Pallas kernels: every
-kernel config the decode step needs, resolved in one pass at engine start
-and re-frozen whenever the driver registry's generation moves, so the
-traced step reads a frozen dict instead of making N registry round-trips.
 
 Passing ``telemetry=`` (a ``repro.telemetry.Telemetry``) opts the engine
 into runtime observability: every launch decision is counted, a sampled
 subset is shadow-probed against the device oracle, and drivers whose
 predictions drift from observed reality are refit and hot-swapped under a
 hard probe budget.
-
-Passing ``auto_kernels=`` (``repro.introspect.AutoKernel`` instances)
-declares introspected kernels this engine serves: their cached drivers are
-covered by the same warm start (cache keys include the traced kernel's
-content hash, so an edited kernel body never warm-starts stale tuning) and
-their derived traffic lattices are merged into the plan-precompilation
-envelope.
 """
 
 from __future__ import annotations
@@ -131,9 +105,7 @@ class Request:
 class ServingEngine:
     def __init__(self, model, params, sharder, batch: int, max_seq: int,
                  eos_id: int = 1, seed: int = 0, warm_start: bool = True,
-                 telemetry=None, plan_envelope=None, auto_kernels=None,
-                 step_plans: bool = True, trace=None,
-                 prefill_chunk: int = 32, bucket_lattices=None):
+                 telemetry=None, trace=None, prefill_chunk: int = 32):
         self.model = model
         self.params = params
         self.sharder = sharder
@@ -142,10 +114,10 @@ class ServingEngine:
         self.eos_id = eos_id
         self.key = jax.random.PRNGKey(seed)
         # Opt-in structured tracing (repro.trace.Tracer): installed as the
-        # process-wide tracer before warm start / plan precompilation so
-        # the whole engine bring-up lands in the flight recorder.  Same
-        # sharing contract as telemetry below: one process-wide slot, the
-        # caller decides which tracer wins.
+        # process-wide tracer before warm start so the whole engine
+        # bring-up lands in the flight recorder.  Same sharing contract as
+        # telemetry below: one process-wide slot, the caller decides which
+        # tracer wins.
         self.tracer = trace
         if trace is not None:
             trace.install()
@@ -158,48 +130,15 @@ class ServingEngine:
         self.telemetry = telemetry
         if telemetry is not None:
             telemetry.install()
-        # Load tuned drivers (and any persisted launch plans) from the
-        # artifact cache so the first decode step already launches with
-        # optimal parameters.  ``warm_started`` is the loaded-names list
-        # with coverage counts attached (WarmStartSummary).
+        # Load tuned drivers from the artifact cache so the first decode
+        # step already launches with optimal parameters.  ``warm_started``
+        # is the loaded-names list with coverage counts attached
+        # (WarmStartSummary).
         from repro.core.driver import WarmStartSummary
         self.warm_started: WarmStartSummary = \
             warm_start_from_cache() if warm_start else WarmStartSummary()
         if telemetry is not None:
             telemetry.note_warm_start(self.warm_started)
-        # Introspected kernels served by this engine (repro.introspect
-        # AutoKernel instances): their tuned drivers arrive through the same
-        # cache warm start as everything else (keyed by spec name + the
-        # traced kernel's content hash), and their derived traffic lattices
-        # join the plan-precompilation envelope below so auto kernels get
-        # O(1) plan-table dispatch with zero hand-written spec code.
-        self.auto_kernels = list(auto_kernels or [])
-        # Precompile launch plans over the declared traffic envelope:
-        # kernel name -> {data param: candidate values}.  One choose_many
-        # pass per kernel; kernels with no driver are skipped (lazy fill
-        # covers them once tuning appears).
-        self.plan_summary: dict = {"compiled": [], "loaded": [],
-                                   "skipped": [], "entries": 0}
-        envelope = dict(plan_envelope or {})
-        for ak in self.auto_kernels:
-            envelope.setdefault(ak.name, ak.plan_envelope())
-        if envelope:
-            from repro.core.plan import precompile_plans
-            self.plan_summary = precompile_plans(envelope)
-
-        # Per-step launch plan (core/step_plan.py): every kernel config the
-        # decode/prefill step will need, resolved in one pass (pinned
-        # overrides + plan tables + one batched choose_many per kernel) and
-        # frozen; the jitted step traces under ``use_step_plan`` so ops
-        # dispatch from the frozen dict with zero registry traffic.  The
-        # plan is generation-checked -- a telemetry refit or a pinned
-        # override makes it stale and the next step rebuilds it, so fresh
-        # evidence wins immediately.  Only built for models that actually
-        # dispatch Pallas kernels.
-        self.step_plans = step_plans
-        self._step_plan = None
-        if step_plans:
-            self._refresh_step_plan()
 
         self.cache = model.init_cache(batch, max_seq)
         self.slot_req: list[Request | None] = [None] * batch
@@ -220,16 +159,10 @@ class ServingEngine:
 
         # Trace counters: each key is bumped inside the corresponding jitted
         # function *body*, which executes once per trace -- so the value is
-        # the compile count, the quantity the bucketed-dispatch path holds
-        # at 1 for the decode step (and at most log2(prefill_chunk)+1 for
-        # the pow2-split prefill scans) across arbitrary traffic mixes.
+        # the compile count, held at 1 for the decode step (and at most
+        # log2(prefill_chunk)+1 for the pow2-split prefill scans) across
+        # arbitrary traffic mixes.
         self.compile_counts = {"decode_step": 0, "prefill_chunk": 0}
-
-        # Per-step bucket accounting (tentpole observability): kernel name
-        # -> BucketedDispatch, replayed host-side after each decode step.
-        self.bucket_stats = {"hits": 0, "misses": 0, "waste_sum": 0.0,
-                             "steps": 0}
-        self._bucket_dispatch = self._build_bucket_dispatch(bucket_lattices)
 
         def step(params, token, pos, cache):
             self.compile_counts["decode_step"] += 1
@@ -370,104 +303,6 @@ class ServingEngine:
             with self._cv:
                 self._cv.notify_all()
 
-    def _build_bucket_dispatch(self, bucket_lattices) -> dict:
-        """kernel -> BucketedDispatch from the ``bucket_lattices=`` arg.
-
-        Prebuilt ``BucketedDispatch`` values pass through; bare
-        ``BucketLattice`` values get a dispatch built over whatever plan
-        the registry holds (empty table -> every step is a default-branch
-        miss, which the stats then show).  Default configs come from the
-        model's own kernel requests when available, else the ops-module
-        heuristics.
-        """
-        if not bucket_lattices:
-            return {}
-        from repro.core.device_plan import (
-            BucketedDispatch, build_bucketed_dispatch)
-
-        defaults: dict[str, dict] = {}
-        cfg = getattr(self.model, "cfg", None)
-        if cfg is not None:
-            from repro.models.transformer import decode_kernel_requests
-            for kr in decode_kernel_requests(cfg, self.batch, self.max_seq):
-                defaults.setdefault(kr.kernel, dict(kr.default))
-        out: dict = {}
-        for kernel, lat in bucket_lattices.items():
-            if isinstance(lat, BucketedDispatch):
-                out[kernel] = lat
-                continue
-            default = defaults.get(kernel) or self._heuristic_default(kernel)
-            if default is None:
-                continue
-            out[kernel] = build_bucketed_dispatch(kernel, lat, default)
-        return out
-
-    @staticmethod
-    def _heuristic_default(kernel: str) -> dict | None:
-        from repro.kernels import ops as _ops
-        for prefix, default in (("matmul", _ops.MATMUL_DEFAULT),
-                                ("flash", _ops.FLASH_DEFAULT),
-                                ("moe", _ops.GMM_DEFAULT),
-                                ("ssd", _ops.SSD_DEFAULT)):
-            if kernel.startswith(prefix):
-                return dict(default)
-        return None
-
-    def _note_bucket_stats(self, active: list[int]) -> None:
-        """Host replay of the in-graph bucket decision for this step's
-        effective sequence length; feeds engine stats and telemetry.
-        Bit-identical to the graph by construction (BucketLattice shares
-        the rounding arithmetic), so no device round-trip is needed."""
-        if not self._bucket_dispatch:
-            return
-        cfg = getattr(self.model, "cfg", None)
-        if cfg is None:
-            return
-        from repro.models.transformer import decode_kernel_requests
-
-        eff = int(max(self.slot_pos[s] for s in active)) + 1
-        Ds: dict[str, dict] = {}
-        for kr in decode_kernel_requests(cfg, self.batch, self.max_seq,
-                                         seqs=(eff,)):
-            Ds.setdefault(kr.kernel, dict(kr.D))
-        for kernel, disp in self._bucket_dispatch.items():
-            D = Ds.get(kernel)
-            if D is None:
-                continue
-            hit, waste = disp.observe(D)
-            self.bucket_stats["hits" if hit else "misses"] += 1
-            self.bucket_stats["waste_sum"] += waste
-            if self.telemetry is not None and \
-                    hasattr(self.telemetry, "note_bucket_step"):
-                self.telemetry.note_bucket_step(hit, waste, kernel=kernel)
-        self.bucket_stats["steps"] += 1
-
-    def _refresh_step_plan(self) -> None:
-        cfg = getattr(self.model, "cfg", None)
-        if cfg is None or not getattr(cfg, "use_pallas", False):
-            self._step_plan = None
-            return
-        from repro.core.step_plan import build_step_plan
-        from repro.models.transformer import decode_kernel_requests
-
-        self._step_plan = build_step_plan(
-            decode_kernel_requests(cfg, self.batch, self.max_seq))
-
-    def _run_step(self, tok, ps):
-        """One jitted step under the active step plan (rebuilt first if the
-        registry generation moved -- the rebuild re-resolves against the
-        new state, so a fresh override or refit takes effect on the very
-        next trace).  The dispatch is asynchronous.
-        """
-        if self._step_plan is not None and self._step_plan.stale():
-            self._refresh_step_plan()
-        if self._step_plan is None:
-            return self._step(self.params, tok, ps, self.cache)
-        from repro.core.step_plan import use_step_plan
-
-        with use_step_plan(self._step_plan):
-            return self._step(self.params, tok, ps, self.cache)
-
     def _fill_slots(self, chunked: bool = False) -> None:
         for s in range(self.batch):
             if self.slot_req[s] is not None:
@@ -495,11 +330,10 @@ class ServingEngine:
     def _pow2_chunks(n: int, cmax: int) -> list[int]:
         """Descending powers of two summing to ``n``, each <= ``cmax``.
 
-        Log2-bucketed chunk lengths (the same rounding the bucket lattice
-        uses for data params): the scan compute is exactly ``n`` lanes --
-        no masked tail lanes re-running decode steps -- at the cost of at
-        most ``log2(cmax) + 1`` distinct chunk shapes, each traced once
-        for the life of the engine.
+        Log2-bucketed chunk lengths: the scan compute is exactly ``n``
+        lanes -- no masked tail lanes re-running decode steps -- at the
+        cost of at most ``log2(cmax) + 1`` distinct chunk shapes, each
+        traced once for the life of the engine.
         """
         out = []
         c = 1
@@ -535,10 +369,7 @@ class ServingEngine:
 
     def _run_prefill(self, tokens, slot, pos0, n_valid,
                      base_tok, base_pos) -> None:
-        """One chunked-prefill dispatch under the active step plan (same
-        staleness contract as ``_run_step``)."""
-        if self._step_plan is not None and self._step_plan.stale():
-            self._refresh_step_plan()
+        """One chunked-prefill dispatch (asynchronous)."""
         with trace_span("engine.prefill_chunk", slot=int(slot),
                         slots=self.batch, lanes=int(n_valid)):
             args = (self.params, self.cache, jnp.asarray(tokens),
@@ -546,13 +377,7 @@ class ServingEngine:
                     jnp.asarray(pos0, jnp.int32),
                     jnp.asarray(n_valid, jnp.int32),
                     jnp.asarray(base_tok), jnp.asarray(base_pos))
-            if self._step_plan is None:
-                self.cache = self._prefill_step(*args)
-            else:
-                from repro.core.step_plan import use_step_plan
-
-                with use_step_plan(self._step_plan):
-                    self.cache = self._prefill_step(*args)
+            self.cache = self._prefill_step(*args)
 
     def _single(self, slot: int, token: int, pos: int) -> None:
         """One prompt token through the whole decode step: a one-lane
@@ -563,7 +388,8 @@ class ServingEngine:
             ps = np.array(self.slot_pos, np.int32)
             tok[slot] = token
             ps[slot] = pos
-            _, self.cache = self._run_step(jnp.asarray(tok), jnp.asarray(ps))
+            _, self.cache = self._step(self.params, jnp.asarray(tok),
+                                       jnp.asarray(ps), self.cache)
 
     def _decode_once(self) -> None:
         active = [s for s in range(self.batch) if self.slot_req[s] is not None]
@@ -571,8 +397,9 @@ class ServingEngine:
             return
         with trace_span("engine.decode", active=len(active),
                         slots=self.batch):
-            logits, self.cache = self._run_step(
-                jnp.asarray(self.slot_last), jnp.asarray(self.slot_pos))
+            logits, self.cache = self._step(
+                self.params, jnp.asarray(self.slot_last),
+                jnp.asarray(self.slot_pos), self.cache)
             with trace_span("engine.sample"):
                 self.key, sub = jax.random.split(self.key)
                 temps = {r.temperature for s, r in enumerate(self.slot_req)
@@ -586,7 +413,6 @@ class ServingEngine:
     def _emit(self, active: list[int], greedy_tok, sampled_tok) -> None:
         """Append each active slot's token, and free the slots whose
         request finished."""
-        self._note_bucket_stats(active)
         now = time.perf_counter()
         for s in active:
             req = self.slot_req[s]

@@ -8,14 +8,8 @@ rely on.
 
 Counter semantics (all monotonic within a process):
   * ``choices_total`` / ``choices_by_source`` -- every instrumented
-    ``choose_or_default`` decision, split by path (driver / override /
-    plan / search / search_memo / default, plus ``bucket`` for serving
-    steps whose config was fetched in-graph by the bucketed-dispatch
-    layer).  Decision-memo hits past the
-    full-fidelity window arrive as *coalesced* events
-    (``ChoiceEvent.n_coalesced``); these counters account for every launch
-    a coalesced event stands for, so totals reflect traffic volume even
-    though the listener fires on a sampled subset.
+    ``choose_or_default`` decision, split by path (override / driver /
+    search / search_memo / default).
   * ``fallback_default_total`` -- launches served by the static heuristic
     (the "untuned forever" signal the subsystem exists to drive to zero).
   * ``shadow_probes_total`` / ``probe_device_seconds_total`` -- sampled
@@ -26,18 +20,10 @@ Counter semantics (all monotonic within a process):
   * ``disk_cache_hits`` / ``disk_cache_misses`` -- driver-artifact cache
     read-throughs (from the registry, so they count even before telemetry
     is installed).
-  * ``plan_hits`` / ``plan_misses`` -- compiled-launch-plan dispatches
-    (the O(1) hot path of core/plan.py) vs envelope misses that fell back
-    to the driver; ``choose_many_calls`` / ``choose_many_rows`` -- batched
-    multi-shape selection passes and their total batch size (how much plan
-    compilation happened, and how wide).  ``plan`` also appears as its own
-    ``choices_by_source`` bucket.
-  * ``bucket_hits`` / ``bucket_misses`` -- bucketed in-graph dispatch
-    outcomes per (decode step, kernel): hit means the raw shape landed on
-    the bucket lattice and the padded bucket's config served the launch;
-    miss means the out-of-range default branch ran.  The
-    ``padding_waste_frac`` gauge is the mean padded-away volume fraction
-    across those steps.
+
+The one gauge, ``registry_generation``, counts the registry mutations that
+could change a launch decision (driver registration, refit invalidation,
+pinned override).
 """
 
 from __future__ import annotations
@@ -78,14 +64,6 @@ class TelemetryCounters:
     refit_device_seconds_total: float = 0.0
     overrides_total: int = 0
     warm_started_kernels: int = 0
-    # Bucketed in-graph dispatch (serving engine, core/buckets.py): per
-    # decode step and kernel, did the raw shape land on the lattice (hit:
-    # the padded bucket's config served in-graph) or fall to the default
-    # branch (miss)?  waste_sum accumulates the padding-waste fraction of
-    # hits, so waste_sum / (hits + misses) is the mean padded-away volume.
-    bucket_hits: int = 0
-    bucket_misses: int = 0
-    bucket_padding_waste_sum: float = 0.0
 
 
 class MetricsExporter:
@@ -112,29 +90,12 @@ class MetricsExporter:
             "refit_device_seconds_total": c.refit_device_seconds_total,
             "overrides_total": c.overrides_total,
             "warm_started_kernels": c.warm_started_kernels,
-            "bucket_hits": c.bucket_hits,
-            "bucket_misses": c.bucket_misses,
             "disk_cache_hits": reg["disk_cache_hits"],
             "disk_cache_misses": reg["disk_cache_misses"],
-            "plan_hits": reg.get("plan_hits", 0),
-            "plan_misses": reg.get("plan_misses", 0),
-            "choose_many_calls": reg.get("choose_many_calls", 0),
-            "choose_many_rows": reg.get("choose_many_rows", 0),
-            "plan_invalidations": reg.get("plan_invalidations", 0),
-            "memo_invalidations": reg.get("memo_invalidations", 0),
         }
         # Gauges: point-in-time registry state (hot-swap churn visibility),
         # as opposed to the monotonic counters above.
-        n_bucket = c.bucket_hits + c.bucket_misses
-        gauges = {
-            "registry_generation": registry.generation,
-            "decision_memo_entries": registry.memo_size(),
-            # Mean fraction of padded bucket volume that was padding, over
-            # every bucket-accounted decode step so far (0.0 when the
-            # engine is not running bucketed dispatch).
-            "padding_waste_frac": (
-                c.bucket_padding_waste_sum / n_bucket if n_bucket else 0.0),
-        }
+        gauges = {"registry_generation": registry.generation}
         keys = [{
             "kernel": s.kernel,
             "hw": s.hw_name,
@@ -197,11 +158,7 @@ class MetricsExporter:
                      "probe_device_seconds_total", "drift_events_total",
                      "refits_total", "refit_failures_total",
                      "refit_device_seconds_total", "overrides_total",
-                     "bucket_hits", "bucket_misses",
                      "disk_cache_hits", "disk_cache_misses",
-                     "plan_hits", "plan_misses",
-                     "choose_many_calls", "choose_many_rows",
-                     "plan_invalidations", "memo_invalidations",
                      "warm_started_kernels"):
             lines.append(f"# TYPE {prefix}_{name} counter")
             counter(name, c[name])

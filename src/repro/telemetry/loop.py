@@ -60,8 +60,8 @@ class Telemetry:
     refits process-local).
 
     ``ledger`` (optional; a ``repro.trace.Ledger`` or a path) turns on the
-    flight ledger: every choice event (already coalesced by the decision
-    memo, so steady-state writes stay rare), shadow probe, drift event and
+    flight ledger: every choice event (ops decide once per shape and
+    trace, so steady-state writes stay rare), shadow probe, drift event and
     refit outcome is appended as one JSONL line -- the persistent record of
     what the system decided, predicted, and observed.
     """
@@ -117,23 +117,6 @@ class Telemetry:
         with self._lock:
             self.counters.warm_started_kernels += len(kernels)
 
-    def note_bucket_step(self, hit: bool, waste: float,
-                         kernel: str | None = None) -> None:
-        """One bucketed-dispatch outcome from a serving decode step: the
-        engine's host replay of the in-graph bucket decision (bit-identical
-        rounding, see core/buckets.py).  ``waste`` is the padding-waste
-        fraction of the hit bucket (0.0 on a miss)."""
-        with self._lock:
-            if hit:
-                self.counters.bucket_hits += 1
-            else:
-                self.counters.bucket_misses += 1
-            self.counters.bucket_padding_waste_sum += float(waste)
-        if self._emitting():
-            self._emit({"type": "bucket_step", "hit": bool(hit),
-                        "waste": float(waste), "kernel": kernel,
-                        "t_ns": time.monotonic_ns()})
-
     # -- event emission ------------------------------------------------------
     def _emitting(self) -> bool:
         """Is any event sink (ledger or metrics bus) attached?  Gates
@@ -161,26 +144,19 @@ class Telemetry:
     # -- the loop ------------------------------------------------------------
     def _on_choice(self, event: ChoiceEvent) -> None:
         c = self.counters
-        # A coalesced event stands for n_coalesced launches (the decision
-        # memo batches steady-state hits); counters account for all of
-        # them, the shadow-probe sampling below sees one event.
-        n = event.n_coalesced
         if self._emitting():
-            # One event per *line*, not per launch: the coalescing
-            # already happened upstream, so this inherits its write rate.
             self._emit({
                 "type": "choice", "kernel": event.kernel,
                 "hw": event.hw_name, "D": dict(event.D),
                 "config": dict(event.config), "source": event.source,
-                "predicted_s": event.predicted_s,
-                "n_coalesced": n, "t_ns": event.t_ns,
+                "predicted_s": event.predicted_s, "t_ns": event.t_ns,
             })
         with self._lock:
-            c.choices_total += n
+            c.choices_total += 1
             c.choices_by_source[event.source] = \
-                c.choices_by_source.get(event.source, 0) + n
+                c.choices_by_source.get(event.source, 0) + 1
             if event.source == "default":
-                c.fallback_default_total += n
+                c.fallback_default_total += 1
             if self._reacting:
                 return          # choices made *by* a refit: count only
             stats, do_probe = self.recorder.observe_choice(event)

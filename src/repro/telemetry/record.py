@@ -140,29 +140,16 @@ class LaunchRecorder:
         number of launches regardless of traffic interleaving.  Only choices
         that carry a prediction (driver/override paths) are probe-eligible:
         without a predicted time there is nothing to compare against.
-
-        A coalesced event (``n_coalesced`` > 1, from the decision memo's
-        sampled steady state) advances ``n_choices`` by the launches it
-        stands for, but is at most *one* probe opportunity -- eligible when
-        the batch it covers crossed a ``probe_every`` boundary.
         """
         with self._lock:
             stats = self._stats_for(event)
             prev = stats.n_choices
-            stats.n_choices += event.n_coalesced
+            stats.n_choices += 1
             stats.last_D = dict(event.D)
             stats.last_config = dict(event.config)
             if event.predicted_s is None:
                 return stats, False
-            period = max(self.config.probe_every, 1)
-            # Probe-eligible iff some launch ordinal in [prev, n_choices-1]
-            # is a multiple of the period (ordinal 0 = the first choice);
-            # for n_coalesced == 1 this is exactly the old
-            # ``prev % period == 0``.  Python floor division makes the
-            # prev == 0 case fall out naturally ((-1) // p == -1).
-            do_probe = ((prev + event.n_coalesced - 1) // period
-                        > (prev - 1) // period)
-            return stats, do_probe
+            return stats, prev % max(self.config.probe_every, 1) == 0
 
     def record_probe(self, stats: KeyStats, predicted_s: float,
                      observed_s: float) -> float:

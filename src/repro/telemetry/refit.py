@@ -233,8 +233,8 @@ class RefitController:
                    executions=result.validation_executions)
 
         # Hot swap + write-through, atomically from the registry's view:
-        # drop every memo describing the old fit, then install the new
-        # driver and the override.  Cache eviction last -- a concurrent
+        # drop the old driver (with its searched shapes and overrides),
+        # then install the new driver and the override.  Cache eviction last -- a concurrent
         # reader sees either the old generation or the new one, never
         # neither.  A failed re-fit swaps nothing: the old driver keeps
         # serving (a drifted fit beats no fit) with the measured override

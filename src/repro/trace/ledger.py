@@ -5,7 +5,7 @@ this process", the ledger answers "what did the system decide, predict and
 observe -- ever".  One JSON object per line, ``type``-tagged:
 
   ``session`` one wall<->monotonic anchor per ledger open (see below)
-  ``choice``  one (possibly coalesced) launch decision (from ChoiceEvents)
+  ``choice``  one launch decision (from ChoiceEvents)
   ``probe``   a shadow probe: predicted vs observed seconds, rel-error EWMA
   ``drift``   a DriftDetector trip
   ``refit``   a RefitController outcome (search/fit/validate/swap)
@@ -23,9 +23,8 @@ reading, so readers can align any later stamp to wall-clock time --
 many processes' ledgers into one wall-clock-ordered stream (the
 multi-process replay path of ``repro.obs``).
 
-Steady-state write volume inherits the driver's coalescing accounting: a
-memo-hit storm writes one ``choice`` line per coalescing window, not one
-per launch.  ``iter_ledger``/``read_ledger`` + ``ledger_summary`` are the
+Ops decide once per shape and trace, so steady-state serving writes no
+``choice`` lines at all.  ``iter_ledger``/``read_ledger`` + ``ledger_summary`` are the
 query side, used by ``python -m repro.launch.status``; ``LedgerTail`` is
 the incremental form (byte offsets advanced only past complete lines)
 shared by ``fleet.RetuneQueue``, ``status --follow`` and the live

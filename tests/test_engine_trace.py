@@ -164,3 +164,45 @@ def test_engine_programs_keep_their_module_names():
                              (prefill, PREFILL_PROGRAM)):
         text = lowered.compile().as_text()
         assert text.startswith(f"HloModule {program},"), text[:80]
+
+
+def test_async_outputs_equal_sync_from_one_decode_trace():
+    """The async front-end (pow2-chunked prefill) gives the greedy
+    outputs of the token-by-token sync loop, and one decode-step trace
+    serves every prompt length."""
+    outs = {}
+    for mode in ("sync", "async"):
+        engine = _engine()
+        reqs = _serve(engine, mode)
+        assert all(r.done for r in reqs)
+        outs[mode] = [list(r.output) for r in reqs]
+        assert engine.compile_counts["decode_step"] == 1
+        if mode == "async":
+            # chunk 4: at most log2(4) + 1 prefill traces
+            assert 1 <= engine.compile_counts["prefill_chunk"] <= 3
+    assert outs["sync"] == outs["async"]
+
+
+class TestPow2Chunks:
+    def test_exact_cover_and_bounds(self):
+        from repro.serving.engine import ServingEngine
+
+        for cmax in (1, 2, 8, 32, 64):
+            allowed = {c for c in (1, 2, 4, 8, 16, 32, 64) if c <= cmax}
+            for n in list(range(0, 70)) + [127, 128, 129, 1000]:
+                chunks = ServingEngine._pow2_chunks(n, cmax)
+                assert sum(chunks) == n, (n, cmax)
+                assert all(c in allowed for c in chunks), (n, cmax)
+                # descending, so at most one of each size below the cap:
+                # the trace-cache bound log2(cmax)+1 plus repeats of cmax
+                assert chunks == sorted(chunks, reverse=True), (n, cmax)
+                below_cap = [c for c in chunks if c < cmax]
+                assert len(below_cap) == len(set(below_cap)), (n, cmax)
+
+    def test_trace_set_is_log_bounded(self):
+        from repro.serving.engine import ServingEngine
+
+        sizes = set()
+        for n in range(1, 2000):
+            sizes.update(ServingEngine._pow2_chunks(n, 64))
+        assert sizes == {1, 2, 4, 8, 16, 32, 64}   # log2(64)+1 traces

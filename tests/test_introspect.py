@@ -230,11 +230,10 @@ class TestSourceFingerprint:
 
 class TestAutoKernelPipeline:
     def test_end_to_end_zero_hand_spec(self, tmp_path, monkeypatch):
-        """introspect -> collect/fit -> choose -> plan-table dispatch ->
-        telemetry, for both auto kernels, no hand-written spec anywhere."""
+        """introspect -> collect/fit -> choose -> dispatch -> telemetry,
+        for both auto kernels, no hand-written spec anywhere."""
         monkeypatch.setenv("KLARAPTOR_CACHE_DIR", str(tmp_path))
         registry.clear()
-        from repro.core.plan import precompile_plans
         from repro.launch.serve import build_auto_kernels
         from repro.telemetry import Telemetry
 
@@ -253,15 +252,11 @@ class TestAutoKernelPipeline:
                 assert drv is not None
                 r = selection_ratio(ak.spec, sim, drv, D)
                 assert r["ratio"] >= 0.7, r
-                summary = precompile_plans({ak.name: ak.plan_envelope()})
-                assert summary["entries"] > 0
-                before = registry.stats()["plan_hits"]
                 cfg = choose_or_default(ak.name, D, ak.defaults)
-                assert registry.stats()["plan_hits"] == before + 1
                 assert cfg == drv.choose(D)
             import json
             j = json.loads(tel.exporter.json())
-            assert j["counters"]["choices_by_source"].get("plan", 0) >= 2
+            assert j["counters"]["choices_by_source"].get("driver", 0) >= 2
         finally:
             tel.uninstall()
             registry.clear()

@@ -216,7 +216,7 @@ class TestFlashBlockCounter:
                                             "interior": interior,
                                             "total": total}
 
-    def _trace_launch(self, **kw):
+    def _trace_launch(self):
         from repro.trace import Tracer
 
         bf = jnp.bfloat16
@@ -224,7 +224,7 @@ class TestFlashBlockCounter:
                 jax.ShapeDtypeStruct((32, 8192, 128), bf),
                 jax.ShapeDtypeStruct((32, 8192, 128), bf)]
         fn = functools.partial(ops.flash_attention, num_q_heads=16,
-                               num_kv_heads=8, use_pallas=True, **kw)
+                               num_kv_heads=8, use_pallas=True)
         with Tracer() as tracer:
             jax.eval_shape(fn, *args)
         return tracer.spans()
@@ -237,10 +237,3 @@ class TestFlashBlockCounter:
         assert [s.attrs for s in spans] == [
             {"kernel": self.KERNEL, "live": 640, "interior": 384,
              "total": 1024}]
-
-    def test_no_span_on_the_plan_hit_path(self, clean):
-        class Plan:
-            def resolve(self, kernel, D):
-                return TestFlashBlockCounter.TILES
-
-        assert self._trace_launch(plan=Plan()) == []

@@ -539,17 +539,3 @@ def test_observatory_counts_session_header_like_replay(tmp_path):
     assert obs.bus.n_events == 1
     replayed = replay_ledgers(tmp_path / "x.jsonl")
     assert obs.bus.snapshot_json() == replayed.bus.snapshot_json()
-
-
-def test_telemetry_note_bucket_step_reaches_bus_without_ledger():
-    from repro.core import V5E, V5eSimulator, matmul_spec
-    from repro.telemetry import Telemetry
-    tel = Telemetry([matmul_spec()], V5eSimulator(V5E), cache=False)
-    obs = Observatory()
-    with obs:
-        tel.note_bucket_step(True, 0.25, kernel="mm")
-    tel.note_bucket_step(True, 0.25, kernel="mm")   # bus gone: no ingest
-    assert obs.bus.counter("bucket_steps",
-                           {"kernel": "mm", "outcome": "hit"}).total == 1.0
-    snap = tel.exporter.snapshot()
-    assert snap["counters"]["bucket_hits"] == 2

@@ -5,7 +5,8 @@ import math
 import numpy as np
 import pytest
 
-from repro.core import (Polynomial, RationalFunction, cuda_occupancy_program,
+from repro.core import (Klaraptor, Polynomial, RationalFunction,
+                        V5eSimulator, cuda_occupancy_program, matmul_spec,
                         tpu_pipeline_occupancy_program)
 from repro.core.rational_program import (Ceil, Const, Floor, Max, Min, Select,
                                          ceil_div, const, floor_div, var)
@@ -114,3 +115,65 @@ class TestOccupancyPrograms:
         occ = cuda_occupancy_program()
         chart = occ.to_flowchart()
         assert "decide" in chart and "compute" in chart
+
+
+# ---------------------------------------------------------------------------
+# D-specialization of rational programs
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def builds():
+    """A matmul driver build (registry untouched) whose fits the
+    specialization tests bind."""
+    sim = V5eSimulator(noise=0.03, seed=7)
+    kl = Klaraptor(sim, cache=False)
+    return {"matmul": kl.build_driver(matmul_spec(), repeats=2,
+                                      max_configs_per_size=16,
+                                      register=False)}
+
+
+class TestSpecialize:
+    def test_expr_folding_matches_eval(self):
+        from repro.core import ceil_div, var
+        e = ceil_div(var("m"), var("bm")) * ceil_div(var("n"), var("bn"))
+        s = e.specialize({"m": 4096, "n": 2048})
+        assert s.free_vars() == {"bm", "bn"}
+        env = {"bm": np.array([8.0, 128.0]), "bn": np.array([128.0, 256.0])}
+        np.testing.assert_array_equal(
+            s.eval(env), e.eval({**env, "m": 4096, "n": 2048}))
+
+    def test_select_folds_and_pieces_shrink(self):
+        from repro.core import RationalProgram, Select, const, var
+        e = Select(var("d") >= const(128), var("p") * 2.0, var("p") * 3.0)
+        prog = RationalProgram("t", ("d", "p"), {"E": e})
+        assert prog.count_pieces() == 2
+        spec = prog.specialize({"d": 256})
+        assert spec.count_pieces() == 1           # decision node folded away
+        assert spec.inputs == ("p",)
+        assert float(spec.eval({"p": 5.0})) == 10.0
+
+    def test_full_binding_gives_constant(self):
+        from repro.core import Const, var
+        e = (var("a") + var("b")) / var("c")
+        s = e.specialize({"a": 6, "b": 2, "c": 4})
+        assert isinstance(s, Const) and s.value == 2.0
+
+    def test_partially_bound_fitted_leaf(self, builds):
+        """Specializing a program whose Fitted leaves mix D and P must pin
+        the D inputs (partial application) so the specialized program is
+        evaluable with only its advertised inputs."""
+        from repro.core import (Fitted, RationalProgram, build_time_program,
+                                matmul_spec, var)
+        fits = {m: f.function for m, f in builds["matmul"].fits.items()}
+        prog = build_time_program(matmul_spec(), fits)
+        D = {"m": 4096.0, "n": 2048.0, "k": 1024.0}
+        sp = prog.specialize(D)
+        assert not (set(sp.inputs) & set(D))
+        P = {"bm": np.array([128.0, 256.0]), "bn": np.array([512.0, 512.0]),
+             "bk": np.array([512.0, 1024.0])}
+        np.testing.assert_allclose(sp.eval(P), prog.eval({**D, **P}))
+        # a partially-applied leaf refuses source emission (codegen never
+        # produces one; silently wrong source would be worse)
+        leaf = Fitted("g", fits["mem_step"], {"bm": 8.0})
+        with pytest.raises(NotImplementedError):
+            leaf.to_source()

@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import (Klaraptor, V5eSimulator, flash_attention_spec,
-                        matmul_spec, moe_gmm_spec, ssd_scan_spec)
+                        matmul_spec, moe_gmm_spec, registry,
+                        set_choice_listener, ssd_scan_spec)
 from repro.introspect import spec_from_kernel
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -64,22 +65,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-class _DriverPlan:
-    """A step plan answering from freshly built drivers; records what it
-    answered, so a test can name the config it compiled."""
-
-    def __init__(self, drivers):
-        self.drivers = drivers
-        self.chosen = {}
-
-    def resolve(self, kernel, D):
-        cfg = self.drivers[kernel].choose(dict(D))
-        self.chosen[kernel] = cfg
-        return cfg
-
-
 @pytest.fixture(scope="module")
-def plan():
+def drivers():
     specs = [
         matmul_spec(), flash_attention_spec(head_dim=128),
         flash_attention_spec(head_dim=LLAMA.head_dim), moe_gmm_spec(),
@@ -88,8 +75,21 @@ def plan():
         spec_from_kernel(colsum_pallas, colsum_grid_spec()),
     ]
     tuner = Klaraptor(V5eSimulator(), cache=False)
-    return _DriverPlan({s.name: tuner.build_driver(s, register=False).driver
-                        for s in specs})
+    return [tuner.build_driver(s, register=False).driver for s in specs]
+
+
+@pytest.fixture()
+def chosen(drivers):
+    """Registers freshly built drivers and records the config each
+    launch decision chose, so a test can name the config it compiled."""
+    registry.clear()
+    for drv in drivers:
+        registry.register(drv)
+    seen = {}
+    set_choice_listener(lambda ev: seen.__setitem__(ev.kernel, ev.config))
+    yield seen
+    set_choice_listener(None)
+    registry.clear()
 
 
 def _attn(bh_q, bh_kv, s, d, heads_q, heads_kv):
@@ -137,19 +137,18 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_compiles_to_mosaic(case, one_chip, plan, monkeypatch):
+def test_compiles_to_mosaic(case, one_chip, chosen, monkeypatch):
     kernel, shapes, op = CASES[case]
     # The ops take their CPU (interpret) branch on this backend; compiling
     # for the described chip needs the TPU branch.
     monkeypatch.setattr(ops, "interpret_mode", lambda requested=True: False)
     args = [jax.ShapeDtypeStruct(s[:-1], s[-1], sharding=one_chip)
             for s in shapes]
-    plan.chosen.clear()
-    fn = functools.partial(op, use_pallas=True, plan=plan)
+    fn = functools.partial(op, use_pallas=True)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert kernel in plan.chosen, f"{kernel} was never dispatched"
+    assert kernel in chosen, f"{kernel} was never dispatched"
     assert "tpu_custom_call" in compiled.as_text(), (
-        f"{kernel} with {plan.chosen[kernel]}: no Mosaic kernel")
+        f"{kernel} with {chosen[kernel]}: no Mosaic kernel")
 
 
 INTERNLM2 = get_config("internlm2-1.8b")

@@ -296,29 +296,22 @@ class TestRegistryGauges:
     def test_generation_memo_and_invalidation_gauges(self, clean):
         tel = Telemetry({}, V5eSimulator()).install()
         try:
+            snap0 = tel.snapshot()
+            # a pinned override and a refit invalidation each move the
+            # registry generation
             registry.note_override("matmul_b16", V5E.name, D_SMALL,
                                    MM_DEFAULT)
-            snap0 = tel.snapshot()
-            assert snap0["gauges"]["decision_memo_entries"] == 0
-            # an override decision is memoized -> the gauge moves
-            choose_or_default("matmul_b16", D_SMALL, MM_DEFAULT)
             snap1 = tel.snapshot()
-            assert snap1["gauges"]["decision_memo_entries"] == 1
-            # a registry mutation drops the memo and counts the kill
+            assert snap1["gauges"]["registry_generation"] > \
+                snap0["gauges"]["registry_generation"]
             registry.invalidate_kernel("matmul_b16")
             snap2 = tel.snapshot()
             assert snap2["gauges"]["registry_generation"] > \
                 snap1["gauges"]["registry_generation"]
-            assert snap2["gauges"]["decision_memo_entries"] == 0
-            assert snap2["counters"]["memo_invalidations"] == \
-                snap1["counters"]["memo_invalidations"] + 1
         finally:
             tel.uninstall()
         text = tel.prometheus()
         assert "# TYPE klaraptor_registry_generation gauge" in text
-        assert "# TYPE klaraptor_decision_memo_entries gauge" in text
-        assert "# TYPE klaraptor_memo_invalidations counter" in text
-        assert "# TYPE klaraptor_plan_invalidations counter" in text
 
 
 def _run_telemetry_with_ledger(tmp_path, refit=False):

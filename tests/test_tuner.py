@@ -179,3 +179,16 @@ class TestPolybenchSuite:
             cands = spec.candidates(
                 {d: 1024 for d in spec.data_params})
             assert cands, spec.name
+
+
+class TestFitTileMemo:
+    def test_memoized_and_correct(self):
+        from repro.kernels.ops import _fit_tile
+        _fit_tile.cache_clear()
+        raw = _fit_tile.__wrapped__
+        cases = [(4096, 512, 128), (100, 64, 8), (7, 512, 8),
+                 (384, 512, 128), (4096, 512, 128)]
+        for size, tile, align in cases:
+            assert _fit_tile(size, tile, align) == raw(size, tile, align)
+        info = _fit_tile.cache_info()
+        assert info.hits >= 1 and info.misses == len(set(cases))

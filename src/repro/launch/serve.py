@@ -206,10 +206,12 @@ def main() -> None:
         print(f"time to first token: median {ttft['median']:.1f} ms, "
               f"p90 {ttft['p90']:.1f} ms over {ttft['requests']} requests")
     if args.run_async:
-        cc = engine.compile_counts
+        cc, ip = engine.compile_counts, engine.cache_in_place
         print(f"async front-end: {cc['decode_step']} decode-step compile(s), "
               f"{cc['prefill_chunk']} prefill-chunk compile(s), "
-              f"chunk={engine.prefill_chunk}")
+              f"chunk={engine.prefill_chunk}; cache updated in place by "
+              f"{ip['decode_step']} decode and {ip['prefill_chunk']} "
+              f"prefill-chunk dispatch(es)")
     if observatory is not None:
         alerts = observatory.evaluate()
         firing = sorted({r for r, _ in observatory.slo.firing})

@@ -134,18 +134,20 @@ def attention(cfg: ModelConfig, p: dict, xq: jax.Array, sharder,
 
 def attention_decode(cfg: ModelConfig, p: dict, x1: jax.Array, sharder,
                      desc: BlockDesc, pos: jax.Array,
-                     cache_k: jax.Array, cache_v: jax.Array,
+                     cache_k: jax.Array, cache_v: jax.Array, layer,
                      cross: bool = False, prefix: str = ""):
-    """One-token decode against a (B, S_cache, KV, dh) KV cache.
+    """One-token decode against layer ``layer`` of a stacked
+    (G, B, S_cache, KV, dh) KV cache.
 
-    For self-attention the new token's k/v are written at position ``pos``;
-    for cross-attention the cache is static (encoder outputs).  Returns
-    (y, cache_k, cache_v).
+    For self-attention the new token's k/v are written in place at
+    ``(layer, b, pos[b])`` before the layer's slice is read; for
+    cross-attention the cache is static (encoder outputs).  Returns
+    (y, cache_k, cache_v), the caches still stacked.
     """
     B = x1.shape[0]
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     group = H // KV
-    S = cache_k.shape[1]
+    S = cache_k.shape[2]
 
     q = (x1 @ p[f"{prefix}wq"]).reshape(B, 1, H, dh)
     if cfg.qk_norm and f"{prefix}q_norm" in p:
@@ -158,14 +160,15 @@ def attention_decode(cfg: ModelConfig, p: dict, x1: jax.Array, sharder,
         if cfg.use_rope:
             q = rope(q, pos[:, None], cfg.rope_theta)
             k1 = rope(k1, pos[:, None], cfg.rope_theta)
-        cache_k = _write_cache(cache_k, k1, pos)
-        cache_v = _write_cache(cache_v, v1, pos)
+        cache_k = _write_cache(cache_k, k1, pos, layer)
+        cache_v = _write_cache(cache_v, v1, pos, layer)
+    ck, cv = cache_k[layer], cache_v[layer]
 
     # Keep the cache in its storage dtype: upcasting (B, S, KV, dh) to f32
     # would materialize a second full cache; accumulate in f32 instead.
-    qf = q.reshape(B, KV, group, dh).astype(cache_k.dtype)
+    qf = q.reshape(B, KV, group, dh).astype(ck.dtype)
     scale = cfg.attn_scale if cfg.attn_scale is not None else dh ** -0.5
-    s = jnp.einsum("bkgd,bskd->bkgs", qf, cache_k,
+    s = jnp.einsum("bkgd,bskd->bkgs", qf, ck,
                    preferred_element_type=f32) * scale     # (B, KV, g, S)
     if cfg.attn_softcap is not None:
         s = cfg.attn_softcap * jnp.tanh(s / cfg.attn_softcap)
@@ -177,18 +180,22 @@ def attention_decode(cfg: ModelConfig, p: dict, x1: jax.Array, sharder,
         mask = jnp.ones_like(mask)
     s = jnp.where(mask, s, -1e30)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", w.astype(cache_v.dtype), cache_v,
+    out = jnp.einsum("bkgs,bskd->bkgd", w.astype(cv.dtype), cv,
                      preferred_element_type=f32)           # (B, KV, g, dh)
     out = out.reshape(B, 1, H * dh).astype(x1.dtype)
     return out @ p[f"{prefix}wo"], cache_k, cache_v
 
 
-def _write_cache(cache: jax.Array, new: jax.Array, pos: jax.Array):
-    """Scatter (B, 1, KV, dh) ``new`` into (B, S, KV, dh) cache at pos."""
-    B, S = cache.shape[0], cache.shape[1]
-    onehot = jax.nn.one_hot(pos, S, dtype=cache.dtype)       # (B, S)
-    return cache * (1.0 - onehot[:, :, None, None]) + \
-        onehot[:, :, None, None] * new.astype(cache.dtype)
+def _write_cache(cache: jax.Array, new: jax.Array, pos: jax.Array, layer
+                 ) -> jax.Array:
+    """Store (B, 1, KV, dh) ``new`` at ``(layer, b, pos[b])`` of the
+    stacked (G, B, S, KV, dh) cache: one row per slot, every other row
+    untouched.  A ``pos`` outside [0, S) writes nothing."""
+    B, S = new.shape[0], cache.shape[2]
+    # An index of S is dropped by the scatter; a negative one would wrap.
+    row = jnp.where((pos >= 0) & (pos < S), pos, S)
+    return cache.at[layer, jnp.arange(B), row].set(
+        new[:, 0].astype(cache.dtype), mode="drop")
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,9 @@ compiles stay fast).  gemma2's local/global alternation is period 2; jamba's
 1:7 attention:mamba interleave with alternating MoE is period 8; uniform
 models are period 1.
 
-Decode carries a per-group cache pytree with the same leading "layers" axis,
-scanned jointly with the parameters.
+Decode carries the whole cache pytree, with the same leading "layers" axis,
+through the scan over the parameters, and updates each layer's slice in
+place.
 """
 
 from __future__ import annotations
@@ -260,49 +261,53 @@ def init_cache_specs(cfg: ModelConfig, batch: int, max_seq: int,
 def decode_step(cfg: ModelConfig, params: dict, token: jax.Array,
                 pos: jax.Array, cache: dict, sharder
                 ) -> tuple[jax.Array, dict]:
-    """One decode step: token (B,), pos (B,) -> (logits (B, V), new cache)."""
+    """One decode step: token (B,), pos (B,) -> (logits (B, V), new cache).
+
+    The stacked cache is carried through the layer loop, not rebuilt:
+    each attention layer writes its token's k/v rows in place and reads
+    its slice, and each mamba layer stores its new states in its slice.
+    A caller that donates the cache gets the step without a copy of it.
+    """
     x = embed_tokens(cfg, params, token[:, None])             # (B,1,d)
 
-    def group_body(x, scanned):
-        gp, gc = scanned
-        newc = {}
+    def group_body(x, cache, gp, g):
+        cache = dict(cache)
         for i, desc in enumerate(cfg.block_pattern):
-            p, c = gp[f"pos{i}"], gc[f"pos{i}"]
-            nc = {}
+            p, c = gp[f"pos{i}"], dict(cache[f"pos{i}"])
+            h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
             if desc.kind == "attn":
-                h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
-                y, nc["k"], nc["v"] = L.attention_decode(
-                    cfg, p, h, sharder, desc, pos, c["k"], c["v"])
-                x = _residual(cfg, x, y)
+                y, c["k"], c["v"] = L.attention_decode(
+                    cfg, p, h, sharder, desc, pos, c["k"], c["v"], g)
             else:
-                h = L.rmsnorm(x, p["norm"], cfg.rms_eps)
-                y, nc["conv"], nc["ssm"] = L.mamba_decode(
-                    cfg, p, h, c["conv"], c["ssm"])
-                x = _residual(cfg, x, y)
+                y, conv, ssm = L.mamba_decode(cfg, p, h, c["conv"][g],
+                                              c["ssm"][g])
+                c["conv"] = c["conv"].at[g].set(conv)
+                c["ssm"] = c["ssm"].at[g].set(ssm)
+            x = _residual(cfg, x, y)
             if desc.cross_attn:
                 h = L.rmsnorm(x, p["x_norm"], cfg.rms_eps)
-                y, nc["xk"], nc["xv"] = L.attention_decode(
-                    cfg, p, h, sharder, desc, pos, c["xk"], c["xv"],
+                y, _, _ = L.attention_decode(
+                    cfg, p, h, sharder, desc, pos, c["xk"], c["xv"], g,
                     cross=True, prefix="x_")
                 x = _residual(cfg, x, y)
             if desc.mlp:
                 y, _ = _feed_forward(cfg, desc, p, x, sharder)
                 x = _residual(cfg, x, y)
-            newc[f"pos{i}"] = nc
-        return x, newc
+            cache[f"pos{i}"] = c
+        return x, cache
 
     if cfg.scan_layers:
-        (x, new_cache) = jax.lax.scan(
-            lambda carry, scanned: group_body(carry, scanned),
-            x, (params["blocks"], cache))
+        def scan_body(carry, scanned):
+            gp, g = scanned
+            return group_body(*carry, gp, g), None
+
+        (x, cache), _ = jax.lax.scan(
+            scan_body, (x, cache),
+            (params["blocks"], jnp.arange(cfg.n_groups, dtype=jnp.int32)))
     else:
-        parts = []
         for g in range(cfg.n_groups):
             gp = jax.tree.map(lambda p: p[g], params["blocks"])
-            gc = jax.tree.map(lambda c: c[g], cache)
-            x, nc = group_body(x, (gp, gc))
-            parts.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
+            x, cache = group_body(x, cache, gp, g)
     x = L.rmsnorm(x, params["final_norm"], cfg.rms_eps)
     logits = unembed(cfg, params, x[:, 0])                    # (B, V)
-    return logits, new_cache
+    return logits, cache

@@ -26,6 +26,12 @@ Two front-ends share those slots:
   steps -- the "one compiled step serves every shape" invariant is
   ``compile_counts["decode_step"] == 1`` across a whole traffic mix.
 
+Both programs take the KV cache donated and update it in place: each
+step writes one row per slot and layer into the buffers it was given.
+So the old ``engine.cache`` is deleted as each step is dispatched, and
+nothing may hold it across a step.  ``cache_in_place`` counts, per
+program, the dispatches whose input cache the runtime consumed.
+
 The scheduler's host phases are ``repro.trace`` spans, so a profiler
 trace (or an installed ``Tracer``) shows which phase each device gap
 falls in.  No span waits on the device; with neither sink on, each costs
@@ -40,9 +46,11 @@ a flag check.
                                 requests still pending)
   ``engine.prefill_chunk``      one prefill dispatch: ``lanes`` steps of
                                 the ``slots``-wide decode step, each
-                                advancing ``slot`` alone
+                                advancing ``slot`` alone (``in_place``)
   ``engine.decode``             one decode round over ``slots`` slots,
                                 ``active`` of them holding a request
+                                (``in_place``: 1 if the step consumed
+                                its input cache)
   ``engine.sample``             inside decode: key split, the greedy and
                                 sampled tokens, their copy to the host
   ``engine.emit``               inside decode: per-slot bookkeeping
@@ -163,12 +171,15 @@ class ServingEngine:
         # log2(prefill_chunk)+1 for the pow2-split prefill scans) across
         # arbitrary traffic mixes.
         self.compile_counts = {"decode_step": 0, "prefill_chunk": 0}
+        # Dispatches whose donated input cache the runtime consumed, so
+        # that the step updated it in place.  Not compiles: kept apart.
+        self.cache_in_place = {"decode_step": 0, "prefill_chunk": 0}
 
         def step(params, token, pos, cache):
             self.compile_counts["decode_step"] += 1
             return model.decode_step(params, token, pos, cache, sharder)
 
-        self._step = jax.jit(step)
+        self._step = jax.jit(step, donate_argnums=(3,))
 
         def prefill_chunk_step(params, cache, tokens, slot, pos0, n_valid,
                                base_tok, base_pos):
@@ -194,7 +205,7 @@ class ServingEngine:
             cache, _ = jax.lax.scan(body, cache, (idx, tokens))
             return cache
 
-        self._prefill_step = jax.jit(prefill_chunk_step)
+        self._prefill_step = jax.jit(prefill_chunk_step, donate_argnums=(1,))
 
     # -- public API -----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -371,35 +382,49 @@ class ServingEngine:
                      base_tok, base_pos) -> None:
         """One chunked-prefill dispatch (asynchronous)."""
         with trace_span("engine.prefill_chunk", slot=int(slot),
-                        slots=self.batch, lanes=int(n_valid)):
-            args = (self.params, self.cache, jnp.asarray(tokens),
+                        slots=self.batch, lanes=int(n_valid)) as span:
+            old = self.cache
+            args = (self.params, old, jnp.asarray(tokens),
                     jnp.asarray(slot, jnp.int32),
                     jnp.asarray(pos0, jnp.int32),
                     jnp.asarray(n_valid, jnp.int32),
                     jnp.asarray(base_tok), jnp.asarray(base_pos))
             self.cache = self._prefill_step(*args)
+            span.set(in_place=self._note_in_place("prefill_chunk", old))
 
     def _single(self, slot: int, token: int, pos: int) -> None:
         """One prompt token through the whole decode step: a one-lane
         prefill dispatch."""
         with trace_span("engine.prefill_chunk", slot=slot, slots=self.batch,
-                        lanes=1):
+                        lanes=1) as span:
             tok = np.array(self.slot_last, np.int32)
             ps = np.array(self.slot_pos, np.int32)
             tok[slot] = token
             ps[slot] = pos
+            old = self.cache
             _, self.cache = self._step(self.params, jnp.asarray(tok),
-                                       jnp.asarray(ps), self.cache)
+                                       jnp.asarray(ps), old)
+            span.set(in_place=self._note_in_place("decode_step", old))
+
+    def _note_in_place(self, program: str, old_cache) -> int:
+        """1 if the dispatch just made consumed ``old_cache`` (the runtime
+        took the donated buffers for its output), else 0; counted in
+        ``cache_in_place``.  Reads a flag: waits on nothing."""
+        used = int(jax.tree.leaves(old_cache)[0].is_deleted())
+        self.cache_in_place[program] += used
+        return used
 
     def _decode_once(self) -> None:
         active = [s for s in range(self.batch) if self.slot_req[s] is not None]
         if not active:
             return
         with trace_span("engine.decode", active=len(active),
-                        slots=self.batch):
+                        slots=self.batch) as span:
+            old = self.cache
             logits, self.cache = self._step(
                 self.params, jnp.asarray(self.slot_last),
-                jnp.asarray(self.slot_pos), self.cache)
+                jnp.asarray(self.slot_pos), old)
+            span.set(in_place=self._note_in_place("decode_step", old))
             with trace_span("engine.sample"):
                 self.key, sub = jax.random.split(self.key)
                 temps = {r.temperature for s, r in enumerate(self.slot_req)

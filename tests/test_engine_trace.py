@@ -85,8 +85,13 @@ def test_engine_spans_reach_the_profile(mode, tmp_path):
     keys = {name: {k for _, _, a in by_name[name] for k in a}
             for name in by_name}
     assert keys["engine.prefill"] == {"rid", "slot", "tokens", "queued"}
-    assert keys["engine.prefill_chunk"] == {"slot", "slots", "lanes"}
-    assert keys["engine.decode"] == {"active", "slots"}
+    assert keys["engine.prefill_chunk"] == {"slot", "slots", "lanes",
+                                            "in_place"}
+    assert keys["engine.decode"] == {"active", "slots", "in_place"}
+    # Every dispatch updated the donated cache in place.
+    assert all(a["in_place"] == 1
+               for name in ("engine.prefill_chunk", "engine.decode")
+               for _, _, a in by_name[name])
     assert keys["engine.sample"] == keys["engine.emit"] == set()
     if mode == "async":
         assert keys["engine.wait"] == set()
